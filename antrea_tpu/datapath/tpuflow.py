@@ -49,7 +49,7 @@ from ..observability.flightrec import emit_into
 from ..observability.metrics import Histogram
 from ..observability.telemetry import TelemetryPlane
 from ..ops.match import (PRUNE_HIST_BOUNDS, PRUNE_LADDER, DeltaTable,
-                         PruneAutotuner, to_host)
+                         PruneAutotuner, placed_meta, to_host)
 from ..packet import Packet, PacketBatch
 from ..utils import ip as iputil
 from ..config import ConfigError
@@ -330,8 +330,8 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         bit-for-bit)."""
         host, match_meta = to_host(cps, delta_slots=self._delta_slots,
                                    prune_budget=self._prune_budget)
-        host = self._pad_tables(host)
-        return jax.tree_util.tree_map(jnp.asarray, host), match_meta
+        drs = jax.tree_util.tree_map(jnp.asarray, self._pad_tables(host))
+        return drs, placed_meta(match_meta, drs)
 
     def _place_services(self, dsvc: pl.DeviceServiceTables):
         """Device service-table placement hook (mesh engine: replicated
@@ -1702,6 +1702,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             second_chance=bool(self._pipe_kw["second_chance"]),
             telemetry=bool(self._pipe_kw["telemetry"]),
         )
+        pl.require_onepass_lowers(self._meta, drs)
         # Async-mode step/drain variants of the meta: the FAST step masks
         # the whole slow path out (phases=0 — misses keep the admission
         # policy's provisional image, models/pipeline miss_code) and the

@@ -39,8 +39,10 @@ def main() -> int:
     from ..agent.controller import AgentPolicyController
     from ..datapath import OracleDatapath, TpuflowDatapath
     from ..packet import PacketBatch
+    from ..utils.compile_cache import enable_compile_cache
     from . import serde
 
+    enable_compile_cache()
     kw = dict(flow_slots=args.flow_slots, aff_slots=args.aff_slots)
     if args.datapath == "tpuflow":
         dp = TpuflowDatapath(miss_chunk=32, **kw)

@@ -93,9 +93,11 @@ class SubprocessAgent:
         self._stderr = tempfile.TemporaryFile()
         self._rdbuf = b""
         env = dict(os.environ)
-        # The child never needs an accelerator; keep it hermetic like the
-        # test suite (tests/conftest.py rationale).
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # The child never needs an accelerator, and a chip belongs to ONE
+        # process: assign (not default) the CPU platform, so a parent
+        # started with JAX_PLATFORMS=tpu cannot hand the child a chip the
+        # parent already holds.
+        env["JAX_PLATFORMS"] = "cpu"
         repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__)
         )))

@@ -376,6 +376,55 @@ class PipelineMeta(NamedTuple):
         )
 
 
+def require_onepass_lowers(meta: PipelineMeta, drs: DeviceRuleSet) -> None:
+    """Construction-time check that the one-pass kernel `meta` selects
+    compiles where `drs` lives: one tile of the real kernel (this world's
+    aggregate widths, phases and K) goes through the TPU compiler, and a
+    refusal becomes a typed ConfigError quoting it — instead of a raw
+    compiler exception out of the first step().  Off-TPU the interpreter
+    runs the kernel, so there is nothing to check."""
+    mm = meta.match
+    if not meta.onepass or _m.pallas_interpret(mm):
+        return
+    from ..config import ConfigError
+
+    ing, eg = drs.ingress, drs.egress
+    b, blk = _m._FUSE_TB, _m.AGG_BLOCK
+    s_in, s_out = ing.at.agg.shape[1], eg.at.agg.shape[1]
+    call = _m._onepass_call(
+        b, s_in, s_out, mm.prune_budget, mm.prune_budget, mm.in_phases,
+        mm.out_phases, mm.svcref, True, meta.timeouts, meta.flow_slots,
+        meta.pref_mask, False)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def u32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.uint32)
+
+    tabs = [ing.at, ing.peer, ing.svc, eg.at, eg.peer, eg.svc]
+    if mm.svcref:
+        tabs.append(eg.svc)
+    # Operand order of slow_onepass's call below.
+    args = ([i32(b, 8), i32(b, 4), i32(b, 4), i32(b, 4), i32(b, 4), i32(b, 8)]
+            + [u32(b, s_in)] * 3 + [u32(b, s_out)] * 3
+            + [i32(b, 8), i32(1, 4)]
+            + [u32(t.inc.size // blk, blk) for t in tabs]
+            + [i32(*ing.action.shape), i32(*eg.action.shape)])
+    try:
+        jax.jit(call).lower(*args).compile()
+    except Exception as e:  # noqa: BLE001 — the compiler's refusals are
+        # NotImplementedError (Pallas lowering), jax-private Mosaic types
+        # or JaxRuntimeError (XLA); all mean the same thing here.
+        raise ConfigError(
+            f"fused=True with prune_budget={mm.prune_budget} selects the "
+            f"one-pass kernel, which does not lower on "
+            f"{jax.devices()[0].device_kind}: {type(e).__name__}: "
+            f"{' '.join(str(e).split())[:300]} — use fused=True alone "
+            f"(staged consumer) or prune_budget alone (pruned consumer)"
+        ) from e
+
+
 def svc_to_host(st: ServiceTables) -> DeviceServiceTables:
     """Numpy-resident variant (zero device placement; see ops/match.to_host)."""
     return DeviceServiceTables(
@@ -1730,10 +1779,7 @@ def _pipeline_step(
         K = meta.match.prune_budget
         sharded = hit_combine is not None
         resolve = not sharded
-        if meta.match.fused_interpret is not None:
-            interp = meta.match.fused_interpret
-        else:
-            interp = jax.devices()[0].platform == "cpu"
+        interp = _m.pallas_interpret(meta.match)
         s_in = aggs[0].shape[1]
         s_out = aggs[3].shape[1]
         w0i = ing.word_idx[0]

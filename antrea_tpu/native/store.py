@@ -5,14 +5,17 @@ config store with on-disk snapshot ... same transactional semantics"):
 the C++ journaled KV store (native/ovsdb_lite.cc) holds the durable
 config/state the reference keeps in ovsdb-server — cookie round numbers,
 interface external-IDs, bridge config.  The library builds on demand with
-g++ (cached next to the source); environments without a toolchain fall
-back to a pure-Python journal with the SAME record format, so the two
-implementations are interchangeable on the same file.
+g++, cached next to the source under a name keyed by the source's hash —
+a stale or foreign `.so` copied along with the tree is never loaded;
+environments without a toolchain fall back to a pure-Python journal with
+the SAME record format, so the two implementations are interchangeable on
+the same file.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import struct
 import subprocess
@@ -23,38 +26,46 @@ _SRC = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "native", "ovsdb_lite.cc",
 )
-_SO = os.path.join(os.path.dirname(_SRC), "ovsdb_lite.so")
 _MAGIC = 0x0A17DB01
 
 _lib = None
 _lib_err: Optional[str] = None
 
 
-def _build() -> Optional[str]:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return None
+def _build() -> tuple[Optional[str], Optional[str]]:
+    """-> (path of the library built from THIS source, error)."""
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError as e:
+        return None, f"native source unreadable: {e}"
+    so = os.path.join(os.path.dirname(_SRC), f"ovsdb_lite.{digest}.so")
+    if os.path.exists(so):
+        return so, None
+    tmp = f"{so}.{os.getpid()}.tmp"  # rename-into-place: no half-written .so
     try:
         r = subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-o", _SO, _SRC],
+            ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
             capture_output=True, text=True, timeout=120,
         )
     except (OSError, subprocess.TimeoutExpired) as e:
-        return f"g++ unavailable: {e}"
+        return None, f"g++ unavailable: {e}"
     if r.returncode != 0:
-        return f"g++ failed: {r.stderr[-500:]}"
-    return None
+        return None, f"g++ failed: {r.stderr[-500:]}"
+    os.replace(tmp, so)
+    return so, None
 
 
 def _load():
     global _lib, _lib_err
     if _lib is not None or _lib_err is not None:
         return
-    err = _build()
+    so, err = _build()
     if err is not None:
         _lib_err = err
         return
     try:
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
     except OSError as e:
         _lib_err = str(e)
         return

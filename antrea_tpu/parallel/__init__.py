@@ -1,5 +1,4 @@
 from .mesh import (  # noqa: F401
-    SHARD_MAP_IMPL,
     make_mesh,
     make_sharded_classifier,
     make_sharded_pipeline,

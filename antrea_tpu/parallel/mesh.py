@@ -50,7 +50,6 @@ prove this, hence check_vma=False).
 
 from __future__ import annotations
 
-import inspect
 from functools import lru_cache, partial
 from typing import NamedTuple
 
@@ -71,52 +70,21 @@ from ..ops import match as m
 DATA, RULE = "data", "rule"
 
 
-def _probe_shard_map():
-    """Capability probe (not a version guess): pick the public
-    `jax.shard_map` when the installed jax exposes it, else the
-    experimental module, and discover the replication-check kwarg each
-    actually accepts by SIGNATURE (`check_vma` on newer public builds,
-    `check_rep` before the rename) — a jax upgrade that renames either
-    again degrades to "no check kwarg" instead of a TypeError.
-
-    Why the replication check is disabled at all (the ONE place this is
-    argued): every sharded kernel here combines its per-phase first-match
-    hit tensors with `lax.pmin` over ``rule`` before anything downstream
-    consumes them, so verdicts — and every state update computed from
-    them — are bitwise identical on all rule shards BY CONSTRUCTION.
-    Neither checker can prove replication established through a collective
-    in the body, so both would reject these (correct) programs; the
-    invariant is instead enforced empirically by the parity suites
-    (tests/test_parallel.py, tests/test_mesh_datapath.py), which diff the
-    sharded outputs bit-for-bit against the single-chip kernels.
-
-    -> (implementation name, callable, check kwarg name or None).
-    """
-    sm = getattr(jax, "shard_map", None)
-    name = "jax.shard_map"
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-
-        name = "jax.experimental.shard_map"
-    params = inspect.signature(sm).parameters
-    kw = next((k for k in ("check_vma", "check_rep") if k in params), None)
-    return name, sm, kw
-
-
-#: Which shard_map implementation the probe selected on this image —
-#: asserted by tests/test_mesh_datapath.py so a jax upgrade that moves
-#: the API surfaces loudly instead of silently falling back.
-SHARD_MAP_IMPL, _SHARD_MAP_FN, _SHARD_MAP_CHECK_KW = _probe_shard_map()
-
-
 def _shard_map(body, *, mesh, in_specs, out_specs):
-    """The one shard_map entry point (see _probe_shard_map for both the
-    capability probe and the disabled-replication-check rationale)."""
-    kwargs = {}
-    if _SHARD_MAP_CHECK_KW is not None:
-        kwargs[_SHARD_MAP_CHECK_KW] = False
-    return _SHARD_MAP_FN(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, **kwargs)
+    """The one shard_map entry point, with the replication check off.
+
+    Why it is disabled (the ONE place this is argued): every sharded
+    kernel here combines its per-phase first-match hit tensors with
+    `lax.pmin` over ``rule`` before anything downstream consumes them, so
+    verdicts — and every state update computed from them — are bitwise
+    identical on all rule shards BY CONSTRUCTION.  check_vma cannot prove
+    replication established through a collective in the body, so it would
+    reject these (correct) programs; the invariant is instead enforced
+    empirically by the parity suites (tests/test_parallel.py,
+    tests/test_mesh_datapath.py), which diff the sharded outputs
+    bit-for-bit against the single-chip kernels."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # Shard-affinity hash (the multichip traffic path, datapath engine in
@@ -226,20 +194,17 @@ def shard_of_tuples(src_ip, dst_ip, proto, sport, dport, n_data: int,
 
 
 def make_mesh(n_data: int, n_rule: int, devices=None) -> Mesh:
+    """(n_data x n_rule) mesh over `devices`, default the default
+    backend's.  Too few devices raises: a virtual-CPU dryrun mesh is
+    built only from `devices=jax.devices("cpu")` passed by the caller,
+    never substituted for missing chips."""
     need = n_data * n_rule
     if devices is None:
         devices = jax.devices()
-        if len(devices) < need:
-            # Single-accelerator host: fall back to the virtual CPU platform
-            # (xla_force_host_platform_device_count) for sharding dryruns.
-            try:
-                cpus = jax.devices("cpu")
-            except RuntimeError:
-                cpus = []
-            if len(cpus) >= need:
-                devices = cpus
     if len(devices) < need:
-        raise ValueError(f"need {need} devices, have {len(devices)}")
+        raise ValueError(
+            f"need {need} devices for a {n_data}x{n_rule} mesh, have "
+            f"{len(devices)} ({devices[0].platform if devices else 'none'})")
     arr = np.asarray(devices[:need]).reshape(n_data, n_rule)
     return Mesh(arr, (DATA, RULE))
 
@@ -358,16 +323,13 @@ def shard_rule_set(cps: CompiledPolicySet, mesh: Mesh,
     n_rule = mesh.shape[RULE]
     drs, meta = m.to_device(cps, word_multiple=n_rule,
                             prune_budget=prune_budget)
-    # The fused consumer must interpret iff the MESH's backend is CPU —
-    # the default platform can differ (virtual-CPU dryrun on a TPU host).
-    meta = meta._replace(
-        fused_interpret=(mesh.devices.flat[0].platform == "cpu")
-    )
     specs = _drs_specs(agg=prune_budget > 0)
     drs = jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), drs, specs
     )
-    return drs, meta
+    # Re-stamp: the operands now live on the MESH, whose platform can
+    # differ from the default backend's (virtual-CPU dryrun on a TPU host).
+    return drs, m.placed_meta(meta, drs)
 
 
 def shard_state(state: pl.PipelineState, mesh: Mesh) -> pl.PipelineState:

@@ -117,7 +117,7 @@ from ..models import forwarding as fw
 from ..models import pipeline as pl
 from ..ops import hashing
 from ..ops import match as m
-from ..ops.match import to_host
+from ..ops.match import placed_meta, to_host
 from ..packet import PacketBatch
 from ..utils import ip as iputil
 from .mesh import (
@@ -675,15 +675,10 @@ class MeshDatapath(TpuflowDatapath):
         # default world), composing with the word_multiple padding above
         # so tenant shapes stay rung-determined ON the mesh too.
         drs = self._pad_tables(host)
-        # The fused consumer must interpret iff the MESH's backend is CPU
-        # (the default platform can differ — virtual-CPU mesh on a TPU
-        # host), mirroring mesh.shard_rule_set.
-        meta = meta._replace(
-            fused_interpret=(mesh.devices.flat[0].platform == "cpu"))
         drs = jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
             drs, _drs_specs(agg=self._prune_budget > 0))
-        return drs, meta
+        return drs, placed_meta(meta, drs)
 
     def _place_rules(self, cps):
         return self._place_rules_on(self._mesh, cps)

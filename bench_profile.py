@@ -45,6 +45,7 @@ from antrea_tpu.simulator.genpolicy import gen_cluster
 from antrea_tpu.simulator.genservice import gen_services
 from antrea_tpu.simulator.traffic import gen_traffic
 from antrea_tpu.utils import ip as iputil
+from antrea_tpu.utils.compile_cache import enable_compile_cache
 
 # bench.py's churn-regime shape, verbatim.
 N_RULES = 100_000
@@ -152,6 +153,7 @@ def main() -> int:
                     help="K budget for --mode prune/fused "
                          "(PRUNE_LADDER rung)")
     args = ap.parse_args()
+    enable_compile_cache()
     out_path = args.out or _next_out(os.path.dirname(os.path.abspath(__file__)))
 
     if args.mode == "telemetry":

@@ -10,7 +10,7 @@ the maintenance scheduler on the mesh.
 
 The partition-spec drift gate (analysis pass `mesh`: every sharded pytree
 field has an explicit PartitionSpec or a reasoned waiver) and the
-_shard_map capability-probe assertion.
+jax.shard_map / make_mesh assertions.
 """
 
 import pathlib
@@ -61,7 +61,7 @@ def _mesh_dp(world, mesh, **extra):
 
 
 # --------------------------------------------------------------------------
-# Satellites: the drift gate + the shard_map capability probe
+# Satellites: the drift gate + the shard_map / make_mesh contract
 # --------------------------------------------------------------------------
 
 # The partition-spec coverage gate (tools/check_mesh.py -> analysis pass
@@ -69,23 +69,27 @@ def _mesh_dp(world, mesh, **extra):
 # tests/test_static_analysis.py.
 
 
-def test_shard_map_capability_probe():
-    """The shim selects its implementation by CAPABILITY PROBE (does the
-    installed jax expose the public alias, and which replication-check
-    kwarg does its signature carry) instead of a blanket version guess —
-    so the assertion is that the probe picked the best implementation
-    this image actually has: the public `jax.shard_map` whenever it
-    exists, the experimental module otherwise (this image, jax 0.4.x),
-    and a check kwarg that really is in the chosen function's
-    signature."""
-    import inspect
+def test_mesh_runs_on_jax_shard_map(monkeypatch):
+    """parallel/mesh calls the public `jax.shard_map` directly with the
+    replication check off (mesh._shard_map argues why), and make_mesh
+    never substitutes other devices for ones the default backend lacks."""
+    from jax.sharding import PartitionSpec as P
 
-    expected = ("jax.shard_map" if getattr(jax, "shard_map", None) is not None
-                else "jax.experimental.shard_map")
-    assert pm.SHARD_MAP_IMPL == expected
-    assert pm._SHARD_MAP_CHECK_KW in ("check_vma", "check_rep")
-    assert pm._SHARD_MAP_CHECK_KW in inspect.signature(
-        pm._SHARD_MAP_FN).parameters
+    calls = []
+    real = jax.shard_map
+
+    def spy(f, **kw):
+        calls.append(kw)
+        return real(f, **kw)
+
+    monkeypatch.setattr(jax, "shard_map", spy)
+    mesh2 = pm.make_mesh(2, 1, devices=jax.devices("cpu")[:2])
+    f = pm._shard_map(lambda x: x * 2, mesh=mesh2, in_specs=P(pm.DATA),
+                      out_specs=P(pm.DATA))
+    assert np.asarray(f(np.arange(4))).tolist() == [0, 2, 4, 6]
+    assert [c["check_vma"] for c in calls] == [False]
+    with pytest.raises(ValueError, match="need 16 devices"):
+        pm.make_mesh(4, 4)  # conftest forces 8
 
 
 def test_shard_affinity_hash_symmetric_and_spread():

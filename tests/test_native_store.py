@@ -29,6 +29,27 @@ def test_native_toolchain_builds():
     assert native_available()
 
 
+def test_library_is_keyed_by_source_hash(tmp_path, monkeypatch):
+    """A stale `.so` sitting next to the source (git ignores it, a tree
+    copy carries it) is never loaded: the library's name carries the hash
+    of the source it was built from."""
+    import hashlib
+    import shutil
+
+    from antrea_tpu.native import store
+
+    src = tmp_path / "ovsdb_lite.cc"
+    shutil.copy(store._SRC, src)
+    (tmp_path / "ovsdb_lite.so").write_bytes(b"stale")
+    monkeypatch.setattr(store, "_SRC", str(src))
+    so, err = store._build()
+    assert err is None
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    assert os.path.basename(so) == f"ovsdb_lite.{digest}.so"
+    assert os.path.getsize(so) > len(b"stale")
+    assert store._build() == (so, None)  # cached, not rebuilt
+
+
 def test_txn_commit_abort_and_reopen(tmp_path, backend):
     p = tmp_path / "db"
     with _open(p, backend) as s:
