@@ -273,6 +273,25 @@ class Datapath(ABC):
         return ([] if self._flightrec is None
                 else self._flightrec.events(tail=tail, kind=kind))
 
+    # -- step tracing (observability/tracing.StepTracer): the engines build
+    # one beside step_hist, always on; inert default for the oracle and
+    # test doubles -----------------------------------------------------------
+
+    _steptrace = None
+
+    def step_trace(self) -> Optional[dict]:
+        """The last STEP_RING_SLOTS `step` calls, oldest first:
+        {"records": structured array (tracing.STEP_RECORD — sequence
+        number, lanes, n_miss, the span's and its phases' perf_counter_ns
+        stamps, the transfer counters), "dropped": rows aged out of the
+        ring}.  `lanes` and `n_miss` are the step's load: they tell a
+        step that was slow under a miss burst or a wide batch from one
+        that was stopped.  None on a datapath that keeps no step trace."""
+        tr = self._steptrace
+        if tr is None:
+            return None
+        return {"records": tr.records(), "dropped": tr.dropped}
+
     # -- hot-path telemetry (observability/telemetry.py) --------------------
     # Engines with telemetry=True build a TelemetryPlane at construction
     # and call _telemetry_account from _step + observe_step from the

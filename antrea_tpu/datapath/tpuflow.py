@@ -48,6 +48,9 @@ from ..models import pipeline as pl
 from ..observability.flightrec import emit_into
 from ..observability.metrics import Histogram
 from ..observability.telemetry import TelemetryPlane
+from ..observability.tracing import (SP_ACCOUNT, SP_ATTRIBUTE, SP_DISPATCH,
+                                     SP_DONE, SP_FETCH, SP_STAGE, SP_UPLOAD,
+                                     SP_WAIT, StepTracer)
 from ..ops.match import (PRUNE_HIST_BOUNDS, PRUNE_LADDER, DeltaTable,
                          PruneAutotuner, placed_meta, to_host)
 from ..packet import Packet, PacketBatch
@@ -269,6 +272,11 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         # np.asarray conversions force completion), i.e. the latency the
         # dissemination/observability planes actually wait out.
         self.step_hist = Histogram()
+        # The step's own spans (observability/tracing.StepTracer): the
+        # `step` span that feeds step_hist, its seven host phases and the
+        # transfer counters, kept for the last 4,096 steps.  Always on,
+        # like step_hist; host-side only.
+        self._steptrace = StepTracer()
         if self._topo is None:
             self._topo = Topology()
         self._compile_rules()
@@ -527,9 +535,15 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         self._audit_refresh_golden()
 
     def _v6_lanes(self, batch: PacketBatch):
-        """Batch -> the pipeline's v6 lane tuple (or None).  Dual-stack
-        instances ALWAYS materialize the wide lanes (the key layout is
-        static); narrow instances reject v6-carrying batches loudly."""
+        """Batch -> the pipeline's v6 lane tuple on the device (or None)."""
+        v6 = self._v6_host(batch)
+        return None if v6 is None else tuple(jnp.asarray(x) for x in v6)
+
+    def _v6_host(self, batch: PacketBatch):
+        """Batch -> the HOST columns of the pipeline's v6 lane tuple (or
+        None).  Dual-stack instances ALWAYS materialize the wide lanes
+        (the key layout is static); narrow instances reject v6-carrying
+        batches loudly."""
         if not self._dual_stack:
             if batch.has_v6:
                 raise ValueError(
@@ -539,29 +553,43 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             return None
         B = batch.size
         if batch.src_ip6 is None:
-            z = np.zeros((B, 4), np.uint32)
-            return (jnp.asarray(iputil.flip_u32(z)),
-                    jnp.asarray(iputil.flip_u32(z)),
-                    jnp.zeros(B, jnp.int32))
-        return (jnp.asarray(iputil.flip_u32(batch.src_ip6)),
-                jnp.asarray(iputil.flip_u32(batch.dst_ip6)),
-                jnp.asarray(batch.is6))
+            z = iputil.flip_u32(np.zeros((B, 4), np.uint32))
+            return (z, z, np.zeros(B, np.int32))
+        return (iputil.flip_u32(batch.src_ip6),
+                iputil.flip_u32(batch.dst_ip6), batch.is6)
+
+    def _upload(self, x):
+        """Host column -> device array (None passes), counted by the
+        step tracer where the transfer is issued."""
+        return None if x is None else jnp.asarray(self._steptrace.uploaded(x))
+
+    def _upload_i32(self, v: int):
+        """A scalar argument of the step: a transfer (and a tiny device
+        program) of its own, counted like the columns."""
+        return jnp.int32(self._steptrace.uploaded(np.int32(v)))
 
     def step(self, batch: PacketBatch, now: int, *, valid=None) -> StepResult:
-        t0 = time.perf_counter()
-        # Traffic time drives the maintenance tick clock (one clock
-        # domain: flow-cache aging and FQDN expiry stamp with THIS now).
-        self._maintenance.observe(now)
-        if self._realization is not None:
-            # First-hit latch (realization tracing): the first LIVE batch
-            # classified under a new bundle generation closes its spans.
-            # One int compare per step after the latch; host-side only,
-            # so the compiled step HLO is bit-identical with tracing off.
-            self._realization.first_hit(self._gen, batch.size)
+        # The `step` span: its end - start is the ONE clock pair that
+        # feeds step_hist and the telemetry fold below.
+        tr = self._steptrace
+        tr.begin(batch.size)
         try:
+            # Traffic time drives the maintenance tick clock (one clock
+            # domain: flow-cache aging and FQDN expiry stamp with THIS
+            # now).
+            self._maintenance.observe(now)
+            if self._realization is not None:
+                # First-hit latch (realization tracing): the first LIVE
+                # batch classified under a new bundle generation closes
+                # its spans.  One int compare per step after the latch;
+                # host-side only, so the compiled step HLO is
+                # bit-identical with tracing off.
+                self._realization.first_hit(self._gen, batch.size)
             return self._step(batch, now, valid=valid)
         finally:
-            dt = time.perf_counter() - t0
+            # Close the span FIRST: a raising _step leaves a closed
+            # record in the ring, whatever the folds below do.
+            dt = tr.end()
             self.step_hist.observe(dt)
             if self._telemetry is not None:
                 # Fold the SAME wall seconds into every (scope, regime)
@@ -570,39 +598,55 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
                 self._telemetry.observe_step(dt)
 
     def _step(self, batch: PacketBatch, now: int, valid=None) -> StepResult:
+        tr = self._steptrace
+        # ---- stage: the host columns, numpy only ---------------------------
+        tr.phase(SP_STAGE)
         # One materialization of the per-lane byte lengths, clamped
         # (negative pkt_len must never decrement a monotonic counter).
         lens = np.maximum(batch.lens(), 0)
+        flags = batch.flags()
+        cols = (iputil.flip_u32(batch.src_ip), iputil.flip_u32(batch.dst_ip),
+                batch.proto.astype(np.int32),
+                batch.src_port.astype(np.int32),
+                batch.dst_port.astype(np.int32), batch.in_ports())
+        # Only materialize the ARP lane when the batch carries ARP —
+        # pure-IP batches keep the round-3 compiled program.
+        arp = batch.arp_ops() if batch.arp_op is not None else None
+        v6 = self._v6_host(batch)
+        # Serving-batcher padding mask: padded lanes ride the spoof
+        # discipline (no state commit / miss admission / counters); None
+        # traces the identical program, so the unbatched path stays
+        # HLO-bit-identical.
+        vmask = None if valid is None else np.asarray(valid, bool)
+
+        # ---- upload: one transfer per column, counted where issued ---------
+        tr.phase(SP_UPLOAD)
+        up = self._upload
+        args = [up(c) for c in cols]
+        args += [self._upload_i32(now), self._upload_i32(self._gen),
+                 up(flags), up(arp), up(lens if self._flow_stats else None)]
+        v6 = None if v6 is None else tuple(up(x) for x in v6)
+        vmask = up(vmask)
+
+        # ---- dispatch: the jitted call until it returns --------------------
+        tr.phase(SP_DISPATCH)
         state, out = fwd.pipeline_step_full(
-            self._state,
-            self._drs,
-            self._dsvc,
-            self._dft,
-            jnp.asarray(iputil.flip_u32(batch.src_ip)),
-            jnp.asarray(iputil.flip_u32(batch.dst_ip)),
-            jnp.asarray(batch.proto.astype(np.int32)),
-            jnp.asarray(batch.src_port.astype(np.int32)),
-            jnp.asarray(batch.dst_port.astype(np.int32)),
-            jnp.asarray(batch.in_ports()),
-            jnp.int32(now),
-            jnp.int32(self._gen),
-            jnp.asarray(batch.flags()),
-            # Only materialize the ARP lane when the batch carries ARP —
-            # pure-IP batches keep the round-3 compiled program.
-            jnp.asarray(batch.arp_ops()) if batch.arp_op is not None else None,
-            jnp.asarray(lens) if self._flow_stats else None,
-            meta=self._meta_step,
-            v6=self._v6_lanes(batch),
-            # Serving-batcher padding mask: padded lanes ride the spoof
-            # discipline (no state commit / miss admission / counters);
-            # None traces the identical program, so the unbatched path
-            # stays HLO-bit-identical.
-            valid=(None if valid is None
-                   else jnp.asarray(np.asarray(valid, bool))),
+            self._state, self._drs, self._dsvc, self._dft, *args,
+            meta=self._meta_step, v6=v6, valid=vmask,
         )
         self._state = state
         self._state_mutations += 1
-        o = {k: np.asarray(v) for k, v in out.items()}
+        # ---- wait: the host waiting for the device.  The first fetch
+        # below would wait for the whole executable anyway; this only
+        # tells the waiting from the copies. --------------------------------
+        tr.phase(SP_WAIT)
+        jax.block_until_ready(out)
+        # ---- fetch: one device->host copy per output -----------------------
+        tr.phase(SP_FETCH)
+        o = {k: tr.fetched(np.asarray(v)) for k, v in out.items()}
+        # ---- account: counters, admission, telemetry, per-rule stats -------
+        tr.phase(SP_ACCOUNT)
+        tr.n_miss = int(o["n_miss"])
         self._evictions += int(o["n_evict"])
         self._prune_account(o)
         pending = None
@@ -617,7 +661,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             # (datapath/tenancy — both are no-ops on the default world).
             pending = o["miss"]
             admitted, _dropped = self._slowpath.admit(
-                self._queue_cols(batch, batch.flags(), lens,
+                self._queue_cols(batch, flags, lens,
                                  tenant=self._tenant_id()),
                 self._tenant_admit_mask(pending != 0), now,
             )
@@ -632,6 +676,8 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         if self._deny is not None:
             self._deny_verdicts(batch, o["code"], pending, now)
 
+        # ---- attribute: rule ids and the StepResult ------------------------
+        tr.phase(SP_ATTRIBUTE)
         unflip = iputil.unflip_u32_array
 
         def keys_of(wide_col):
@@ -663,7 +709,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
                                          o["out_port"])
             ]
 
-        return StepResult(
+        res = StepResult(
             code=o["code"],
             est=o["est"],
             pending=pending,
@@ -683,7 +729,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
                 for i in o["egress_rule"]
             ],
             committed=o["committed"],
-            n_miss=int(o["n_miss"]),
+            n_miss=tr.n_miss,
             spoofed=o["spoofed"],
             punt=o["punt"],
             mcast_idx=o["mcast_idx"],
@@ -702,6 +748,8 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             dnat_key=dnat_key,
             peer_key=peer_key,
         )
+        tr.phase(SP_DONE)
+        return res
 
     def stats(self) -> DatapathStats:
         return DatapathStats(

@@ -47,6 +47,7 @@ from ..compiler.topology import (
     TC_REDIRECT,
     ForwardingTables,
 )
+from ..ops.scopes import device_scope
 from . import pipeline as pl
 
 
@@ -331,163 +332,165 @@ def _pipeline_step_full(
     v6 lanes spoof-guard / forward / TC through the lexicographic
     sub-tables; arp_op on a v6 lane models Neighbor Discovery (NS=1 answers
     from the nd table, the ARPResponder twin)."""
-    if v6 is not None:
-        src6w, dst6w, is6 = v6
-        saddr_w = pl._wide_words(src_f, src6w, is6)
-        daddr_w = pl._wide_words(dst_f, dst6w, is6)
-        m6 = is6 != 0
-        spoof = spoof_lookup(dft, src_f, in_port, src_w=saddr_w, is6=is6)
-    else:
-        is6 = None
-        spoof = spoof_lookup(dft, src_f, in_port)
-    # IGMP membership traffic is punted to the controller, never forwarded
-    # (ref packetin.go PacketInCategoryIGMP; pkg/agent/multicast snooping):
-    # excluded from the policy pipeline like spoofed lanes so reports
-    # neither commit conntrack state nor count as policy verdicts.
-    is_arp = (arp_op > 0) if arp_op is not None else None
-    igmp = ~spoof & (proto == PROTO_IGMP)
-    if is_arp is not None:
-        igmp = igmp & ~is_arp
-    # Multicast data traffic bypasses conntrack (multicast.go): classified
-    # every step, never cached.  The 224/4 window is a v4 range — v6 lanes
-    # carry a don't-care narrow dst and must not alias into it.
-    is_mc = (dst_f >= MCAST_LO_F) & (dst_f <= MCAST_HI_F)
-    if is6 is not None:
-        is_mc = is_mc & ~m6
-    no_commit_l = is_mc
-    if flags is not None:
-        # A FIN/RST-flagged TCP miss classifies but never ESTABLISHES a
-        # connection (a closing segment is not a new flow); established
-        # hits tear down inside the pipeline (pl._TEARDOWN_FLAGS path).
-        no_commit_l = no_commit_l | (
-            (proto == pl.PROTO_TCP) & ((flags & pl._TEARDOWN_FLAGS) != 0)
-        )
-    if no_commit is not None:
-        no_commit_l = no_commit_l | no_commit
-    valid_l = ~spoof & ~igmp
-    if is_arp is not None:
-        valid_l = valid_l & ~is_arp
-    if valid is not None:
-        valid_l = valid_l & valid
+    with device_scope("forwarding"):
+        if v6 is not None:
+            src6w, dst6w, is6 = v6
+            saddr_w = pl._wide_words(src_f, src6w, is6)
+            daddr_w = pl._wide_words(dst_f, dst6w, is6)
+            m6 = is6 != 0
+            spoof = spoof_lookup(dft, src_f, in_port, src_w=saddr_w, is6=is6)
+        else:
+            is6 = None
+            spoof = spoof_lookup(dft, src_f, in_port)
+        # IGMP membership traffic is punted to the controller, never forwarded
+        # (ref packetin.go PacketInCategoryIGMP; pkg/agent/multicast snooping):
+        # excluded from the policy pipeline like spoofed lanes so reports
+        # neither commit conntrack state nor count as policy verdicts.
+        is_arp = (arp_op > 0) if arp_op is not None else None
+        igmp = ~spoof & (proto == PROTO_IGMP)
+        if is_arp is not None:
+            igmp = igmp & ~is_arp
+        # Multicast data traffic bypasses conntrack (multicast.go): classified
+        # every step, never cached.  The 224/4 window is a v4 range — v6 lanes
+        # carry a don't-care narrow dst and must not alias into it.
+        is_mc = (dst_f >= MCAST_LO_F) & (dst_f <= MCAST_HI_F)
+        if is6 is not None:
+            is_mc = is_mc & ~m6
+        no_commit_l = is_mc
+        if flags is not None:
+            # A FIN/RST-flagged TCP miss classifies but never ESTABLISHES a
+            # connection (a closing segment is not a new flow); established
+            # hits tear down inside the pipeline (pl._TEARDOWN_FLAGS path).
+            no_commit_l = no_commit_l | (
+                (proto == pl.PROTO_TCP) & ((flags & pl._TEARDOWN_FLAGS) != 0)
+            )
+        if no_commit is not None:
+            no_commit_l = no_commit_l | no_commit
+        valid_l = ~spoof & ~igmp
+        if is_arp is not None:
+            valid_l = valid_l & ~is_arp
+        if valid is not None:
+            valid_l = valid_l & valid
     state, out = pl._pipeline_step(
         state, drs, dsvc, src_f, dst_f, proto, sport, dport, now, gen,
         meta=meta, hit_combine=hit_combine, valid=valid_l,
         no_commit=no_commit_l, flags=flags, v6=v6, lens=lens,
         prune_exclude=prune_exclude,
     )
-    code = jnp.where(spoof, ACT_DROP, out["code"]).astype(jnp.int32)
-    # Forward toward the packet's effective destination: the DNAT-resolved
-    # endpoint — except reply-direction hits, whose dnat fields carry the
-    # SOURCE un-rewrite; a reply forwards to its literal dst (the client).
-    eff_dst = jnp.where(out["reply"] == 1, dst_f, out["dnat_ip_f"])
-    fwd = forwarding_lookup(dft, eff_dst, in_port)
-    peer_w = None
-    if is6 is not None:
-        # v6 lanes forward by their wide effective destination through the
-        # lexicographic tables; merge per family.
-        eff_dst_w = jnp.where((out["reply"] == 1)[:, None], daddr_w,
-                              out["dnat_w_f"])
-        fwd6 = forwarding_lookup6(dft, eff_dst_w, in_port)
-        fwd = {
-            "kind": jnp.where(m6, fwd6["kind"], fwd["kind"]),
-            "out_port": jnp.where(m6, fwd6["out_port"], fwd["out_port"]),
-            "peer_f": jnp.where(m6, 0, fwd["peer_f"]),
-            "dec_ttl": jnp.where(m6, fwd6["dec_ttl"], fwd["dec_ttl"]),
-            "lp_row": fwd["lp_row"],
-            "is_local": jnp.where(m6, fwd6["is_local"], fwd["is_local"]),
-            "is_mc": fwd["is_mc"] & ~m6,
-            "mcast_idx": jnp.where(m6, -1, fwd["mcast_idx"]),
-            "lp_row6": fwd6["lp_row"],
-            "is_local6": fwd6["is_local"] & m6,
-        }
-        # Wide peer view: v4 tunnel peers in mapped form, v6 peers native.
-        peer_w = jnp.where(
-            m6[:, None], fwd6["peer_w"],
-            pl._wide_words(fwd["peer_f"], None, None),
-        )
-    kind = jnp.where(
-        spoof, FWD_DROP_SPOOF, jnp.where(igmp, FWD_PUNT, fwd["kind"])
-    ).astype(jnp.int32)
-    if is_arp is not None:
-        # ARPResponder: answered requests reply out the ingress port;
-        # unanswered (or reply-opcode) ARP floods.  ARPSpoofGuard already
-        # resolved in `spoof` (sender IP vs port binding).  v6 lanes model
-        # Neighbor Discovery: NS (op 1) answers from the nd table — the
-        # NDP twin of the responder (route_linux.go v6 neighbors).
-        acap = dft.arp_ip_f.shape[0]
-        arow = jnp.clip(jnp.searchsorted(dft.arp_ip_f, dst_f), 0, acap - 1)
-        answer = (
-            is_arp & ~spoof
-            & (arow < dft.n_arp[0]) & (dft.arp_ip_f[arow] == dst_f)
-            & (arp_op == ARP_OP_REQUEST)
-        )
+    with device_scope("forwarding"):
+        code = jnp.where(spoof, ACT_DROP, out["code"]).astype(jnp.int32)
+        # Forward toward the packet's effective destination: the DNAT-resolved
+        # endpoint — except reply-direction hits, whose dnat fields carry the
+        # SOURCE un-rewrite; a reply forwards to its literal dst (the client).
+        eff_dst = jnp.where(out["reply"] == 1, dst_f, out["dnat_ip_f"])
+        fwd = forwarding_lookup(dft, eff_dst, in_port)
+        peer_w = None
         if is6 is not None:
-            _ndrow, nd_known = _row_eq_wide(dft.nd_ipw, dft.n_nd, daddr_w)
-            answer6 = (
-                is_arp & ~spoof & nd_known & (arp_op == ARP_OP_REQUEST)
+            # v6 lanes forward by their wide effective destination through the
+            # lexicographic tables; merge per family.
+            eff_dst_w = jnp.where((out["reply"] == 1)[:, None], daddr_w,
+                                  out["dnat_w_f"])
+            fwd6 = forwarding_lookup6(dft, eff_dst_w, in_port)
+            fwd = {
+                "kind": jnp.where(m6, fwd6["kind"], fwd["kind"]),
+                "out_port": jnp.where(m6, fwd6["out_port"], fwd["out_port"]),
+                "peer_f": jnp.where(m6, 0, fwd["peer_f"]),
+                "dec_ttl": jnp.where(m6, fwd6["dec_ttl"], fwd["dec_ttl"]),
+                "lp_row": fwd["lp_row"],
+                "is_local": jnp.where(m6, fwd6["is_local"], fwd["is_local"]),
+                "is_mc": fwd["is_mc"] & ~m6,
+                "mcast_idx": jnp.where(m6, -1, fwd["mcast_idx"]),
+                "lp_row6": fwd6["lp_row"],
+                "is_local6": fwd6["is_local"] & m6,
+            }
+            # Wide peer view: v4 tunnel peers in mapped form, v6 peers native.
+            peer_w = jnp.where(
+                m6[:, None], fwd6["peer_w"],
+                pl._wide_words(fwd["peer_f"], None, None),
             )
-            answer = jnp.where(m6, answer6, answer)
         kind = jnp.where(
-            is_arp & ~spoof,
-            jnp.where(answer, FWD_ARP_REPLY, FWD_ARP_FLOOD),
-            kind,
+            spoof, FWD_DROP_SPOOF, jnp.where(igmp, FWD_PUNT, fwd["kind"])
         ).astype(jnp.int32)
-    deliverable = (code == ACT_ALLOW) & (
-        (kind == FWD_LOCAL) | (kind == FWD_TUNNEL) | (kind == FWD_GATEWAY)
-        | (kind == FWD_MCAST)
-    )
-    uni_deliverable = deliverable & (kind != FWD_MCAST)
-    tc_base = tc_lookup(dft, src_f, fwd["lp_row"], fwd["is_local"])
-    if is6 is not None:
-        tc_base = jnp.where(
-            m6,
-            tc_lookup6(dft, saddr_w, fwd["lp_row6"], fwd["is_local6"]),
-            tc_base,
+        if is_arp is not None:
+            # ARPResponder: answered requests reply out the ingress port;
+            # unanswered (or reply-opcode) ARP floods.  ARPSpoofGuard already
+            # resolved in `spoof` (sender IP vs port binding).  v6 lanes model
+            # Neighbor Discovery: NS (op 1) answers from the nd table — the
+            # NDP twin of the responder (route_linux.go v6 neighbors).
+            acap = dft.arp_ip_f.shape[0]
+            arow = jnp.clip(jnp.searchsorted(dft.arp_ip_f, dst_f), 0, acap - 1)
+            answer = (
+                is_arp & ~spoof
+                & (arow < dft.n_arp[0]) & (dft.arp_ip_f[arow] == dst_f)
+                & (arp_op == ARP_OP_REQUEST)
+            )
+            if is6 is not None:
+                _ndrow, nd_known = _row_eq_wide(dft.nd_ipw, dft.n_nd, daddr_w)
+                answer6 = (
+                    is_arp & ~spoof & nd_known & (arp_op == ARP_OP_REQUEST)
+                )
+                answer = jnp.where(m6, answer6, answer)
+            kind = jnp.where(
+                is_arp & ~spoof,
+                jnp.where(answer, FWD_ARP_REPLY, FWD_ARP_FLOOD),
+                kind,
+            ).astype(jnp.int32)
+        deliverable = (code == ACT_ALLOW) & (
+            (kind == FWD_LOCAL) | (kind == FWD_TUNNEL) | (kind == FWD_GATEWAY)
+            | (kind == FWD_MCAST)
         )
-    tc_w = jnp.where(uni_deliverable, tc_base, 0)
-    tc_act = tc_w & 3
-    tc_port = tc_w >> 2
-    out_port = jnp.where(deliverable, fwd["out_port"], -1)
-    if is_arp is not None:
-        out_port = jnp.where(kind == FWD_ARP_REPLY, in_port, out_port)
-    # Redirect replaces the output port (ref TrafficControl redirect action:
-    # the packet leaves via the target device instead of its computed port).
-    out_port = jnp.where(tc_act == TC_REDIRECT, tc_port, out_port)
-    # L7 redirect mark (ref network_policy.go:2213 l7NPTrafficControlFlows
-    # — the reg0 L7 bit + VLAN handoff to the L7 engine): set when the
-    # DECIDING allow rule carries L7 protocols.  Resolved by attribution
-    # index against the CURRENT rule table — cached hits inherit the
-    # ct_label caveat documented on stats (datapath/tpuflow.py).
-    def l7_of(dd, idx):
-        n = dd.l7.shape[0]
-        safe = jnp.clip(idx, 0, n - 1)
-        return jnp.where((idx >= 0) & (idx < n), dd.l7[safe], 0)
+        uni_deliverable = deliverable & (kind != FWD_MCAST)
+        tc_base = tc_lookup(dft, src_f, fwd["lp_row"], fwd["is_local"])
+        if is6 is not None:
+            tc_base = jnp.where(
+                m6,
+                tc_lookup6(dft, saddr_w, fwd["lp_row6"], fwd["is_local6"]),
+                tc_base,
+            )
+        tc_w = jnp.where(uni_deliverable, tc_base, 0)
+        tc_act = tc_w & 3
+        tc_port = tc_w >> 2
+        out_port = jnp.where(deliverable, fwd["out_port"], -1)
+        if is_arp is not None:
+            out_port = jnp.where(kind == FWD_ARP_REPLY, in_port, out_port)
+        # Redirect replaces the output port (ref TrafficControl redirect action:
+        # the packet leaves via the target device instead of its computed port).
+        out_port = jnp.where(tc_act == TC_REDIRECT, tc_port, out_port)
+        # L7 redirect mark (ref network_policy.go:2213 l7NPTrafficControlFlows
+        # — the reg0 L7 bit + VLAN handoff to the L7 engine): set when the
+        # DECIDING allow rule carries L7 protocols.  Resolved by attribution
+        # index against the CURRENT rule table — cached hits inherit the
+        # ct_label caveat documented on stats (datapath/tpuflow.py).
+        def l7_of(dd, idx):
+            n = dd.l7.shape[0]
+            safe = jnp.clip(idx, 0, n - 1)
+            return jnp.where((idx >= 0) & (idx < n), dd.l7[safe], 0)
 
-    l7 = jnp.where(
-        code == ACT_ALLOW,
-        l7_of(drs.ingress, out["ingress_rule"])
-        | l7_of(drs.egress, out["egress_rule"]),
-        0,
-    ).astype(jnp.int32)
+        l7 = jnp.where(
+            code == ACT_ALLOW,
+            l7_of(drs.ingress, out["ingress_rule"])
+            | l7_of(drs.egress, out["egress_rule"]),
+            0,
+        ).astype(jnp.int32)
 
-    out.update(
-        code=code,
-        reject_kind=pl.reject_kind_of(code, proto),
-        spoofed=spoof.astype(jnp.int32),
-        l7_redirect=l7,
-        punt=igmp.astype(jnp.int32),
-        fwd_kind=kind,
-        out_port=out_port.astype(jnp.int32),
-        peer_f=jnp.where(uni_deliverable, fwd["peer_f"], 0),
-        dec_ttl=jnp.where(uni_deliverable, fwd["dec_ttl"], 0),
-        tc_act=tc_act,
-        tc_port=tc_port,
-        mcast_idx=jnp.where(deliverable, fwd["mcast_idx"], -1),
-    )
-    if peer_w is not None:
-        # Wide tunnel-peer view (v6 podCIDR rows may tunnel over either
-        # family); zeroed like peer_f for non-deliverable lanes.
-        out["peer_w"] = jnp.where(uni_deliverable[:, None], peer_w, 0)
+        out.update(
+            code=code,
+            reject_kind=pl.reject_kind_of(code, proto),
+            spoofed=spoof.astype(jnp.int32),
+            l7_redirect=l7,
+            punt=igmp.astype(jnp.int32),
+            fwd_kind=kind,
+            out_port=out_port.astype(jnp.int32),
+            peer_f=jnp.where(uni_deliverable, fwd["peer_f"], 0),
+            dec_ttl=jnp.where(uni_deliverable, fwd["dec_ttl"], 0),
+            tc_act=tc_act,
+            tc_port=tc_port,
+            mcast_idx=jnp.where(deliverable, fwd["mcast_idx"], -1),
+        )
+        if peer_w is not None:
+            # Wide tunnel-peer view (v6 podCIDR rows may tunnel over either
+            # family); zeroed like peer_f for non-deliverable lanes.
+            out["peer_w"] = jnp.where(uni_deliverable[:, None], peer_w, 0)
     return state, out
 
 

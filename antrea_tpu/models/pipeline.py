@@ -66,6 +66,7 @@ from ..ops import hashing
 from ..ops import match as _m
 from ..ops.match import (PRUNE_HIST_BOUNDS, DeviceRuleSet, StaticMeta,
                          classify_batch, to_device, to_host)
+from ..ops.scopes import device_scope
 
 # Python ints, never eager jnp scalars: see the BIG comment in ops/match.py.
 MISS = -1
@@ -761,6 +762,7 @@ def make_pipeline(
     return step, state, (drs, dsvc)
 
 
+@device_scope("service_lb")
 def _service_lb(
     aff: AffinityTable,
     dsvc: DeviceServiceTables,
@@ -991,298 +993,301 @@ def _pipeline_step(
             "the one-kernel fast path (onepass) requires the narrow v4 "
             "key layout and an aggregate-pruned meta (prune_budget > 0)")
 
-    src_raw = _raw_bits(src_f)
-    dst_raw = _raw_bits(dst_f)
-    pp = (sport << 16) | dport
-    gen_w = jnp.asarray(gen, jnp.int32) % GEN_ETERNAL  # never == GEN_ETERNAL
+    with device_scope("fast_path"), device_scope("probe"):
+        src_raw = _raw_bits(src_f)
+        dst_raw = _raw_bits(dst_f)
+        pp = (sport << 16) | dport
+        gen_w = jnp.asarray(gen, jnp.int32) % GEN_ETERNAL  # never == GEN_ETERNAL
 
-    # ---- fast path: flow-cache lookup (2 row gathers + 1 column gather) ----
-    if A == 2:
-        if v6 is not None:
-            raise ValueError(
-                "v6 lanes require a dual_stack pipeline "
-                "(make_pipeline(dual_stack=True))"
-            )
-        saddr = daddr = is6 = None  # wide-mode-only locals
-        addr = jnp.stack([src_f, dst_f], axis=1)
-        h = hashing.flow_hash(src_raw, dst_raw, proto, sport, dport, xp=jnp)
-    else:
-        # Wide (dual-stack) addressing: every lane is a 4-word v4-mapped /
-        # v6 quadruple (sign-flipped per word, utils/ip.key_to_words).
-        if v6 is not None:
-            src6w, dst6w, is6 = v6
-        else:
-            is6 = jnp.zeros_like(src_f)
-            src6w = dst6w = None
-        saddr = _wide_words(src_f, src6w, is6)
-        daddr = _wide_words(dst_f, dst6w, is6)
-        addr = jnp.concatenate([saddr, daddr], axis=1)
-        h = hashing.flow_hash_wide(
-            [addr[:, i] for i in range(8)], proto, sport, dport, xp=jnp
-        )
-    slot = (h & jnp.uint32(N - 1)).astype(jnp.int32)
-    pg_cur = proto | 0x100 | (gen_w << 9)
-    pg_est = proto | 0x100 | (GEN_ETERNAL << 9)
-    hit, est, rpl, mr, kr0, ts0 = _cache_lookup(
-        flow, slot, addr, pp, pg_cur, pg_est, now, proto, meta
-    )
-    if valid is not None:
-        # Lane mask (SpoofGuard gating, models/forwarding.py): excluded
-        # lanes neither refresh nor commit any state and take the fast-path
-        # default image — the stage order of the reference, where
-        # SpoofGuard drops happen BEFORE conntrack/policy tables.
-        hit = hit & valid
-        est = est & valid
-        rpl = rpl & valid
-    tel_on = meta.telemetry
-    if tel_on:
-        # Probe-split telemetry (hit / stale / miss), recomputed XLA-side
-        # from the SAME gathered key rows the probe decoded (kr0), so it
-        # costs three reductions and zero extra gathers.  `stale` = the
-        # key matched but the entry aged out (the megaflow-revalidation
-        # signal: the flow was cached and expired under traffic);
-        # generation-stale denials count as plain misses — they are
-        # invisible to lookups by design, not aged occupancy.  Lanes
-        # another dispatch owns (mesh spill retries, prune_exclude) and
-        # valid-masked lanes are excluded, the exactly-once discipline
-        # prune metering already follows.
-        tv = jnp.ones(B, bool) if valid is None else (valid != 0)
-        if prune_exclude is not None:
-            tv = tv & ~prune_exclude
-        kpg0 = kr0[:, A + 1]
-        key_hit0 = (
-            (kr0[:, :A] == addr).all(axis=1)
-            & (kr0[:, A] == pp)
-            & ((kpg0 == pg_cur) | (kpg0 == pg_est)
-               | (kpg0 == (pg_est | REPLY_BIT)))
-        )
-        tel_probe_hit = (hit & tv).sum(dtype=jnp.int32)
-        tel_probe_stale = (key_hit0 & ~hit & tv).sum(dtype=jnp.int32)
-        tel_probe_miss = (~key_hit0 & tv).sum(dtype=jnp.int32)
-    DC, M1C, RC, ZC = _meta_cols(A)
-    c_code, c_svc, c_dport = _unpack_meta1(mr[:, M1C])
-    # Narrow dnat view: the v4 value (wide worlds: word 3, the v4-mapped
-    # column — a don't-care for v6 lanes, whose consumers read c_dnat_w).
-    c_dnat_ip = mr[:, DC]
-    c_dnat_w = mr[:, 0:4] if A == 8 else None
-    c_rule_in, c_rule_out = _unpack_rules(mr[:, RC])
-
-    # Idle-timeout refresh for hits.
-    flow = flow._replace(ts=flow.ts.at[jnp.where(hit, slot, dump)].set(now))
-
-    if meta.second_chance:
-        # Second-chance reset: a hit is the entry's "referenced" event —
-        # clear the 2-bit collision counter so active flows keep their
-        # protection (the CLOCK-algorithm reference bit, see CHANCE_SHIFT).
-        ZC_ = _meta_cols(A)[3]
-        tgt_h = jnp.where(hit, slot, dump)
-        flow = flow._replace(meta=flow.meta.at[tgt_h, ZC_].set(
-            flow.meta[tgt_h, ZC_] & ~CHANCE_MASK))
-
-    if meta.count_flow_stats:
-        # Per-direction traffic counters (conntrack OriginalPackets/
-        # OriginalBytes, flowexporter/types.go:59): every hit adds to ITS
-        # entry's columns.  64-bit accumulation in two i32 limbs (the
-        # kernel's u64 counters; the old i32 saturation capped volumes at
-        # 2GB): the low limb adds with a wrapping scatter, and one carry
-        # per slot propagates into the high limb — exact as long as one
-        # entry receives < 2^32 bytes within a SINGLE batch (a per-batch
-        # bound, not a lifetime cap).
-        lv = jnp.zeros(B, jnp.int32) if lens is None else lens
-        ctgt = jnp.where(hit, slot, dump)
-        cwin = _winner_mask(N, slot, hit, dump)  # one carry writer per slot
-
-        def wide_add(lo, hi, add):
-            old = lo[ctgt]
-            lo = lo.at[ctgt].add(add)
-            # u32 view shrank => the slot's low limb wrapped exactly once.
-            carried = lo[ctgt].astype(jnp.uint32) < old.astype(jnp.uint32)
-            hi = hi.at[jnp.where(cwin & carried, ctgt, dump)].add(1)
-            return lo, hi
-
-        new_pk, new_pkh = wide_add(flow.pkts, flow.pkts_hi,
-                                   jnp.ones(B, jnp.int32))
-        new_oc, new_och = wide_add(flow.octets, flow.octets_hi,
-                                   jnp.maximum(lv, 0))
-        flow = flow._replace(pkts=new_pk, octets=new_oc,
-                             pkts_hi=new_pkh, octets_hi=new_och)
-
-    # Conntrack refreshes BOTH tuple directions on traffic in either
-    # direction (one kernel-ct connection == our two cache entries): an
-    # active connection's reply leg must not idle out while forward traffic
-    # keeps flowing (ovs-pipeline.md:1200 — reply traffic of an established
-    # connection is never policy-dropped).  Refreshing the partner on EVERY
-    # hit would add a key gather + ts scatter to the throughput path
-    # (~20% measured on v5e), so it is DEFERRED: meta[:,3] (pref) records
-    # the last partner-refresh attempt, and the partner walk runs only for
-    # lanes older than ct_timeout/2 — under lax.cond, so batches with no
-    # due lane pay nothing.  Sound because a verified refresh also
-    # resurrects a stale-but-unevicted partner: the connection provably
-    # stayed active (this entry's own freshness), matching kernel ct which
-    # would have refreshed the shared entry at every packet.  The partner
-    # slot is recomputed from the cached DNAT meta and its key VERIFIED
-    # before the refresh, so an unrelated entry that evicted the partner is
-    # never life-extended.
-    #   fwd est hit:  partner = reply entry (dnat_ip, src, dnat_port, sport)
-    #   reply hit:    partner = fwd entry (dst=client, frontend ip/port)
-    p_half = max(1, meta.ct_timeout_s // 2)
-    pmask = meta.pref_mask
-    c_pref = mr[:, ZC] & pmask  # strip the cached snat/dsr(/chance) bits
-    # Age in mod-2^29 arithmetic (PREF_MASK; bits 0-28 carry pref, bit 29
-    # is CONFIRMED in the meta3 layout; under second_chance the stamp
-    # narrows to bits 0-26): exact whenever the true age < the mask
-    # width, which the idle timeout guarantees for any live entry.
-    p_need = est & (((now - c_pref) & pmask) >= p_half)
-
-    def partner_probe(keys, mask):
-        """Derive each lane's PARTNER tuple (the other conntrack direction
-        of its hit entry, un/re-DNAT applied) and key-verify it against
-        `keys` — shared by the deferred partner refresh and the FIN/RST
-        teardown so the two can never drift.  -> (p_slot, live_mask).
-
-        Dual-stack: the cached meta rows carry the 4-word DNAT / un-DNAT
-        resolution (c_dnat_w), so the wide partner tuple is the exact
-        structural mirror of the narrow one — forward hits pair with
-        (dnat, src), reply hits with (dst, cached frontend)."""
-        p_sport = jnp.where(rpl, dport, c_dport)
-        p_dport = jnp.where(rpl, c_dport, sport)
-        p_pg = jnp.where(rpl, pg_est, pg_est | REPLY_BIT)
+        # ---- fast path: flow-cache lookup (2 row gathers + 1 column gather) ----
         if A == 2:
-            p_src = jnp.where(rpl, dst_f, c_dnat_ip)
-            p_dst = jnp.where(rpl, c_dnat_ip, src_f)
-            p_addr = jnp.stack([p_src, p_dst], axis=1)
-            p_h = hashing.flow_hash(
-                _raw_bits(p_src), _raw_bits(p_dst), proto, p_sport, p_dport,
-                xp=jnp,
-            )
+            if v6 is not None:
+                raise ValueError(
+                    "v6 lanes require a dual_stack pipeline "
+                    "(make_pipeline(dual_stack=True))"
+                )
+            saddr = daddr = is6 = None  # wide-mode-only locals
+            addr = jnp.stack([src_f, dst_f], axis=1)
+            h = hashing.flow_hash(src_raw, dst_raw, proto, sport, dport, xp=jnp)
         else:
-            rplw = (rpl != 0)[:, None]
-            p_srcw = jnp.where(rplw, daddr, c_dnat_w)
-            p_dstw = jnp.where(rplw, c_dnat_w, saddr)
-            p_addr = jnp.concatenate([p_srcw, p_dstw], axis=1)
-            p_h = hashing.flow_hash_wide(
-                [p_addr[:, i] for i in range(8)], proto, p_sport, p_dport,
-                xp=jnp,
+            # Wide (dual-stack) addressing: every lane is a 4-word v4-mapped /
+            # v6 quadruple (sign-flipped per word, utils/ip.key_to_words).
+            if v6 is not None:
+                src6w, dst6w, is6 = v6
+            else:
+                is6 = jnp.zeros_like(src_f)
+                src6w = dst6w = None
+            saddr = _wide_words(src_f, src6w, is6)
+            daddr = _wide_words(dst_f, dst6w, is6)
+            addr = jnp.concatenate([saddr, daddr], axis=1)
+            h = hashing.flow_hash_wide(
+                [addr[:, i] for i in range(8)], proto, sport, dport, xp=jnp
             )
-        p_slot = (p_h & jnp.uint32(N - 1)).astype(jnp.int32)
-        pkr = keys[p_slot]
-        live = (
-            mask
-            & (pkr[:, :A] == p_addr).all(axis=1)
-            & (pkr[:, A] == ((p_sport << 16) | p_dport))
-            & (pkr[:, A + 1] == p_pg)
+        slot = (h & jnp.uint32(N - 1)).astype(jnp.int32)
+        pg_cur = proto | 0x100 | (gen_w << 9)
+        pg_est = proto | 0x100 | (GEN_ETERNAL << 9)
+        hit, est, rpl, mr, kr0, ts0 = _cache_lookup(
+            flow, slot, addr, pp, pg_cur, pg_est, now, proto, meta
         )
-        return p_slot, live
+        if valid is not None:
+            # Lane mask (SpoofGuard gating, models/forwarding.py): excluded
+            # lanes neither refresh nor commit any state and take the fast-path
+            # default image — the stage order of the reference, where
+            # SpoofGuard drops happen BEFORE conntrack/policy tables.
+            hit = hit & valid
+            est = est & valid
+            rpl = rpl & valid
+        tel_on = meta.telemetry
+        if tel_on:
+            # Probe-split telemetry (hit / stale / miss), recomputed XLA-side
+            # from the SAME gathered key rows the probe decoded (kr0), so it
+            # costs three reductions and zero extra gathers.  `stale` = the
+            # key matched but the entry aged out (the megaflow-revalidation
+            # signal: the flow was cached and expired under traffic);
+            # generation-stale denials count as plain misses — they are
+            # invisible to lookups by design, not aged occupancy.  Lanes
+            # another dispatch owns (mesh spill retries, prune_exclude) and
+            # valid-masked lanes are excluded, the exactly-once discipline
+            # prune metering already follows.
+            tv = jnp.ones(B, bool) if valid is None else (valid != 0)
+            if prune_exclude is not None:
+                tv = tv & ~prune_exclude
+            kpg0 = kr0[:, A + 1]
+            key_hit0 = (
+                (kr0[:, :A] == addr).all(axis=1)
+                & (kr0[:, A] == pp)
+                & ((kpg0 == pg_cur) | (kpg0 == pg_est)
+                   | (kpg0 == (pg_est | REPLY_BIT)))
+            )
+            tel_probe_hit = (hit & tv).sum(dtype=jnp.int32)
+            tel_probe_stale = (key_hit0 & ~hit & tv).sum(dtype=jnp.int32)
+            tel_probe_miss = (~key_hit0 & tv).sum(dtype=jnp.int32)
+        DC, M1C, RC, ZC = _meta_cols(A)
+        c_code, c_svc, c_dport = _unpack_meta1(mr[:, M1C])
+        # Narrow dnat view: the v4 value (wide worlds: word 3, the v4-mapped
+        # column — a don't-care for v6 lanes, whose consumers read c_dnat_w).
+        c_dnat_ip = mr[:, DC]
+        c_dnat_w = mr[:, 0:4] if A == 8 else None
+        c_rule_in, c_rule_out = _unpack_rules(mr[:, RC])
 
-    def partner_refresh(flow):
-        p_slot, p_live = partner_probe(flow.keys, p_need)
+    with device_scope("fast_path"), device_scope("refresh"):
+        # Idle-timeout refresh for hits.
+        flow = flow._replace(ts=flow.ts.at[jnp.where(hit, slot, dump)].set(now))
+
         if meta.second_chance:
-            # Read the CURRENT meta for the preserved high bits: the
-            # hit-path reset above already cleared the chance counter on
-            # this very slot, and re-stamping from the start-of-batch
-            # snapshot would resurrect it.
-            tgt_p = jnp.where(p_need, slot, dump)
+            # Second-chance reset: a hit is the entry's "referenced" event —
+            # clear the 2-bit collision counter so active flows keep their
+            # protection (the CLOCK-algorithm reference bit, see CHANCE_SHIFT).
+            ZC_ = _meta_cols(A)[3]
+            tgt_h = jnp.where(hit, slot, dump)
+            flow = flow._replace(meta=flow.meta.at[tgt_h, ZC_].set(
+                flow.meta[tgt_h, ZC_] & ~CHANCE_MASK))
+
+        if meta.count_flow_stats:
+            # Per-direction traffic counters (conntrack OriginalPackets/
+            # OriginalBytes, flowexporter/types.go:59): every hit adds to ITS
+            # entry's columns.  64-bit accumulation in two i32 limbs (the
+            # kernel's u64 counters; the old i32 saturation capped volumes at
+            # 2GB): the low limb adds with a wrapping scatter, and one carry
+            # per slot propagates into the high limb — exact as long as one
+            # entry receives < 2^32 bytes within a SINGLE batch (a per-batch
+            # bound, not a lifetime cap).
+            lv = jnp.zeros(B, jnp.int32) if lens is None else lens
+            ctgt = jnp.where(hit, slot, dump)
+            cwin = _winner_mask(N, slot, hit, dump)  # one carry writer per slot
+
+            def wide_add(lo, hi, add):
+                old = lo[ctgt]
+                lo = lo.at[ctgt].add(add)
+                # u32 view shrank => the slot's low limb wrapped exactly once.
+                carried = lo[ctgt].astype(jnp.uint32) < old.astype(jnp.uint32)
+                hi = hi.at[jnp.where(cwin & carried, ctgt, dump)].add(1)
+                return lo, hi
+
+            new_pk, new_pkh = wide_add(flow.pkts, flow.pkts_hi,
+                                       jnp.ones(B, jnp.int32))
+            new_oc, new_och = wide_add(flow.octets, flow.octets_hi,
+                                       jnp.maximum(lv, 0))
+            flow = flow._replace(pkts=new_pk, octets=new_oc,
+                                 pkts_hi=new_pkh, octets_hi=new_och)
+
+        # Conntrack refreshes BOTH tuple directions on traffic in either
+        # direction (one kernel-ct connection == our two cache entries): an
+        # active connection's reply leg must not idle out while forward traffic
+        # keeps flowing (ovs-pipeline.md:1200 — reply traffic of an established
+        # connection is never policy-dropped).  Refreshing the partner on EVERY
+        # hit would add a key gather + ts scatter to the throughput path
+        # (~20% measured on v5e), so it is DEFERRED: meta[:,3] (pref) records
+        # the last partner-refresh attempt, and the partner walk runs only for
+        # lanes older than ct_timeout/2 — under lax.cond, so batches with no
+        # due lane pay nothing.  Sound because a verified refresh also
+        # resurrects a stale-but-unevicted partner: the connection provably
+        # stayed active (this entry's own freshness), matching kernel ct which
+        # would have refreshed the shared entry at every packet.  The partner
+        # slot is recomputed from the cached DNAT meta and its key VERIFIED
+        # before the refresh, so an unrelated entry that evicted the partner is
+        # never life-extended.
+        #   fwd est hit:  partner = reply entry (dnat_ip, src, dnat_port, sport)
+        #   reply hit:    partner = fwd entry (dst=client, frontend ip/port)
+        p_half = max(1, meta.ct_timeout_s // 2)
+        pmask = meta.pref_mask
+        c_pref = mr[:, ZC] & pmask  # strip the cached snat/dsr(/chance) bits
+        # Age in mod-2^29 arithmetic (PREF_MASK; bits 0-28 carry pref, bit 29
+        # is CONFIRMED in the meta3 layout; under second_chance the stamp
+        # narrows to bits 0-26): exact whenever the true age < the mask
+        # width, which the idle timeout guarantees for any live entry.
+        p_need = est & (((now - c_pref) & pmask) >= p_half)
+
+        def partner_probe(keys, mask):
+            """Derive each lane's PARTNER tuple (the other conntrack direction
+            of its hit entry, un/re-DNAT applied) and key-verify it against
+            `keys` — shared by the deferred partner refresh and the FIN/RST
+            teardown so the two can never drift.  -> (p_slot, live_mask).
+
+            Dual-stack: the cached meta rows carry the 4-word DNAT / un-DNAT
+            resolution (c_dnat_w), so the wide partner tuple is the exact
+            structural mirror of the narrow one — forward hits pair with
+            (dnat, src), reply hits with (dst, cached frontend)."""
+            p_sport = jnp.where(rpl, dport, c_dport)
+            p_dport = jnp.where(rpl, c_dport, sport)
+            p_pg = jnp.where(rpl, pg_est, pg_est | REPLY_BIT)
+            if A == 2:
+                p_src = jnp.where(rpl, dst_f, c_dnat_ip)
+                p_dst = jnp.where(rpl, c_dnat_ip, src_f)
+                p_addr = jnp.stack([p_src, p_dst], axis=1)
+                p_h = hashing.flow_hash(
+                    _raw_bits(p_src), _raw_bits(p_dst), proto, p_sport, p_dport,
+                    xp=jnp,
+                )
+            else:
+                rplw = (rpl != 0)[:, None]
+                p_srcw = jnp.where(rplw, daddr, c_dnat_w)
+                p_dstw = jnp.where(rplw, c_dnat_w, saddr)
+                p_addr = jnp.concatenate([p_srcw, p_dstw], axis=1)
+                p_h = hashing.flow_hash_wide(
+                    [p_addr[:, i] for i in range(8)], proto, p_sport, p_dport,
+                    xp=jnp,
+                )
+            p_slot = (p_h & jnp.uint32(N - 1)).astype(jnp.int32)
+            pkr = keys[p_slot]
+            live = (
+                mask
+                & (pkr[:, :A] == p_addr).all(axis=1)
+                & (pkr[:, A] == ((p_sport << 16) | p_dport))
+                & (pkr[:, A + 1] == p_pg)
+            )
+            return p_slot, live
+
+        def partner_refresh(flow):
+            p_slot, p_live = partner_probe(flow.keys, p_need)
+            if meta.second_chance:
+                # Read the CURRENT meta for the preserved high bits: the
+                # hit-path reset above already cleared the chance counter on
+                # this very slot, and re-stamping from the start-of-batch
+                # snapshot would resurrect it.
+                tgt_p = jnp.where(p_need, slot, dump)
+                return flow._replace(
+                    ts=flow.ts.at[jnp.where(p_live, p_slot, dump)].set(now),
+                    meta=flow.meta.at[tgt_p, ZC].set(
+                        (now & pmask) | (flow.meta[tgt_p, ZC] & ~pmask)
+                    ),
+                )
             return flow._replace(
                 ts=flow.ts.at[jnp.where(p_live, p_slot, dump)].set(now),
-                meta=flow.meta.at[tgt_p, ZC].set(
-                    (now & pmask) | (flow.meta[tgt_p, ZC] & ~pmask)
+                # Attempt-time update even when the partner is gone, so an
+                # evicted partner doesn't drag the walk into every batch.
+                # Preserve the cached snat/dsr bits alongside the new stamp.
+                meta=flow.meta.at[jnp.where(p_need, slot, dump), ZC].set(
+                    (now & pmask) | (mr[:, ZC] & ~pmask)
                 ),
             )
-        return flow._replace(
-            ts=flow.ts.at[jnp.where(p_live, p_slot, dump)].set(now),
-            # Attempt-time update even when the partner is gone, so an
-            # evicted partner doesn't drag the walk into every batch.
-            # Preserve the cached snat/dsr bits alongside the new stamp.
-            meta=flow.meta.at[jnp.where(p_need, slot, dump), ZC].set(
-                (now & pmask) | (mr[:, ZC] & ~pmask)
-            ),
-        )
 
-    flow = jax.lax.cond(p_need.any(), partner_refresh, lambda f: f, flow)
+        flow = jax.lax.cond(p_need.any(), partner_refresh, lambda f: f, flow)
 
-    # SYN_SENT -> ESTABLISHED confirmation (the kernel ct state machine's
-    # two-way-traffic transition): the FIRST reply-direction hit proves the
-    # peer answered; set CONF on the hit entry and its verified partner so
-    # both directions graduate to the confirmed lifetime.  Once per
-    # connection -> under lax.cond, zero steady-state cost.
-    conf_need = rpl & (((mr[:, ZC] >> 29) & 1) == 0)
+        # SYN_SENT -> ESTABLISHED confirmation (the kernel ct state machine's
+        # two-way-traffic transition): the FIRST reply-direction hit proves the
+        # peer answered; set CONF on the hit entry and its verified partner so
+        # both directions graduate to the confirmed lifetime.  Once per
+        # connection -> under lax.cond, zero steady-state cost.
+        conf_need = rpl & (((mr[:, ZC] >> 29) & 1) == 0)
 
-    def confirm(flow):
-        # OR into the CURRENT meta (partner_refresh may have just stamped
-        # pref on this very slot; clobbering it with the start-of-batch
-        # snapshot would diverge from the scalar oracle's pref=now).
-        m = flow.meta
-        tgt0 = jnp.where(conf_need, slot, dump)
-        m = m.at[tgt0, ZC].set(m[tgt0, ZC] | CONF_BIT)
-        c_slot, c_live = partner_probe(flow.keys, conf_need)
-        tgt = jnp.where(c_live, c_slot, dump)
-        m = m.at[tgt, ZC].set(m[tgt, ZC] | CONF_BIT)
-        return flow._replace(meta=m)
+        def confirm(flow):
+            # OR into the CURRENT meta (partner_refresh may have just stamped
+            # pref on this very slot; clobbering it with the start-of-batch
+            # snapshot would diverge from the scalar oracle's pref=now).
+            m = flow.meta
+            tgt0 = jnp.where(conf_need, slot, dump)
+            m = m.at[tgt0, ZC].set(m[tgt0, ZC] | CONF_BIT)
+            c_slot, c_live = partner_probe(flow.keys, conf_need)
+            tgt = jnp.where(c_live, c_slot, dump)
+            m = m.at[tgt, ZC].set(m[tgt, ZC] | CONF_BIT)
+            return flow._replace(meta=m)
 
-    flow = jax.lax.cond(conf_need.any(), confirm, lambda f: f, flow)
+        flow = jax.lax.cond(conf_need.any(), confirm, lambda f: f, flow)
 
-    # TCP connection teardown (conntrack close): a FIN or RST on an
-    # established entry removes BOTH tuple directions after this packet's
-    # own (still-established) verdict — subsequent same-tuple packets
-    # re-classify under the CURRENT policy instead of est-bypassing a
-    # connection that no longer exists.  Conservative vs kernel ct (which
-    # walks FIN_WAIT/TIME_WAIT): trailing segments of a closing connection
-    # re-classify; nothing ever bypasses policy MORE than the kernel.
-    # Out-of-window teardown cost: zero when no lane carries the flags.
-    if flags is not None:
-        td = est & (proto == PROTO_TCP) & ((flags & _TEARDOWN_FLAGS) != 0)
+        # TCP connection teardown (conntrack close): a FIN or RST on an
+        # established entry removes BOTH tuple directions after this packet's
+        # own (still-established) verdict — subsequent same-tuple packets
+        # re-classify under the CURRENT policy instead of est-bypassing a
+        # connection that no longer exists.  Conservative vs kernel ct (which
+        # walks FIN_WAIT/TIME_WAIT): trailing segments of a closing connection
+        # re-classify; nothing ever bypasses policy MORE than the kernel.
+        # Out-of-window teardown cost: zero when no lane carries the flags.
+        if flags is not None:
+            td = est & (proto == PROTO_TCP) & ((flags & _TEARDOWN_FLAGS) != 0)
 
-        def teardown(flow):
-            keys = flow.keys.at[jnp.where(td, slot, dump)].set(0)
-            t_slot, t_live = partner_probe(keys, td)
-            keys = keys.at[jnp.where(t_live, t_slot, dump)].set(0)
-            return flow._replace(keys=keys)
+            def teardown(flow):
+                keys = flow.keys.at[jnp.where(td, slot, dump)].set(0)
+                t_slot, t_live = partner_probe(keys, td)
+                keys = keys.at[jnp.where(t_live, t_slot, dump)].set(0)
+                return flow._replace(keys=keys)
 
-        flow = jax.lax.cond(td.any(), teardown, lambda f: f, flow)
+            flow = jax.lax.cond(td.any(), teardown, lambda f: f, flow)
 
-    miss = ~hit if valid is None else (~hit & valid)
-    n_miss = miss.sum(dtype=jnp.int32)
+    with device_scope("fast_path"), device_scope("assemble"):
+        miss = ~hit if valid is None else (~hit & valid)
+        n_miss = miss.sum(dtype=jnp.int32)
 
-    # Fast-path output images (+1 dump element for masked slow-path scatter).
-    def outbuf(vals):
-        return jnp.concatenate([vals, jnp.zeros((1,), jnp.int32)])
+        # Fast-path output images (+1 dump element for masked slow-path scatter).
+        def outbuf(vals):
+            return jnp.concatenate([vals, jnp.zeros((1,), jnp.int32)])
 
-    # ADMITTED miss lanes default to meta.miss_code: ACT_ALLOW in
-    # synchronous mode (overwritten by the slow path anyway), the
-    # admission policy's provisional verdict in the async fast step
-    # (PH_SLOW masked, misses queued for the background engine —
-    # datapath/slowpath).  Valid-masked lanes (SpoofGuard/ARP/IGMP-punt,
-    # handled BEFORE the pipeline) are NOT misses and keep the plain
-    # ALLOW image their kind overrides expect (forwarding.py) — a hold
-    # policy must never report DROP for a lane it never evaluated.
-    out_code = outbuf(jnp.where(
-        hit, c_code, jnp.where(miss, meta.miss_code, ACT_ALLOW)
-    ))
-    out_svc = outbuf(jnp.where(hit, c_svc, MISS))
-    out_dnat_ip = outbuf(jnp.where(hit, c_dnat_ip, dst_f))
-    out_dnat_port = outbuf(jnp.where(hit, c_dport, dport))
-    out_rule_in = outbuf(jnp.where(hit, c_rule_in, MISS))
-    out_rule_out = outbuf(jnp.where(hit, c_rule_out, MISS))
-    out_committed = outbuf(jnp.zeros(B, jnp.int32))
-    # SNAT mark cached in meta3's sign bit at commit time; reply-direction
-    # hits carry the un-SNAT implicitly via the restored frontend tuple.
-    c_snat = (mr[:, ZC] >> 31) & 1
-    out_snat = outbuf(jnp.where(hit & ~rpl, c_snat, 0))
-    # DSR delivery mark, pinned into the entry at commit time exactly like
-    # the SNAT mark (meta3 bit 30): service updates that renumber LB
-    # programs cannot flip an established connection's delivery mode.
-    c_dsr = (mr[:, ZC] >> 30) & 1
-    out_dsr = outbuf(jnp.where(hit & ~rpl, c_dsr, 0))
-    # Wide DNAT image ((B+1, 4), wide worlds only): cache hits read the
-    # cached word row, misses default to the literal dst words and are
-    # overwritten by the slow path.
-    if A == 8:
-        out_dnat_w = jnp.concatenate(
-            [jnp.where(hit[:, None], c_dnat_w, daddr),
-             jnp.zeros((1, 4), jnp.int32)], axis=0,
-        )
-    else:
-        out_dnat_w = None
+        # ADMITTED miss lanes default to meta.miss_code: ACT_ALLOW in
+        # synchronous mode (overwritten by the slow path anyway), the
+        # admission policy's provisional verdict in the async fast step
+        # (PH_SLOW masked, misses queued for the background engine —
+        # datapath/slowpath).  Valid-masked lanes (SpoofGuard/ARP/IGMP-punt,
+        # handled BEFORE the pipeline) are NOT misses and keep the plain
+        # ALLOW image their kind overrides expect (forwarding.py) — a hold
+        # policy must never report DROP for a lane it never evaluated.
+        out_code = outbuf(jnp.where(
+            hit, c_code, jnp.where(miss, meta.miss_code, ACT_ALLOW)
+        ))
+        out_svc = outbuf(jnp.where(hit, c_svc, MISS))
+        out_dnat_ip = outbuf(jnp.where(hit, c_dnat_ip, dst_f))
+        out_dnat_port = outbuf(jnp.where(hit, c_dport, dport))
+        out_rule_in = outbuf(jnp.where(hit, c_rule_in, MISS))
+        out_rule_out = outbuf(jnp.where(hit, c_rule_out, MISS))
+        out_committed = outbuf(jnp.zeros(B, jnp.int32))
+        # SNAT mark cached in meta3's sign bit at commit time; reply-direction
+        # hits carry the un-SNAT implicitly via the restored frontend tuple.
+        c_snat = (mr[:, ZC] >> 31) & 1
+        out_snat = outbuf(jnp.where(hit & ~rpl, c_snat, 0))
+        # DSR delivery mark, pinned into the entry at commit time exactly like
+        # the SNAT mark (meta3 bit 30): service updates that renumber LB
+        # programs cannot flip an established connection's delivery mode.
+        c_dsr = (mr[:, ZC] >> 30) & 1
+        out_dsr = outbuf(jnp.where(hit & ~rpl, c_dsr, 0))
+        # Wide DNAT image ((B+1, 4), wide worlds only): cache hits read the
+        # cached word row, misses default to the literal dst words and are
+        # overwritten by the slow path.
+        if A == 8:
+            out_dnat_w = jnp.concatenate(
+                [jnp.where(hit[:, None], c_dnat_w, daddr),
+                 jnp.zeros((1, 4), jnp.int32)], axis=0,
+            )
+        else:
+            out_dnat_w = None
 
     # Round-7 prune observability (python-static: zero ops, zero extra
     # outputs when the budget is 0 — the HLO-identity contract).
@@ -1469,6 +1474,7 @@ def _pipeline_step(
             # Phase-gated (PH_COMMIT; the eviction audit additionally
             # requires PH_COMMIT since it reads the insert targets) so the
             # profiler can isolate the commit scatters' cost.
+            @device_scope("cache_commit")
             def do_commit(flow, aff, n_evict, n_reclaim, tel_sc):
                 egen = jnp.where(committed_m, GEN_ETERNAL, gen_w)
                 pg_ins = p_m | 0x100 | (egen << 9)
@@ -1567,41 +1573,42 @@ def _pipeline_step(
                         tel_sc = tel_sc + sc_n
 
                 if meta.phases & PH_EVICT:
-                    # Eviction accounting (round-2 verdict weak #5:
-                    # quantify the direct-mapped collision cost): an
-                    # insert over a live entry whose TUPLE differs (cols
-                    # 0-2 + proto/direction bits of col 3 — a same-tuple
-                    # rewrite is an update, not an eviction).
-                    tgt2 = jnp.where(ins2, slot2, dump)
-                    okr = flow.keys[tgt2]
-                    id3 = 0xFF | REPLY_BIT
-                    tuple_differs = (
-                        (okr[:, : A + 1] != keys2[:, : A + 1]).any(axis=1)
-                        | ((okr[:, A + 1] & id3) != (keys2[:, A + 1] & id3))
-                    )
-                    overwrote = ins2 & (okr[:, A + 1] != 0) & tuple_differs
-                    if meta.drain_reclaim:
-                        # Fused maintenance (overlapped drain): a target
-                        # row that is DEAD to lookups — idle-expired per
-                        # its per-state timeout, or a stale-generation
-                        # denial — is reclaimed occupancy, not a live
-                        # eviction; the drain round ages/revalidates the
-                        # rows it touches in the pass that already
-                        # gathered them (the ts/conf reads ride the same
-                        # tgt2 the audit uses).
-                        om3 = flow.meta[tgt2, ZC]
-                        otmo = entry_timeout(
-                            (om3 >> 29) & 1, okr[:, A + 1] & 0xFF,
-                            meta.timeouts,
+                    with device_scope("eviction_scan"):
+                        # Eviction accounting (round-2 verdict weak #5:
+                        # quantify the direct-mapped collision cost): an
+                        # insert over a live entry whose TUPLE differs (cols
+                        # 0-2 + proto/direction bits of col 3 — a same-tuple
+                        # rewrite is an update, not an eviction).
+                        tgt2 = jnp.where(ins2, slot2, dump)
+                        okr = flow.keys[tgt2]
+                        id3 = 0xFF | REPLY_BIT
+                        tuple_differs = (
+                            (okr[:, : A + 1] != keys2[:, : A + 1]).any(axis=1)
+                            | ((okr[:, A + 1] & id3) != (keys2[:, A + 1] & id3))
                         )
-                        ogen = (okr[:, A + 1] >> 9) & GEN_ETERNAL
-                        dead = ((now - flow.ts[tgt2]) > otmo) | (
-                            (ogen != GEN_ETERNAL) & (ogen != gen_w)
-                        )
-                        n_reclaim = n_reclaim + (overwrote & dead).sum(
-                            dtype=jnp.int32)
-                        overwrote = overwrote & ~dead
-                    n_evict = n_evict + overwrote.sum(dtype=jnp.int32)
+                        overwrote = ins2 & (okr[:, A + 1] != 0) & tuple_differs
+                        if meta.drain_reclaim:
+                            # Fused maintenance (overlapped drain): a target
+                            # row that is DEAD to lookups — idle-expired per
+                            # its per-state timeout, or a stale-generation
+                            # denial — is reclaimed occupancy, not a live
+                            # eviction; the drain round ages/revalidates the
+                            # rows it touches in the pass that already
+                            # gathered them (the ts/conf reads ride the same
+                            # tgt2 the audit uses).
+                            om3 = flow.meta[tgt2, ZC]
+                            otmo = entry_timeout(
+                                (om3 >> 29) & 1, okr[:, A + 1] & 0xFF,
+                                meta.timeouts,
+                            )
+                            ogen = (okr[:, A + 1] >> 9) & GEN_ETERNAL
+                            dead = ((now - flow.ts[tgt2]) > otmo) | (
+                                (ogen != GEN_ETERNAL) & (ogen != gen_w)
+                            )
+                            n_reclaim = n_reclaim + (overwrote & dead).sum(
+                                dtype=jnp.int32)
+                            overwrote = overwrote & ~dead
+                        n_evict = n_evict + overwrote.sum(dtype=jnp.int32)
 
                 if meta.count_flow_stats:
                     # Fresh entries start at this packet's contribution on
@@ -2119,97 +2126,99 @@ def _pipeline_step(
     def noop(args):
         return args
 
-    slow_init = (flow, aff, (out_code, out_svc, out_dnat_ip, out_dnat_port,
-                             out_rule_in, out_rule_out, out_committed,
-                             out_snat, out_dsr, jnp.int32(0),
-                             jnp.int32(0)) + (
-                             (out_dnat_w,) if A == 8 else ()) + ((
-                             jnp.int32(0), jnp.int32(0),
-                             jnp.zeros(len(PRUNE_HIST_BOUNDS) + 2,
-                                       jnp.int32)) if prune_on else ()) + (
-                             (jnp.int32(0), jnp.int32(0))
-                             if tel_on else ()))
-    if meta.phases & PH_SLOW:
-        slow_body = slow_onepass if meta.onepass else slow
-        flow, aff, outs = jax.lax.cond(n_miss > 0, slow_body, noop,
-                                       slow_init)
-    else:
-        # Slow path masked out entirely (profiling floor): misses keep the
-        # fast-path default image and commit nothing.
-        flow, aff, outs = slow_init
+    with device_scope("miss_detect"):
+        slow_init = (flow, aff, (out_code, out_svc, out_dnat_ip, out_dnat_port,
+                                 out_rule_in, out_rule_out, out_committed,
+                                 out_snat, out_dsr, jnp.int32(0),
+                                 jnp.int32(0)) + (
+                                 (out_dnat_w,) if A == 8 else ()) + ((
+                                 jnp.int32(0), jnp.int32(0),
+                                 jnp.zeros(len(PRUNE_HIST_BOUNDS) + 2,
+                                           jnp.int32)) if prune_on else ()) + (
+                                 (jnp.int32(0), jnp.int32(0))
+                                 if tel_on else ()))
+        if meta.phases & PH_SLOW:
+            slow_body = slow_onepass if meta.onepass else slow
+            flow, aff, outs = jax.lax.cond(n_miss > 0, slow_body, noop,
+                                           slow_init)
+        else:
+            # Slow path masked out entirely (profiling floor): misses keep the
+            # fast-path default image and commit nothing.
+            flow, aff, outs = slow_init
     (out_code, out_svc, out_dnat_ip, out_dnat_port,
      out_rule_in, out_rule_out, out_committed, out_snat, out_dsr,
      n_evict, n_reclaim) = outs[:11]
     if A == 8:
         out_dnat_w = outs[11]
 
-    final_code = out_code[:B]
-    out = {
-        "code": final_code,
-        "est": est.astype(jnp.int32),
-        # Reply-direction hit: dnat_ip_f/dnat_port carry the UN-DNAT rewrite
-        # (the frontend tuple the reply's SOURCE is restored to), not a
-        # destination rewrite.
-        "reply": rpl.astype(jnp.int32),
-        # REJECT synthesis kind (reject.go analog), derived from the
-        # packet's own proto so cached REJECT hits get the right kind too.
-        "reject_kind": reject_kind_of(final_code, proto),
-        "svc_idx": out_svc[:B],
-        "dnat_ip_f": out_dnat_ip[:B],
-        "dnat_port": out_dnat_port[:B],
-        "ingress_rule": out_rule_in[:B],
-        "egress_rule": out_rule_out[:B],
-        "committed": out_committed[:B],
-        # Per-lane cache-miss mask (1 = this lane took / would take the
-        # slow path).  In synchronous mode an informational overlay; in
-        # the async fast step (PH_SLOW masked) it is the miss-queue
-        # ADMISSION mask the engine consumes (datapath/slowpath).
-        "miss": miss.astype(jnp.int32),
-        # SNAT-mark classification (pipeline.go SNATMark analog): external
-        # frontend traffic under ETP=Cluster needs masquerade on egress.
-        "snat": out_snat[:B],
-        # DSR delivery mark (pipeline.go:145 DSRServiceMarkTable): forward
-        # toward dnat_ip_f (the selected endpoint) but do NOT rewrite the
-        # L3 destination and do NOT SNAT; the endpoint owns the VIP and
-        # replies straight to the client (pipeline.go:698-708).
-        "dsr": out_dsr[:B],
-        "n_miss": n_miss,
-        # Live entries overwritten by a different tuple this step (the
-        # direct-mapped collision cost; weak-#5 measurement surface).
-        "n_evict": n_evict,
-        # Dead rows (idle-expired / stale-gen) reclaimed by inserts —
-        # split out of n_evict only under meta.drain_reclaim (the
-        # overlapped drain's fused maintenance); always 0 otherwise.
-        "n_reclaim": n_reclaim,
-    }
-    if prune_on:
-        pos = 11 + (1 if A == 8 else 0)
-        # Round-7 prune observability, aggregated over the slow-path
-        # rounds (valid lanes only): aggregate-AND-zero short circuits,
-        # full-width fallback redispatches, and the candidate-superblock
-        # bucket counts + value sum (_prune_bucket_counts layout).  Keys
-        # exist iff prune_budget > 0, so the unpruned step's output
-        # pytree — and its compiled HLO — is unchanged.
-        out["n_prune_skips"] = outs[pos]
-        out["n_prune_fb"] = outs[pos + 1]
-        out["prune_cand_hist"] = outs[pos + 2]
-    if A == 8:
-        # Wide (4-word) DNAT resolution — the full-address view v6
-        # consumers (forwarding, StepResult) read; v4 lanes' word 3 equals
-        # dnat_ip_f.  Reply hits carry the un-DNAT frontend words.
-        out["dnat_w_f"] = out_dnat_w[:B]
-    if tel_on:
-        # Hot-path telemetry counters (observability/telemetry.py
-        # TELEMETRY_COUNTERS): keys exist iff meta.telemetry, so the off
-        # path's output pytree — and its compiled HLO — is unchanged.
-        # The prune trio above doubles as the telemetry candidate-hist /
-        # skip / fallback source when prune_budget > 0.
-        pos_t = 11 + (1 if A == 8 else 0) + (3 if prune_on else 0)
-        out["tel_probe_hit"] = tel_probe_hit
-        out["tel_probe_stale"] = tel_probe_stale
-        out["tel_probe_miss"] = tel_probe_miss
-        out["tel_dma_hb"] = outs[pos_t]
-        out["tel_chance_bumps"] = outs[pos_t + 1]
+    with device_scope("fast_path"), device_scope("assemble"):
+        final_code = out_code[:B]
+        out = {
+            "code": final_code,
+            "est": est.astype(jnp.int32),
+            # Reply-direction hit: dnat_ip_f/dnat_port carry the UN-DNAT rewrite
+            # (the frontend tuple the reply's SOURCE is restored to), not a
+            # destination rewrite.
+            "reply": rpl.astype(jnp.int32),
+            # REJECT synthesis kind (reject.go analog), derived from the
+            # packet's own proto so cached REJECT hits get the right kind too.
+            "reject_kind": reject_kind_of(final_code, proto),
+            "svc_idx": out_svc[:B],
+            "dnat_ip_f": out_dnat_ip[:B],
+            "dnat_port": out_dnat_port[:B],
+            "ingress_rule": out_rule_in[:B],
+            "egress_rule": out_rule_out[:B],
+            "committed": out_committed[:B],
+            # Per-lane cache-miss mask (1 = this lane took / would take the
+            # slow path).  In synchronous mode an informational overlay; in
+            # the async fast step (PH_SLOW masked) it is the miss-queue
+            # ADMISSION mask the engine consumes (datapath/slowpath).
+            "miss": miss.astype(jnp.int32),
+            # SNAT-mark classification (pipeline.go SNATMark analog): external
+            # frontend traffic under ETP=Cluster needs masquerade on egress.
+            "snat": out_snat[:B],
+            # DSR delivery mark (pipeline.go:145 DSRServiceMarkTable): forward
+            # toward dnat_ip_f (the selected endpoint) but do NOT rewrite the
+            # L3 destination and do NOT SNAT; the endpoint owns the VIP and
+            # replies straight to the client (pipeline.go:698-708).
+            "dsr": out_dsr[:B],
+            "n_miss": n_miss,
+            # Live entries overwritten by a different tuple this step (the
+            # direct-mapped collision cost; weak-#5 measurement surface).
+            "n_evict": n_evict,
+            # Dead rows (idle-expired / stale-gen) reclaimed by inserts —
+            # split out of n_evict only under meta.drain_reclaim (the
+            # overlapped drain's fused maintenance); always 0 otherwise.
+            "n_reclaim": n_reclaim,
+        }
+        if prune_on:
+            pos = 11 + (1 if A == 8 else 0)
+            # Round-7 prune observability, aggregated over the slow-path
+            # rounds (valid lanes only): aggregate-AND-zero short circuits,
+            # full-width fallback redispatches, and the candidate-superblock
+            # bucket counts + value sum (_prune_bucket_counts layout).  Keys
+            # exist iff prune_budget > 0, so the unpruned step's output
+            # pytree — and its compiled HLO — is unchanged.
+            out["n_prune_skips"] = outs[pos]
+            out["n_prune_fb"] = outs[pos + 1]
+            out["prune_cand_hist"] = outs[pos + 2]
+        if A == 8:
+            # Wide (4-word) DNAT resolution — the full-address view v6
+            # consumers (forwarding, StepResult) read; v4 lanes' word 3 equals
+            # dnat_ip_f.  Reply hits carry the un-DNAT frontend words.
+            out["dnat_w_f"] = out_dnat_w[:B]
+        if tel_on:
+            # Hot-path telemetry counters (observability/telemetry.py
+            # TELEMETRY_COUNTERS): keys exist iff meta.telemetry, so the off
+            # path's output pytree — and its compiled HLO — is unchanged.
+            # The prune trio above doubles as the telemetry candidate-hist /
+            # skip / fallback source when prune_budget > 0.
+            pos_t = 11 + (1 if A == 8 else 0) + (3 if prune_on else 0)
+            out["tel_probe_hit"] = tel_probe_hit
+            out["tel_probe_stale"] = tel_probe_stale
+            out["tel_probe_miss"] = tel_probe_miss
+            out["tel_dma_hb"] = outs[pos_t]
+            out["tel_chance_bumps"] = outs[pos_t + 1]
     return PipelineState(flow=flow, aff=aff), out
 
 

@@ -38,6 +38,14 @@ bounded drop-oldest span table served at `GET /realization?uid=`
 `realization.json` in the support bundle, and a `realization` event in
 the flight recorder per closed span.  Bookkeeping cost is budgeted by
 the maintenance scheduler's `observability` task.
+
+The HOT path has its own tracer here too (`StepTracer`): one record per
+`Datapath.step` call — the `step` span, its seven host phases
+(STEP_PHASES) and the transfer counters — kept in a bounded in-memory
+ring and mirrored into the JAX profiler's trace as `tpuflow.step[.phase]`
+annotations; STEP_SCOPES (declared in the leaf module ops/scopes.py,
+re-exported here) names the scopes the device program's ops
+carry, so a device trace files every op under the same cut.
 """
 
 from __future__ import annotations
@@ -46,6 +54,9 @@ import time
 from collections import OrderedDict
 from typing import Optional
 
+import numpy as np
+
+from ..ops.scopes import STEP_SCOPES, device_scope  # noqa: F401
 from .metrics import Histogram
 
 # Stage DURATIONS of one realization span, in causal order; each is the
@@ -63,6 +74,130 @@ _HIST_STAGES = REALIZATION_STAGES + ("total",)
 # Commit-plane stamp names in transaction order (tracked per commit, then
 # grafted onto every span the commit realized).
 _COMMIT_STAMPS = ("start", "compile", "canary", "swap", "settle")
+
+# Host phases of ONE `Datapath.step` call, in order; contiguous children
+# of the parent span `step` (phase k ends where phase k+1 begins).  The
+# ONE place the phase names are spelled: engines stamp by index
+# (StepTracer.phase), readers take the names from here.
+STEP_PHASES = (
+    "stage", "upload", "dispatch", "wait", "fetch", "account", "attribute",
+)
+
+# Boundary indices for StepTracer.phase: SP_<PHASE> is where that phase
+# begins, SP_DONE closes the last one.
+(SP_STAGE, SP_UPLOAD, SP_DISPATCH, SP_WAIT, SP_FETCH, SP_ACCOUNT,
+ SP_ATTRIBUTE, SP_DONE) = range(len(STEP_PHASES) + 1)
+
+# Steps the in-memory ring keeps (drop-oldest, drops metered).
+STEP_RING_SLOTS = 4096
+
+# One ring row.  `t_*` are time.perf_counter_ns() readings: the `step`
+# span runs t_start..t_end, phase p runs from t_<p> to the next field
+# (t_done closes the last phase), so the ten stamps are monotonic and
+# the phases telescope to t_done - t_stage exactly.  The four counters
+# are taken where the transfer is issued: one per host->device upload
+# and its nbytes, one per fetched output and its nbytes.
+STEP_RECORD = np.dtype(
+    [("seq", "<i8"), ("lanes", "<i8"), ("n_miss", "<i8"), ("t_start", "<i8")]
+    + [(f"t_{p}", "<i8") for p in STEP_PHASES]
+    + [("t_done", "<i8"), ("t_end", "<i8"), ("h2d_transfers", "<i8"),
+       ("h2d_bytes", "<i8"), ("d2h_transfers", "<i8"), ("d2h_bytes", "<i8")]
+)
+_N_STAMPS = len(STEP_PHASES) + 3  # start, one per phase, done, end
+
+
+class StepTracer:
+    """Phase spans + transfer counters of every `step` call, always on.
+
+    Owned by the engine (one per datapath, shared by its tenant worlds);
+    single-threaded like `step` itself.  `begin` opens the `step` span,
+    `phase(k)` is the boundary where STEP_PHASES[k] begins (k ==
+    len(STEP_PHASES) closes the last), `end` closes the span, writes the
+    ring row and returns the span's seconds — the ONE clock pair
+    `step_hist` and the telemetry fold are fed from.  A `_step` that
+    raised leaves its unreached boundaries on the end stamp (zero-width
+    phases), never an open record.  Every span is also a
+    `jax.profiler.TraceAnnotation` (`tpuflow.step`, `tpuflow.step.<phase>`)
+    — free while no profiler session runs, and in a traced run an event on
+    the device ops' timeline.
+    """
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
+        self._names = tuple(f"tpuflow.step.{p}" for p in STEP_PHASES)
+        self.slots = STEP_RING_SLOTS
+        self._clock = time.perf_counter_ns  # the benchmark client's clock
+        self._ring = np.zeros(self.slots, STEP_RECORD)
+        self._rows = self._ring.view(np.int64).reshape(self.slots, -1)
+        self.steps_total = 0
+        self.dropped = 0
+        self._stamps: list = []
+        self._span = self._child = None
+        self._lanes = 0
+        self.n_miss = 0
+        self.h2d_transfers = self.h2d_bytes = 0
+        self.d2h_transfers = self.d2h_bytes = 0
+
+    def begin(self, lanes: int) -> None:
+        self._lanes = int(lanes)
+        self.n_miss = 0
+        self.h2d_transfers = self.h2d_bytes = 0
+        self.d2h_transfers = self.d2h_bytes = 0
+        self._span = self._annotate("tpuflow.step",
+                                    seq=self.steps_total + 1)
+        self._span.__enter__()
+        self._stamps = [self._clock()]
+
+    def phase(self, k: int) -> None:
+        t = self._clock()
+        if self._child is not None:
+            self._child.__exit__(None, None, None)
+            self._child = None
+        self._stamps.append(t)
+        if k < len(self._names):
+            self._child = self._annotate(self._names[k])
+            self._child.__enter__()
+
+    def since(self, k: int) -> float:
+        """Seconds from where phase k began to the latest boundary."""
+        return (self._stamps[-1] - self._stamps[k + 1]) * 1e-9
+
+    def uploaded(self, x):
+        """Count one host->device transfer where it is issued; -> x."""
+        self.h2d_transfers += 1
+        self.h2d_bytes += x.nbytes
+        return x
+
+    def fetched(self, x):
+        """Count one device->host copy where it lands; -> x."""
+        self.d2h_transfers += 1
+        self.d2h_bytes += x.nbytes
+        return x
+
+    def end(self) -> float:
+        t = self._clock()
+        if self._child is not None:
+            self._child.__exit__(None, None, None)
+            self._child = None
+        self._span.__exit__(None, None, None)
+        ts = self._stamps
+        ts.extend([t] * (_N_STAMPS - len(ts)))
+        seq = self.steps_total = self.steps_total + 1
+        if seq > self.slots:
+            self.dropped += 1  # the oldest row, overwritten here
+        self._rows[(seq - 1) % self.slots] = (
+            seq, self._lanes, self.n_miss, *ts, self.h2d_transfers,
+            self.h2d_bytes, self.d2h_transfers, self.d2h_bytes)
+        return (t - ts[0]) * 1e-9
+
+    def records(self) -> np.ndarray:
+        """The closed records the ring still holds, oldest first (a
+        copy: the ring is overwritten in place)."""
+        if self.steps_total <= self.slots:
+            return self._ring[:self.steps_total].copy()
+        return np.roll(self._ring, -(self.steps_total % self.slots))
 
 
 class RealizationTracer:
@@ -229,6 +364,22 @@ class RealizationTracer:
         """The transaction rolled back: nothing realized, drop the
         stamps (the retry's own transaction re-stamps from compile)."""
         self._open_commit = None
+
+    def last_commit(self) -> Optional[dict]:
+        """Stage seconds of the last settled commit transaction, readable
+        without a realization span (a direct `install_bundle` opens
+        none): {"generation", "compile_s", "canary_s", "swap_s",
+        "settle_s"}, telescoping to settle - start.  `compile` runs from
+        commit_begin to the stamp after the engine built and uploaded the
+        candidate (snapshot + host rule compile + upload); `canary` is
+        the fresh-probe gate.  None before the first commit."""
+        if self._last_commit is None:
+            return None
+        gen, stamps = self._last_commit
+        out = {"generation": gen}
+        for prev, stage in zip(_COMMIT_STAMPS, _COMMIT_STAMPS[1:]):
+            out[f"{stage}_s"] = stamps[stage] - stamps[prev]
+        return out
 
     # -- the first-hit latch (engines' step()) -------------------------------
 

@@ -52,6 +52,7 @@ from ..compiler.compile import (
     DirectionTensors,
 )
 from ..utils import ip as iputil
+from .scopes import device_scope
 
 # "No match" sentinel for first-match indices.  Deliberately a PYTHON int,
 # not an eager jnp scalar: a concrete device array captured by a jitted
@@ -1210,6 +1211,7 @@ def _searchsorted6(bounds6: jax.Array, xw: jax.Array) -> jax.Array:
     return leq.sum(axis=1, dtype=jnp.int32)
 
 
+@device_scope("classify")
 def classify_batch(
     drs: DeviceRuleSet,
     src_ip_f: jax.Array,  # (B,) sign-flipped i32
@@ -1279,65 +1281,67 @@ def classify_batch(
     def iso_bit(tab: IsoTable, x: jax.Array, x6w=None) -> jax.Array:
         return tab.val[_dim_index(tab, x, x6w, is6)]
 
-    # Ingress: pod = dst, peer = src.  Egress: pod = src, peer = dst.
-    s6 = src6w if v6 is not None else None
-    d6 = dst6w if v6 is not None else None
-    in_at = dim_row(ing.at, dst_ip_f, d6)
-    in_peer = dim_row(ing.peer, src_ip_f, s6)
-    in_svc = dim_row(ing.svc, svc_key)
-    out_at = dim_row(eg.at, src_ip_f, s6)
-    out_peer = dim_row(eg.peer, dst_ip_f, d6)
-    out_svc = dim_row(eg.svc, svc_key)
-    if meta.svcref:
-        # toServices probe (the ServiceGroupID-conjunction analog): a
-        # second egress svc-dim gather keyed on the lane's ServiceLB
-        # resolution in the reference sub-space.  OR is exact — ordinary
-        # port ranges live below SVCREF_BASE and reference ranges at
-        # SVCREF_BASE + idx, so each rule can match via exactly one of
-        # the two probes (compiler/compile.py SVCREF_BASE contract).
-        out_svc = out_svc | dim_row(eg.svc, _svcref_key(svc_key, svc_ref))
-    iso_in = iso_bit(drs.iso_in, dst_ip_f, d6)
-    iso_out = iso_bit(drs.iso_out, src_ip_f, s6)
+    with device_scope("classify.candidate"):
+        # Ingress: pod = dst, peer = src.  Egress: pod = src, peer = dst.
+        s6 = src6w if v6 is not None else None
+        d6 = dst6w if v6 is not None else None
+        in_at = dim_row(ing.at, dst_ip_f, d6)
+        in_peer = dim_row(ing.peer, src_ip_f, s6)
+        in_svc = dim_row(ing.svc, svc_key)
+        out_at = dim_row(eg.at, src_ip_f, s6)
+        out_peer = dim_row(eg.peer, dst_ip_f, d6)
+        out_svc = dim_row(eg.svc, svc_key)
+        if meta.svcref:
+            # toServices probe (the ServiceGroupID-conjunction analog): a
+            # second egress svc-dim gather keyed on the lane's ServiceLB
+            # resolution in the reference sub-space.  OR is exact — ordinary
+            # port ranges live below SVCREF_BASE and reference ranges at
+            # SVCREF_BASE + idx, so each rule can match via exactly one of
+            # the two probes (compiler/compile.py SVCREF_BASE contract).
+            out_svc = out_svc | dim_row(eg.svc, _svcref_key(svc_key, svc_ref))
+        iso_in = iso_bit(drs.iso_in, dst_ip_f, d6)
+        iso_out = iso_bit(drs.iso_out, src_ip_f, s6)
 
-    if meta.delta_slots > 0:
-        # Incremental membership deltas patch the gathered rows, so peer/
-        # appliedTo/isolation consumers all see post-delta membership.
-        # Slots are family-pure: v4 slots patch v4 lanes on the narrow
-        # column, v6 slots patch v6 lanes on their wide words — v6 pod
-        # churn stays O(1), no recompile (DeltaTable docstring).
-        d = drs.ip_delta
-        wide_d = None if v6 is None else (d6, is6)
-        wide_s = None if v6 is None else (s6, is6)
-        in_at = _patch_rows(in_at, dst_ip_f, d, d.at_in, wide_d)
-        in_peer = _patch_rows(in_peer, src_ip_f, d, d.peer_in, wide_s)
-        out_at = _patch_rows(out_at, src_ip_f, d, d.at_out, wide_s)
-        out_peer = _patch_rows(out_peer, dst_ip_f, d, d.peer_out, wide_d)
-        iso_in = _patch_iso(iso_in, dst_ip_f, d, 0, wide_d)
-        iso_out = _patch_iso(iso_out, src_ip_f, d, 1, wide_s)
+        if meta.delta_slots > 0:
+            # Incremental membership deltas patch the gathered rows, so peer/
+            # appliedTo/isolation consumers all see post-delta membership.
+            # Slots are family-pure: v4 slots patch v4 lanes on the narrow
+            # column, v6 slots patch v6 lanes on their wide words — v6 pod
+            # churn stays O(1), no recompile (DeltaTable docstring).
+            d = drs.ip_delta
+            wide_d = None if v6 is None else (d6, is6)
+            wide_s = None if v6 is None else (s6, is6)
+            in_at = _patch_rows(in_at, dst_ip_f, d, d.at_in, wide_d)
+            in_peer = _patch_rows(in_peer, src_ip_f, d, d.peer_in, wide_s)
+            out_at = _patch_rows(out_at, src_ip_f, d, d.at_out, wide_s)
+            out_peer = _patch_rows(out_peer, dst_ip_f, d, d.peer_out, wide_d)
+            iso_in = _patch_iso(iso_in, dst_ip_f, d, 0, wide_d)
+            iso_out = _patch_iso(iso_out, src_ip_f, d, 1, wide_s)
 
-    if fused:
-        shard = hit_combine is not None
-        in_hits, out_hits = _fused_hits(
-            (in_at, in_peer, in_svc), (out_at, out_peer, out_svc), meta,
-            w0_in=ing.word_idx[0] if shard else None,
-            w0_out=eg.word_idx[0] if shard else None,
-        )
-    else:
-        in_hits = _phase_hits(
-            in_at & in_peer & in_svc, ing.word_idx, meta.in_phases
-        )
-        out_hits = _phase_hits(
-            out_at & out_peer & out_svc, eg.word_idx, meta.out_phases
-        )
+    with device_scope("classify.scan"):
+        if fused:
+            shard = hit_combine is not None
+            in_hits, out_hits = _fused_hits(
+                (in_at, in_peer, in_svc), (out_at, out_peer, out_svc), meta,
+                w0_in=ing.word_idx[0] if shard else None,
+                w0_out=eg.word_idx[0] if shard else None,
+            )
+        else:
+            in_hits = _phase_hits(
+                in_at & in_peer & in_svc, ing.word_idx, meta.in_phases
+            )
+            out_hits = _phase_hits(
+                out_at & out_peer & out_svc, eg.word_idx, meta.out_phases
+            )
 
-    if hit_combine is not None:
-        in_hits = tuple(hit_combine(h) for h in in_hits)
-        out_hits = tuple(hit_combine(h) for h in out_hits)
+        if hit_combine is not None:
+            in_hits = tuple(hit_combine(h) for h in in_hits)
+            out_hits = tuple(hit_combine(h) for h in out_hits)
 
-    in_code, in_rule = _resolve(ing.action, in_hits, iso_in)
-    out_code, out_rule = _resolve(eg.action, out_hits, iso_out)
+        in_code, in_rule = _resolve(ing.action, in_hits, iso_in)
+        out_code, out_rule = _resolve(eg.action, out_hits, iso_out)
 
-    final = jnp.where(out_code != ACT_ALLOW, out_code, in_code)
+        final = jnp.where(out_code != ACT_ALLOW, out_code, in_code)
     return {
         "code": final,
         "egress_code": out_code,
@@ -1490,6 +1494,7 @@ def _consumer_call(b, w_in, w_out, in_phases, out_phases, interpret,
                   for w in (w_in, w_in, w_in, w_out, w_out, w_out)] + extra,
         out_specs=pl.BlockSpec((tb, 8), lambda i: (i, 0)),
         interpret=interpret,
+        name="classify_consumer",
     )
 
 
@@ -1567,6 +1572,7 @@ def _pruned_consumer_call(b, kw_in, kw_out, in_phases, out_phases, interpret):
                             kw_out, kw_out, kw_out, kw_out)],
         out_specs=pl.BlockSpec((tb, 8), lambda i: (i, 0)),
         interpret=interpret,
+        name="classify_pruned_consumer",
     )
 
 
@@ -1624,25 +1630,26 @@ def _classify_pruned(
     def dim_idx(tab, x, x6w):
         return _dim_index(tab, x, x6w, is6)
 
-    iv_in_at = dim_idx(ing.at, dst_ip_f, dst6w)
-    iv_in_peer = dim_idx(ing.peer, src_ip_f, src6w)
-    iv_in_svc = dim_idx(ing.svc, svc_key, None)
-    iv_out_at = dim_idx(eg.at, src_ip_f, src6w)
-    iv_out_peer = dim_idx(eg.peer, dst_ip_f, dst6w)
-    iv_out_svc = dim_idx(eg.svc, svc_key, None)
-    iv_ref = None
-    if meta.svcref:
-        iv_ref = dim_idx(eg.svc, _svcref_key(svc_key, svc_ref), None)
+    with device_scope("classify.summary"):
+        iv_in_at = dim_idx(ing.at, dst_ip_f, dst6w)
+        iv_in_peer = dim_idx(ing.peer, src_ip_f, src6w)
+        iv_in_svc = dim_idx(ing.svc, svc_key, None)
+        iv_out_at = dim_idx(eg.at, src_ip_f, src6w)
+        iv_out_peer = dim_idx(eg.peer, dst_ip_f, dst6w)
+        iv_out_svc = dim_idx(eg.svc, svc_key, None)
+        iv_ref = None
+        if meta.svcref:
+            iv_ref = dim_idx(eg.svc, _svcref_key(svc_key, svc_ref), None)
 
-    iso_in = drs.iso_in.val[dim_idx(drs.iso_in, dst_ip_f, dst6w)]
-    iso_out = drs.iso_out.val[dim_idx(drs.iso_out, src_ip_f, src6w)]
+        iso_in = drs.iso_in.val[dim_idx(drs.iso_in, dst_ip_f, dst6w)]
+        iso_out = drs.iso_out.val[dim_idx(drs.iso_out, src_ip_f, src6w)]
 
-    d = drs.ip_delta if meta.delta_slots > 0 else None
-    wide_d = None if v6 is None else (dst6w, is6)
-    wide_s = None if v6 is None else (src6w, is6)
-    if d is not None:
-        iso_in = _patch_iso(iso_in, dst_ip_f, d, 0, wide_d)
-        iso_out = _patch_iso(iso_out, src_ip_f, d, 1, wide_s)
+        d = drs.ip_delta if meta.delta_slots > 0 else None
+        wide_d = None if v6 is None else (dst6w, is6)
+        wide_s = None if v6 is None else (src6w, is6)
+        if d is not None:
+            iso_in = _patch_iso(iso_in, dst_ip_f, d, 0, wide_d)
+            iso_out = _patch_iso(iso_out, src_ip_f, d, 1, wide_s)
 
     # Per-direction dimension wiring: (tables, interval rows, probe ip
     # column + wide words per ip dim, delta masks, phases).  Ingress: pod
@@ -1672,11 +1679,13 @@ def _classify_pruned(
         g = a & p & s
         return g, (g != jnp.uint32(0)).sum(axis=1, dtype=jnp.int32)
 
-    g_in, nc_in = agg_and(dir_in)
-    g_out, nc_out = agg_and(dir_out)
-    BIGS = jnp.full((B,), BIG, jnp.int32)
-    no_fb = jnp.zeros((B,), bool)
+    with device_scope("classify.summary"):
+        g_in, nc_in = agg_and(dir_in)
+        g_out, nc_out = agg_and(dir_out)
+        BIGS = jnp.full((B,), BIG, jnp.int32)
+        no_fb = jnp.zeros((B,), bool)
 
+    @device_scope("classify.candidate")
     def cand_mats(dc, g):
         """Phase-2 candidate gather for one direction -> ((ca, cp, cs,
         base) flattened to (B, Ke*AGG_BLOCK), Ke); the caller derives the
@@ -1740,104 +1749,105 @@ def _classify_pruned(
                              sub(dc["w_peer"]))
         return _phase_hits(ra & rp & rs, dd.word_idx, dc["phases"])
 
-    if summary_only:
-        in_hits = (BIGS, BIGS, BIGS)
-        out_hits = (BIGS, BIGS, BIGS)
-        fb = no_fb
-    else:
-        def phase2(_):
-            mats_in, ke_in = cand_mats(dir_in, g_in)
-            mats_out, ke_out = cand_mats(dir_out, g_out)
-            if fused:
-                pad = (-B) % _FUSE_TB
-                if pad:
-                    mats_in = tuple(jnp.pad(x, ((0, pad), (0, 0)))
-                                    for x in mats_in)
-                    mats_out = tuple(jnp.pad(x, ((0, pad), (0, 0)))
-                                     for x in mats_out)
-                call = _pruned_consumer_call(
-                    B + pad, ke_in * AGG_BLOCK, ke_out * AGG_BLOCK,
-                    meta.in_phases, meta.out_phases,
-                    pallas_interpret(meta),
-                )
-                hits = call(*mats_in, *mats_out)[:B]
-                hits6 = tuple(hits[:, i] for i in range(6))
-            else:
-                ia, ipr, isv, bi = mats_in
-                oa, opr, osv, bo = mats_out
-                hits6 = (_phase_first_from_base(ia & ipr & isv, bi,
-                                                meta.in_phases)
-                         + _phase_first_from_base(oa & opr & osv, bo,
-                                                  meta.out_phases))
-            fb = (nc_in > ke_in) | (nc_out > ke_out)
-            fb_idx = jnp.nonzero(fb, size=B, fill_value=B)[0].astype(
-                jnp.int32)
-            n_fb = fb.sum(dtype=jnp.int32)
-            rungs = []
-            r = _FB_MIN
-            while r < B:
-                rungs.append(r)
-                r *= 4
-            rungs = sorted(set(min(r, B) for r in rungs + [B]))
-
-            def apply_rung(r):
-                def go(h6):
-                    idx = fb_idx[:r]
-                    safe = jnp.minimum(idx, B - 1)
-                    ih = full_dir_hits(dir_in, safe)
-                    oh = full_dir_hits(dir_out, safe)
-                    tgt = jnp.where(idx < B, idx, B)  # B drops (OOB)
-                    return tuple(
-                        cur.at[tgt].set(new, mode="drop")
-                        for cur, new in zip(h6, ih + oh)
+    with device_scope("classify.scan"):
+        if summary_only:
+            in_hits = (BIGS, BIGS, BIGS)
+            out_hits = (BIGS, BIGS, BIGS)
+            fb = no_fb
+        else:
+            def phase2(_):
+                mats_in, ke_in = cand_mats(dir_in, g_in)
+                mats_out, ke_out = cand_mats(dir_out, g_out)
+                if fused:
+                    pad = (-B) % _FUSE_TB
+                    if pad:
+                        mats_in = tuple(jnp.pad(x, ((0, pad), (0, 0)))
+                                        for x in mats_in)
+                        mats_out = tuple(jnp.pad(x, ((0, pad), (0, 0)))
+                                         for x in mats_out)
+                    call = _pruned_consumer_call(
+                        B + pad, ke_in * AGG_BLOCK, ke_out * AGG_BLOCK,
+                        meta.in_phases, meta.out_phases,
+                        pallas_interpret(meta),
                     )
+                    hits = call(*mats_in, *mats_out)[:B]
+                    hits6 = tuple(hits[:, i] for i in range(6))
+                else:
+                    ia, ipr, isv, bi = mats_in
+                    oa, opr, osv, bo = mats_out
+                    hits6 = (_phase_first_from_base(ia & ipr & isv, bi,
+                                                    meta.in_phases)
+                             + _phase_first_from_base(oa & opr & osv, bo,
+                                                      meta.out_phases))
+                fb = (nc_in > ke_in) | (nc_out > ke_out)
+                fb_idx = jnp.nonzero(fb, size=B, fill_value=B)[0].astype(
+                    jnp.int32)
+                n_fb = fb.sum(dtype=jnp.int32)
+                rungs = []
+                r = _FB_MIN
+                while r < B:
+                    rungs.append(r)
+                    r *= 4
+                rungs = sorted(set(min(r, B) for r in rungs + [B]))
 
-                return go
+                def apply_rung(r):
+                    def go(h6):
+                        idx = fb_idx[:r]
+                        safe = jnp.minimum(idx, B - 1)
+                        ih = full_dir_hits(dir_in, safe)
+                        oh = full_dir_hits(dir_out, safe)
+                        tgt = jnp.where(idx < B, idx, B)  # B drops (OOB)
+                        return tuple(
+                            cur.at[tgt].set(new, mode="drop")
+                            for cur, new in zip(h6, ih + oh)
+                        )
 
-            branches = [lambda h6: h6] + [apply_rung(r) for r in rungs]
-            sel = jnp.where(
-                n_fb == 0,
-                0,
-                1 + sum(((n_fb > r).astype(jnp.int32) for r in rungs[:-1]),
-                        start=jnp.int32(0)),
+                    return go
+
+                branches = [lambda h6: h6] + [apply_rung(r) for r in rungs]
+                sel = jnp.where(
+                    n_fb == 0,
+                    0,
+                    1 + sum(((n_fb > r).astype(jnp.int32) for r in rungs[:-1]),
+                            start=jnp.int32(0)),
+                )
+                hits6 = jax.lax.switch(sel, branches, hits6)
+                return hits6 + (fb,)
+
+            def all_dead(_):
+                # Aggregate-AND-zero short circuit for the whole batch (the
+                # adversarial / default-deny cold shape): no candidate
+                # gather, no fallback — straight to the default verdicts.
+                return (BIGS,) * 6 + (no_fb,)
+
+            res = jax.lax.cond(
+                ((nc_in > 0) | (nc_out > 0)).any(), phase2, all_dead, None
             )
-            hits6 = jax.lax.switch(sel, branches, hits6)
-            return hits6 + (fb,)
+            in_hits, out_hits, fb = res[0:3], res[3:6], res[6]
 
-        def all_dead(_):
-            # Aggregate-AND-zero short circuit for the whole batch (the
-            # adversarial / default-deny cold shape): no candidate
-            # gather, no fallback — straight to the default verdicts.
-            return (BIGS,) * 6 + (no_fb,)
+        skip = ((nc_in == 0) & (nc_out == 0)).astype(jnp.int32)
+        cand = jnp.maximum(nc_in, nc_out)
+        fbi = fb.astype(jnp.int32)
+        if hit_combine is not None:
+            in_hits = tuple(hit_combine(h) for h in in_hits)
+            out_hits = tuple(hit_combine(h) for h in out_hits)
+            # The prune observables are SHARD-LOCAL under rule sharding
+            # (each shard prunes its own aggregate slice); emitting them raw
+            # would violate the replicated-output contract every other
+            # output keeps via the pmin (mesh._shard_map).  Combine
+            # them through the SAME min-combine: skip is an AND (min of
+            # 0/1 — no shard had a candidate), fallback an OR (1 - min of
+            # the complement — ANY shard redispatched), and cand the MAX
+            # per-shard count (min of the negation) — the quantity the
+            # per-shard K budget must actually cover, which is what the
+            # autotuner and the histogram exist to answer.
+            skip = hit_combine(skip)
+            fbi = 1 - hit_combine(1 - fbi)
+            cand = -hit_combine(-cand)
 
-        res = jax.lax.cond(
-            ((nc_in > 0) | (nc_out > 0)).any(), phase2, all_dead, None
-        )
-        in_hits, out_hits, fb = res[0:3], res[3:6], res[6]
-
-    skip = ((nc_in == 0) & (nc_out == 0)).astype(jnp.int32)
-    cand = jnp.maximum(nc_in, nc_out)
-    fbi = fb.astype(jnp.int32)
-    if hit_combine is not None:
-        in_hits = tuple(hit_combine(h) for h in in_hits)
-        out_hits = tuple(hit_combine(h) for h in out_hits)
-        # The prune observables are SHARD-LOCAL under rule sharding
-        # (each shard prunes its own aggregate slice); emitting them raw
-        # would violate the replicated-output contract every other
-        # output keeps via the pmin (mesh._shard_map).  Combine
-        # them through the SAME min-combine: skip is an AND (min of
-        # 0/1 — no shard had a candidate), fallback an OR (1 - min of
-        # the complement — ANY shard redispatched), and cand the MAX
-        # per-shard count (min of the negation) — the quantity the
-        # per-shard K budget must actually cover, which is what the
-        # autotuner and the histogram exist to answer.
-        skip = hit_combine(skip)
-        fbi = 1 - hit_combine(1 - fbi)
-        cand = -hit_combine(-cand)
-
-    in_code, in_rule = _resolve(ing.action, in_hits, iso_in)
-    out_code, out_rule = _resolve(eg.action, out_hits, iso_out)
-    final = jnp.where(out_code != ACT_ALLOW, out_code, in_code)
+        in_code, in_rule = _resolve(ing.action, in_hits, iso_in)
+        out_code, out_rule = _resolve(eg.action, out_hits, iso_out)
+        final = jnp.where(out_code != ACT_ALLOW, out_code, in_code)
     return {
         "code": final,
         "egress_code": out_code,
@@ -2230,6 +2240,7 @@ def _onepass_call(b, s_in, s_out, k_in, k_out, in_phases, out_phases,
         out_specs=out_specs,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="classify_onepass",
     )
 
 

@@ -113,6 +113,9 @@ from ..datapath.maintenance import MaintenanceTask
 from ..datapath.slowpath import ADMIT_DROP, MissQueue, SlowPathEngine
 from ..datapath.tpuflow import TpuflowDatapath, _rid
 from ..observability.telemetry import classify_regime
+from ..observability.tracing import (SP_ACCOUNT, SP_ATTRIBUTE, SP_DISPATCH,
+                                     SP_DONE, SP_FETCH, SP_STAGE, SP_UPLOAD,
+                                     SP_WAIT)
 from ..models import forwarding as fw
 from ..models import pipeline as pl
 from ..ops import hashing
@@ -761,6 +764,9 @@ class MeshDatapath(TpuflowDatapath):
     # -- the sharded step ----------------------------------------------------
 
     def _step(self, batch: PacketBatch, now: int, valid=None) -> StepResult:
+        tr = self._steptrace
+        # ---- stage: host columns, shard routing and the permutation --------
+        tr.phase(SP_STAGE)
         D = self._n_data
         B = batch.size
         if B % D:
@@ -793,32 +799,46 @@ class MeshDatapath(TpuflowDatapath):
         perm, inv, spill = _shard_placement(shard, D)
         src = batch.src_ip[perm].astype(np.uint32)
         dst = batch.dst_ip[perm].astype(np.uint32)
-        proto = batch.proto[perm].astype(np.int32)
-        sport = batch.src_port[perm].astype(np.int32)
-        dport = batch.dst_port[perm].astype(np.int32)
-        pflags = flags[perm]
         # The fused walk derives the mcast/teardown commit gating and the
         # SpoofGuard/ARP/IGMP validity masks itself (models/forwarding);
         # the engine contributes only the spill rule — an off-home lane
         # classifies but never caches in a foreign shard.
+        lanes = (iputil.flip_u32(src), iputil.flip_u32(dst),
+                 batch.proto[perm].astype(np.int32),
+                 batch.src_port[perm].astype(np.int32),
+                 batch.dst_port[perm].astype(np.int32), in_ports[perm],
+                 flags[perm], arp[perm],
+                 np.ones(B, bool) if ext is None else ext[perm], spill,
+                 lens[perm].astype(np.int32), spill)
         stepf = _mesh_step_full_fn(self._mesh, self._meta_step, has_arp)
         dsvc, dft = self._shared_tables()
-        t0 = time.perf_counter() if fo is not None else 0.0
-        state, out = stepf(
-            self._state, self._drs, dsvc, dft,
-            iputil.flip_u32(src), iputil.flip_u32(dst), proto, sport, dport,
-            in_ports[perm], jnp.int32(now), jnp.int32(self._gen),
-            pflags, arp[perm],
-            np.ones(B, bool) if ext is None else ext[perm], spill,
-            lens[perm].astype(np.int32), spill,
-        )
+
+        # ---- upload: the columns go into the sharded call as numpy, so the
+        # CALL uploads them (as `_spill_retry`'s does): they are counted
+        # here, where they are handed over, and their time falls in
+        # `dispatch` — only the two scalars transfer in this phase. -------
+        tr.phase(SP_UPLOAD)
+        for x in lanes:
+            tr.uploaded(x)
+        scalars = (self._upload_i32(now), self._upload_i32(self._gen))
+
+        # ---- dispatch / wait / fetch (the tpuflow boundaries) --------------
+        tr.phase(SP_DISPATCH)
+        state, out = stepf(self._state, self._drs, dsvc, dft, *lanes[:6],
+                           *scalars, *lanes[6:])
         self._state = state
         self._state_mutations += 1
-        o = {k: np.asarray(v) for k, v in out.items()}
+        tr.phase(SP_WAIT)
+        jax.block_until_ready(out)
+        tr.phase(SP_FETCH)
+        o = {k: tr.fetched(np.asarray(v)) for k, v in out.items()}
+        tr.phase(SP_ACCOUNT)
         if fo is not None:
-            # Dispatch-liveness deadline: a stalled sharded dispatch (the
-            # arrays above force materialization) is a wedge symptom.
-            fo.note_dispatch(time.perf_counter() - t0, now)
+            # Dispatch-liveness deadline: a stalled sharded dispatch is a
+            # wedge symptom.  Fed from the dispatch + wait + fetch phases
+            # just stamped (upload included: the call uploads) — no clock
+            # pair of its own.
+            fo.note_dispatch(tr.since(SP_DISPATCH), now)
         o.pop("n_miss")
         self._evictions += int(o.pop("n_evict").sum())
         self._reclaims += int(o.pop("n_reclaim").sum())
@@ -846,7 +866,7 @@ class MeshDatapath(TpuflowDatapath):
                                   arp, has_arp, lens, now)
         # Recomputed from the MERGED per-lane mask: a retried lane's miss
         # image is its home-shard one, not the foreign always-miss.
-        n_miss = int(o["miss"].sum())
+        n_miss = tr.n_miss = int(o["miss"].sum())
         if fo_masked is not None:
             # The evacuation re-miss burst: dead-resident flows pay one
             # re-miss each on their survivor home (bounded, metered).
@@ -900,8 +920,10 @@ class MeshDatapath(TpuflowDatapath):
         self._count_metrics(o, in_ids, out_ids, lens, pending=pending)
         if self._deny is not None:
             self._deny_verdicts(batch, o["code"], pending, now)
+        # ---- attribute: rule ids and the StepResult ------------------------
+        tr.phase(SP_ATTRIBUTE)
         unflip = iputil.unflip_u32_array
-        return StepResult(
+        res = StepResult(
             code=o["code"],
             est=o["est"],
             pending=pending,
@@ -932,6 +954,8 @@ class MeshDatapath(TpuflowDatapath):
             tc_act=o["tc_act"],
             tc_port=o["tc_port"],
         )
+        tr.phase(SP_DONE)
+        return res
 
     def _spill_retry(self, batch: PacketBatch, o: dict, spilled: np.ndarray,
                      shard: np.ndarray, flags: np.ndarray,
@@ -968,18 +992,22 @@ class MeshDatapath(TpuflowDatapath):
         rflags = flags[idx]
         stepf = _mesh_step_full_fn(self._mesh, self._meta_step, has_arp)
         dsvc, dft = self._shared_tables()
-        state, out = stepf(
-            self._state, self._drs, dsvc, dft,
+        # The retry's transfers are the step's too (it runs inside the
+        # `account` phase): counted where the dispatch issues them (the
+        # columns go in as numpy, so the call itself uploads them).
+        tr = self._steptrace
+        lanes = [tr.uploaded(x) for x in (
             iputil.flip_u32(src), iputil.flip_u32(dst), proto,
             batch.src_port[idx].astype(np.int32),
-            batch.dst_port[idx].astype(np.int32),
-            in_ports[idx], jnp.int32(now), jnp.int32(self._gen),
+            batch.dst_port[idx].astype(np.int32), in_ports[idx],
             rflags, arp[idx], valid, np.zeros(idx.size, bool),
-            lens[idx].astype(np.int32), ~valid,
-        )
+            lens[idx].astype(np.int32), ~valid)]
+        state, out = stepf(
+            self._state, self._drs, dsvc, dft, *lanes[:6],
+            self._upload_i32(now), self._upload_i32(self._gen), *lanes[6:])
         self._state = state
         self._state_mutations += 1
-        o2 = {k: np.asarray(v) for k, v in out.items()}
+        o2 = {k: tr.fetched(np.asarray(v)) for k, v in out.items()}
         self._evictions += int(o2.pop("n_evict").sum())
         self._reclaims += int(o2.pop("n_reclaim").sum())
         o2.pop("n_miss")

@@ -1,0 +1,355 @@
+"""The step tracer (observability/tracing.StepTracer): the `step` span, its
+seven host phases and the transfer counters of every `Datapath.step` call,
+on the single-chip engine and a 4-virtual-device MeshDatapath; the commit
+stages readable without a realization span; the device program's scopes.
+
+What is held:
+  * the ten stamps of a record are monotonic and the phases telescope to
+    the span exactly; step_hist is fed from the SAME clock pair;
+  * the ring drops oldest and meters it; a raising `_step` leaves a closed
+    record and still feeds step_hist;
+  * h2d/d2h counters equal, by hand, what one batch shape uploads/fetches;
+  * the instrumented `_step` (hoisted staging, `block_until_ready`) answers
+    and mutates state exactly like the dispatch it replaced;
+  * `last_commit()` after a direct install_bundle telescopes to settle -
+    start;
+  * every STEP_SCOPES name is in the lowered step program, the spans land
+    in a profiler trace, and no name is spelled outside the schema tuples.
+"""
+
+import ast
+import glob
+import os
+import pathlib
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import antrea_tpu
+from antrea_tpu.datapath import TpuflowDatapath
+from antrea_tpu.models import forwarding as fwd
+from antrea_tpu.observability import tracing
+from antrea_tpu.observability.tracing import (STEP_PHASES, STEP_RECORD,
+                                              STEP_SCOPES, StepTracer)
+from antrea_tpu.packet import PacketBatch
+from antrea_tpu.simulator import gen_cluster, gen_services, gen_traffic
+from antrea_tpu.utils import ip as iputil
+
+B = 256
+KW = dict(flow_slots=1 << 10, aff_slots=1 << 8, canary_probes=8,
+          miss_chunk=64)
+STAMPS = ["t_start"] + [f"t_{p}" for p in STEP_PHASES] + ["t_done", "t_end"]
+PKG = pathlib.Path(antrea_tpu.__file__).parent
+
+
+@pytest.fixture(scope="module")
+def world():
+    cluster = gen_cluster(120, n_nodes=4, pods_per_node=8, seed=7)
+    return (cluster, gen_services(8, cluster.pod_ips, seed=2),
+            gen_traffic(cluster.pod_ips, B, n_flows=96, seed=3))
+
+
+def _make(kind, world):
+    cluster, services, _ = world
+    if kind == "tpuflow":
+        return TpuflowDatapath(cluster.ps, services, **KW)
+    if len(jax.devices("cpu")) < 4:
+        pytest.skip("needs 4 virtual CPU devices")
+    from antrea_tpu.parallel import MeshDatapath
+
+    return MeshDatapath(cluster.ps, services, n_data=2, n_rule=2,
+                        devices=jax.devices("cpu")[:4], **KW)
+
+
+@pytest.fixture(scope="module", params=["tpuflow", "mesh"])
+def engine(request, world):
+    """(kind, engine, results) after three steps of the same batch."""
+    dp = _make(request.param, world)
+    results = [dp.step(world[2], now=10 + i) for i in range(3)]
+    return request.param, dp, results
+
+
+# -- the records ---------------------------------------------------------------
+
+def test_stamps_are_monotonic_and_phases_telescope(engine):
+    _, dp, results = engine
+    trace = dp.step_trace()
+    rec = trace["records"]
+    assert rec.dtype == STEP_RECORD and trace["dropped"] == 0
+    assert rec["seq"].tolist() == [1, 2, 3]
+    assert (rec["lanes"] == B).all()
+    assert rec["n_miss"].tolist() == [r.n_miss for r in results]
+    stamps = np.stack([rec[s] for s in STAMPS], axis=1)
+    assert (np.diff(stamps, axis=1) >= 0).all()
+    assert (rec["t_start"][1:] >= rec["t_end"][:-1]).all()
+    # phase p runs from t_<p> to the next stamp: children of `step`, with
+    # the span's self time before the first and after the last.
+    phases = np.diff(stamps[:, 1:-1], axis=1)
+    assert phases.shape[1] == len(STEP_PHASES) == 7
+    self_ns = (rec["t_stage"] - rec["t_start"]) + (rec["t_end"]
+                                                   - rec["t_done"])
+    assert (phases.sum(axis=1) + self_ns
+            == rec["t_end"] - rec["t_start"]).all()
+    # The device did real work, and the host waited for it in `wait`.
+    assert (phases > 0).all()
+
+
+def test_step_hist_is_fed_from_the_span(engine):
+    """One clock pair: the histogram's count and sum ARE the ring's."""
+    _, dp, _ = engine
+    rec = dp.step_trace()["records"]
+    assert dp.step_hist.count == len(rec) == 3
+    span_s = [(e - s) * 1e-9 for s, e in zip(rec["t_start"].tolist(),
+                                             rec["t_end"].tolist())]
+    assert dp.step_hist.sum == pytest.approx(sum(span_s), rel=1e-12)
+    src = (PKG / "datapath" / "tpuflow.py").read_text()
+    step = src[src.index("    def step(self"):src.index("    def _step(self")]
+    assert "perf_counter" not in step and "tr.end()" in step
+
+
+def test_transfer_counters_by_hand(engine):
+    kind, dp, _ = engine
+    rec = dp.step_trace()["records"][-1]
+    if kind == "tpuflow":
+        # src, dst, proto, sport, dport, in_port, flags (i32 columns) and
+        # the two scalars now, gen; no ARP lane, no lens (FlowExporter
+        # gate off), no v6, no padding mask.
+        assert rec["h2d_transfers"] == 9
+        assert rec["h2d_bytes"] == 7 * B * 4 + 2 * 4
+    else:
+        # The mesh walk always carries arp, lens and three bool masks
+        # (valid, no_commit=spill, prune_exclude=spill).
+        assert rec["h2d_transfers"] >= 14
+        assert rec["h2d_bytes"] >= 9 * B * 4 + 3 * B + 2 * 4
+    # Every output of the step program is fetched once; by its shape.
+    cols = [iputil.flip_u32(np.zeros(B, np.uint32))] * 2 + [
+        np.zeros(B, np.int32)] * 5
+    if kind == "tpuflow":
+        out = jax.eval_shape(
+            lambda *a: fwd.pipeline_step_full(
+                dp._state, dp._drs, dp._dsvc, dp._dft, *a[:6], jnp.int32(1),
+                jnp.int32(1), a[6], meta=dp._meta_step)[1], *cols)
+        assert rec["d2h_transfers"] == len(out)
+        assert rec["d2h_bytes"] == sum(
+            int(np.prod(v.shape)) * v.dtype.itemsize for v in out.values())
+    assert rec["d2h_bytes"] > 20 * B * 4  # ~25 per-lane i32 outputs
+
+
+def test_the_ring_drops_oldest_and_meters_it(world, monkeypatch):
+    monkeypatch.setattr(tracing, "STEP_RING_SLOTS", 4)
+    tr = StepTracer()
+    assert len(tr.records()) == 0
+    for i in range(6):
+        tr.begin(lanes=i)
+        for k in range(len(STEP_PHASES) + 1):
+            tr.phase(k)
+        tr.uploaded(np.zeros(3, np.int32))
+        assert tr.end() >= 0
+    rec = tr.records()
+    assert rec["seq"].tolist() == [3, 4, 5, 6] and tr.dropped == 2
+    assert rec["lanes"].tolist() == [2, 3, 4, 5]
+    assert (rec["h2d_bytes"] == 12).all() and (rec["d2h_bytes"] == 0).all()
+    # On an engine: the same ring behind step_trace().
+    dp = _make("tpuflow", world)
+    for i in range(6):
+        dp.step(world[2], now=20 + i)
+    trace = dp.step_trace()
+    assert trace["dropped"] == 2
+    assert trace["records"]["seq"].tolist() == [3, 4, 5, 6]
+
+
+@pytest.mark.parametrize("kind", ["tpuflow", "mesh"])
+def test_a_raising_step_closes_its_span(kind, world):
+    dp = _make(kind, world)
+    batch = world[2]
+    if kind == "tpuflow":  # a v6 lane on a v4-only engine: raises in `stage`
+        bad = PacketBatch(
+            src_ip=batch.src_ip, dst_ip=batch.dst_ip, proto=batch.proto,
+            src_port=batch.src_port, dst_port=batch.dst_port,
+            src_ip6=np.zeros((B, 4), np.uint32),
+            dst_ip6=np.zeros((B, 4), np.uint32), is6=np.ones(B, np.int32))
+    else:  # 3 lanes over 2 replicas
+        bad = PacketBatch(**{f: getattr(batch, f)[:3] for f in (
+            "src_ip", "dst_ip", "proto", "src_port", "dst_port")})
+    with pytest.raises(ValueError):
+        dp.step(bad, now=5)
+    rec = dp.step_trace()["records"]
+    assert len(rec) == 1 and dp.step_hist.count == 1
+    stamps = [int(rec[s][0]) for s in STAMPS]
+    assert stamps == sorted(stamps) and stamps[-1] > stamps[0]
+    # The unreached boundaries lie on the end stamp: zero-width phases.
+    assert stamps[2:] == [stamps[-1]] * (len(STAMPS) - 2)
+    assert dp.step_hist.sum == pytest.approx(
+        (stamps[-1] - stamps[0]) * 1e-9, rel=1e-12)
+    # ... and the next step records normally.
+    dp.step(batch, now=6)
+    rec = dp.step_trace()["records"]
+    assert rec["seq"].tolist() == [1, 2] and rec["n_miss"][1] > 0
+
+
+def _parent_step(dp, batch, now):
+    """The dispatch as it stood before the tracer: every upload an argument
+    expression of the call, no `block_until_ready`, a bare fetch."""
+    state, out = fwd.pipeline_step_full(
+        dp._state, dp._drs, dp._dsvc, dp._dft,
+        jnp.asarray(iputil.flip_u32(batch.src_ip)),
+        jnp.asarray(iputil.flip_u32(batch.dst_ip)),
+        jnp.asarray(batch.proto.astype(np.int32)),
+        jnp.asarray(batch.src_port.astype(np.int32)),
+        jnp.asarray(batch.dst_port.astype(np.int32)),
+        jnp.asarray(batch.in_ports()),
+        jnp.int32(now), jnp.int32(dp._gen),
+        jnp.asarray(batch.flags()),
+        jnp.asarray(batch.arp_ops()) if batch.arp_op is not None else None,
+        jnp.asarray(np.maximum(batch.lens(), 0)) if dp._flow_stats else None,
+        meta=dp._meta_step, v6=dp._v6_lanes(batch), valid=None)
+    dp._state = state
+    return {k: np.asarray(v) for k, v in out.items()}
+
+
+@pytest.mark.parametrize("dual_stack", [False, True])
+def test_answers_and_state_equal_the_parents_path(world, dual_stack):
+    cluster, services, batch = world
+    kw = dict(KW, dual_stack=dual_stack)
+    new = TpuflowDatapath(cluster.ps, services, **kw)
+    old = TpuflowDatapath(cluster.ps, services, **kw)
+    fresh = gen_traffic(cluster.pod_ips, B, n_flows=64, seed=11)
+    for now, b in ((10, batch), (11, batch), (12, fresh), (13, batch)):
+        res = new.step(b, now=now)
+        o = _parent_step(old, b, now)
+        for field, key in (("code", "code"), ("est", "est"),
+                           ("committed", "committed"), ("reply", "reply"),
+                           ("svc_idx", "svc_idx"), ("dnat_port", "dnat_port"),
+                           ("snat", "snat"), ("fwd_kind", "fwd_kind"),
+                           ("out_port", "out_port"), ("spoofed", "spoofed")):
+            assert (getattr(res, field) == o[key]).all(), field
+        assert (res.dnat_ip == iputil.unflip_u32_array(o["dnat_ip_f"])).all()
+        assert res.n_miss == int(o["n_miss"])
+        for ids, key, got in ((new._cps.ingress.rule_ids, "ingress_rule",
+                               res.ingress_rule),
+                              (new._cps.egress.rule_ids, "egress_rule",
+                               res.egress_rule)):
+            want = [ids[i] if 0 <= i < len(ids) and ids[i] else None
+                    for i in o[key]]
+            assert got == want
+        for a, b_ in zip(jax.tree_util.tree_leaves(new._state),
+                         jax.tree_util.tree_leaves(old._state)):
+            assert (np.asarray(a) == np.asarray(b_)).all()
+    assert any(r > 0 for r in new.step_trace()["records"]["n_miss"])
+
+
+# -- the commit stages ---------------------------------------------------------
+
+def test_last_commit_after_a_direct_install(world):
+    cluster, services, _ = world
+    dp = TpuflowDatapath(**KW)
+    tracer = dp.realization_tracer
+    assert tracer.last_commit() is None  # booting is no transaction
+    gen = dp.install_bundle(cluster.ps, services)
+    last = tracer.last_commit()
+    assert last.pop("generation") == gen == dp.generation
+    assert set(last) == {"compile_s", "canary_s", "swap_s", "settle_s"}
+    assert all(v >= 0 for v in last.values())
+    assert last["compile_s"] > 0 and last["canary_s"] > 0
+    _, stamps = tracer._last_commit
+    assert sum(last.values()) == pytest.approx(
+        stamps["settle"] - stamps["start"], rel=1e-12)
+    # No realization span was opened: the stages are readable anyway.
+    assert tracer.spans() == []
+    dp.install_bundle(None, services[:4])
+    assert tracer.last_commit()["generation"] == dp.generation == gen + 1
+    assert TpuflowDatapath(realization_slots=0, **KW).realization_tracer \
+        is None
+
+
+# -- the device scopes and the host annotations --------------------------------
+
+def _lowered_text(dp):
+    i32 = jnp.zeros(B, jnp.int32)
+    return fwd.pipeline_step_full.lower(
+        dp._state, dp._drs, dp._dsvc, dp._dft, i32, i32, i32, i32, i32, i32,
+        jnp.int32(1), jnp.int32(1), i32, None, None,
+        meta=dp._meta_step).as_text(debug_info=True)
+
+
+def test_the_lowered_step_names_every_scope(world):
+    cluster, services, _ = world
+    plain = _lowered_text(TpuflowDatapath(cluster.ps, services, **KW))
+    # `classify.summary` is the aggregate stage of the pruned classifier;
+    # the unpruned walk has no such stage.
+    assert [s for s in STEP_SCOPES if s not in plain] == ["classify.summary"]
+    pruned = _lowered_text(TpuflowDatapath(cluster.ps, services,
+                                           prune_budget=2, **KW))
+    for scope in STEP_SCOPES:
+        assert re.search(rf'[/"]{re.escape(scope)}[/"]', pruned), scope
+    # Nested as tracing.py says: the round loop's scopes inside
+    # miss_detect, the eviction scan inside the commit.
+    assert "fast_path/probe" in plain and "fast_path/refresh" in plain
+    assert re.search(r"miss_detect/.*while/body/service_lb", plain)
+    assert re.search(r"miss_detect/.*classify/classify\.scan", plain)
+    assert re.search(r"miss_detect/.*cache_commit/eviction_scan", plain)
+    with pytest.raises(ValueError):
+        tracing.device_scope("fastpath")
+
+
+def test_the_names_are_spelled_in_the_schema_only():
+    """Device scopes enter through `ops/scopes.device_scope` with a
+    STEP_SCOPES literal, host spans through StepTracer; nobody else names
+    one, and the kernel layer does not import the observability plane."""
+    used = set()
+    for path in glob.glob(str(PKG / "**" / "*.py"), recursive=True):
+        rel = os.path.relpath(path, PKG)
+        if rel.startswith("analysis" + os.sep):
+            continue
+        src = open(path).read()
+        if rel != os.path.join("ops", "scopes.py"):
+            assert "named_scope" not in src, rel
+        if rel != os.path.join("observability", "tracing.py"):
+            assert "TraceAnnotation" not in src, rel
+            assert "tpuflow.step" not in src, rel
+        if rel.split(os.sep)[0] in ("ops", "models"):
+            assert not re.search(r"^\s*(from|import) .*observability", src,
+                                 re.M), rel
+        for node in ast.walk(ast.parse(src)):
+            if (isinstance(node, ast.Call)
+                    and getattr(node.func, "id", None) == "device_scope"):
+                (arg,) = node.args
+                assert isinstance(arg, ast.Constant), rel
+                used.add(arg.value)
+    assert used == set(STEP_SCOPES)
+    assert len(set(STEP_SCOPES)) == len(STEP_SCOPES)
+    names = [n for n in STEP_RECORD.names if n.startswith("t_")]
+    assert names == STAMPS
+    # The three Pallas consumers carry stable kernel names.
+    match = (PKG / "ops" / "match.py").read_text()
+    assert match.count("pl.pallas_call(") == len(
+        re.findall(r'name="classify_\w+"', match)) == 3
+
+
+def test_the_spans_land_in_a_profiler_trace(world, tmp_path):
+    dp = _make("tpuflow", world)
+    dp.step(world[2], now=1)  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        dp.step(world[2], now=2)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    spans = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("tpuflow.step"):
+                    spans[ev.name] = (ev.start_ns, ev.start_ns
+                                      + ev.duration_ns, dict(ev.stats))
+    assert set(spans) == {"tpuflow.step"} | {
+        f"tpuflow.step.{p}" for p in STEP_PHASES}
+    start, end, stats = spans.pop("tpuflow.step")
+    assert int(stats["seq"]) == 2
+    assert all(start <= s and e <= end for s, e, _ in spans.values())
