@@ -28,6 +28,7 @@ columns so signed compares give unsigned order.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import cached_property
 
 import numpy as np
 
@@ -243,6 +244,20 @@ class DirectionTensors:
     @property
     def n_rules(self) -> int:
         return int(self.at_gid.shape[0])
+
+    @cached_property
+    def rule_id_table(self) -> np.ndarray:
+        """(len(rule_ids) + 1,) object table for resolving a whole index
+        column at once: entry r is rule_ids[r] (the same str object), or
+        None where that id is empty (a padding row); the LAST entry is
+        None, the slot every out-of-range index is clamped to.  Built on
+        first use and kept with the rule_ids it was built from — they are
+        never mutated after construction, so an engine that swaps its
+        compiled set (install, rollback, tenant world) swaps the table
+        with it."""
+        table = np.empty(len(self.rule_ids) + 1, dtype=object)
+        table[:-1] = [rid or None for rid in self.rule_ids]
+        return table
 
 
 @dataclass

@@ -60,7 +60,12 @@ class StepResult:
     dnat_ip: np.ndarray  # u32, post-DNAT destination; on reply=1 packets:
     #   the UN-DNAT rewrite (frontend ip the reply's SOURCE is restored to)
     dnat_port: np.ndarray
-    ingress_rule: list  # Optional[str] per packet
+    # Optional[str] per packet, one list a direction.  The tpuflow
+    # engines resolve them EAGERLY, inside the step's `attribute` phase:
+    # the device's rule-index column gathers from the compiled set's
+    # rule_id_table (datapath/tpuflow._rids, the column form of _rid;
+    # TpuflowDatapath and MeshDatapath both call it).
+    ingress_rule: list
     egress_rule: list
     committed: np.ndarray  # 0/1 — conntrack commit happened this step
     n_miss: int

@@ -71,6 +71,17 @@ def _rid(ids: list, idx: int):
     return ids[idx] if 0 <= idx < len(ids) and ids[idx] else None
 
 
+def _rids(dt, idx: np.ndarray) -> list:
+    """_rid over a whole column of stored rule INDICES of direction `dt`
+    (a DirectionTensors): one gather from its rule_id_table, every index
+    outside [0, len(rule_ids)) — default, or carried by a cached entry
+    from an older generation — clamped onto the table's trailing None.
+    A list of str | None, one per lane: both engines' StepResult."""
+    table = dt.rule_id_table
+    n = table.shape[0] - 1
+    return table[np.where((idx >= 0) & (idx < n), idx, n)].tolist()
+
+
 class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
                       TransactionalDatapath, AuditableDatapath,
                       persist.PersistableDatapath, Datapath):
@@ -680,16 +691,17 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         tr.phase(SP_ATTRIBUTE)
         unflip = iputil.unflip_u32_array
 
-        def keys_of(wide_col):
-            """(B, 4) flipped word rows -> per-lane combined keys.
+        def keys_of(wide_col, keep=True):
+            """(B, 4) flipped word rows -> per-lane combined keys, 0 on
+            the lanes a `keep` mask leaves out.
             Vectorized for the common case: v4-mapped rows (word 3 IS the
             key) take one numpy pass; Python big-int math runs only for
             lanes carrying a real v6 address."""
             words = unflip(wide_col).astype(np.int64)
             mapped = ((words[:, 0] == 0) & (words[:, 1] == 0)
                       & (words[:, 2] == 0xFFFF))
-            keys = words[:, 3].tolist()
-            for i in np.nonzero(~mapped)[0]:
+            keys = np.where(keep, words[:, 3], 0).tolist()
+            for i in np.nonzero(keep & ~mapped)[0]:
                 w = words[i]
                 keys[i] = iputil.V6_OFF + (
                     (int(w[0]) << 96) | (int(w[1]) << 64)
@@ -697,17 +709,14 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
                 )
             return keys
 
+        # peer_f / peer_w are zeroed for non-deliverable lanes in the
+        # kernel; the (kind==TUNNEL & deliverable) gate avoids un-flipping
+        # that 0 (and reports 0, not the mapped-zero key).
+        tunnel = (o["fwd_kind"] == FWD_TUNNEL) & (o["out_port"] != -1)
         dnat_key = peer_key = None
         if self._dual_stack:
             dnat_key = keys_of(o["dnat_w_f"])
-            peer_key = keys_of(o["peer_w"])
-            # Non-tunnel lanes' peer words are zero; report 0, not the
-            # mapped-zero key.
-            peer_key = [
-                k if (kind == FWD_TUNNEL and port != -1) else 0
-                for k, kind, port in zip(peer_key, o["fwd_kind"],
-                                         o["out_port"])
-            ]
+            peer_key = keys_of(o["peer_w"], keep=tunnel)
 
         res = StepResult(
             code=o["code"],
@@ -720,14 +729,8 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             svc_idx=o["svc_idx"],
             dnat_ip=unflip(o["dnat_ip_f"]),
             dnat_port=o["dnat_port"],
-            ingress_rule=[
-                in_ids[i] if 0 <= i < len(in_ids) and in_ids[i] else None
-                for i in o["ingress_rule"]
-            ],
-            egress_rule=[
-                out_ids[i] if 0 <= i < len(out_ids) and out_ids[i] else None
-                for i in o["egress_rule"]
-            ],
+            ingress_rule=_rids(self._cps.ingress, o["ingress_rule"]),
+            egress_rule=_rids(self._cps.egress, o["egress_rule"]),
             committed=o["committed"],
             n_miss=tr.n_miss,
             spoofed=o["spoofed"],
@@ -736,12 +739,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             l7_redirect=o["l7_redirect"],
             fwd_kind=o["fwd_kind"],
             out_port=o["out_port"],
-            # peer_f is zeroed for non-deliverable lanes in the kernel; the
-            # (kind==TUNNEL & deliverable) gate avoids un-flipping that 0.
-            peer_ip=np.where(
-                (o["fwd_kind"] == FWD_TUNNEL) & (o["out_port"] != -1),
-                unflip(o["peer_f"]), 0,
-            ).astype(np.uint32),
+            peer_ip=np.where(tunnel, unflip(o["peer_f"]), 0).astype(np.uint32),
             dec_ttl=o["dec_ttl"],
             tc_act=o["tc_act"],
             tc_port=o["tc_port"],

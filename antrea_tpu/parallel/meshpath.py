@@ -111,7 +111,7 @@ from ..config import ConfigError
 from ..datapath.interface import StepResult
 from ..datapath.maintenance import MaintenanceTask
 from ..datapath.slowpath import ADMIT_DROP, MissQueue, SlowPathEngine
-from ..datapath.tpuflow import TpuflowDatapath, _rid
+from ..datapath.tpuflow import TpuflowDatapath, _rids
 from ..observability.telemetry import classify_regime
 from ..observability.tracing import (SP_ACCOUNT, SP_ATTRIBUTE, SP_DISPATCH,
                                      SP_DONE, SP_FETCH, SP_STAGE, SP_UPLOAD,
@@ -934,8 +934,8 @@ class MeshDatapath(TpuflowDatapath):
             svc_idx=o["svc_idx"],
             dnat_ip=unflip(o["dnat_ip_f"]),
             dnat_port=o["dnat_port"],
-            ingress_rule=[_rid(in_ids, i) for i in o["ingress_rule"]],
-            egress_rule=[_rid(out_ids, i) for i in o["egress_rule"]],
+            ingress_rule=_rids(self._cps.ingress, o["ingress_rule"]),
+            egress_rule=_rids(self._cps.egress, o["egress_rule"]),
             committed=o["committed"],
             n_miss=n_miss,
             spoofed=o["spoofed"],

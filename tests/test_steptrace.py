@@ -10,7 +10,9 @@ What is held:
     record and still feeds step_hist;
   * h2d/d2h counters equal, by hand, what one batch shape uploads/fetches;
   * the instrumented `_step` (hoisted staging, `block_until_ready`) answers
-    and mutates state exactly like the dispatch it replaced;
+    and mutates state exactly like the dispatch it replaced, and its
+    vectorised `attribute` phase (rule ids by table gather, masked wide
+    keys) equals the per-lane scalar statement, types included;
   * `last_commit()` after a direct install_bundle telescopes to settle -
     start;
   * every STEP_SCOPES name is in the lowered step program, the spans land
@@ -30,12 +32,14 @@ import jax
 import jax.numpy as jnp
 
 import antrea_tpu
+from antrea_tpu.compiler.topology import FWD_TUNNEL, NodeRoute, Topology
 from antrea_tpu.datapath import TpuflowDatapath
+from antrea_tpu.datapath.tpuflow import _rid
 from antrea_tpu.models import forwarding as fwd
 from antrea_tpu.observability import tracing
 from antrea_tpu.observability.tracing import (STEP_PHASES, STEP_RECORD,
                                               STEP_SCOPES, StepTracer)
-from antrea_tpu.packet import PacketBatch
+from antrea_tpu.packet import Packet, PacketBatch
 from antrea_tpu.simulator import gen_cluster, gen_services, gen_traffic
 from antrea_tpu.utils import ip as iputil
 
@@ -211,6 +215,44 @@ def _parent_step(dp, batch, now):
     return {k: np.asarray(v) for k, v in out.items()}
 
 
+def _scalar_attribution(dp, o):
+    """The `attribute` phase lane by lane, as it was written before the
+    table gather: `_rid` for the rule ids; for a dual-stack engine the wide
+    keys (a v4-mapped row IS its word 3, anything else the 128-bit value
+    past V6_OFF) and the peer key only on deliverable tunnel lanes."""
+    def key(row):
+        w = [int(x) for x in iputil.unflip_u32_array(row)]
+        if w[:3] == [0, 0, 0xFFFF]:
+            return w[3]
+        return iputil.V6_OFF + ((w[0] << 96) | (w[1] << 64) | (w[2] << 32)
+                                | w[3])
+
+    want = {
+        "ingress_rule": [_rid(dp._cps.ingress.rule_ids, int(i))
+                         for i in o["ingress_rule"]],
+        "egress_rule": [_rid(dp._cps.egress.rule_ids, int(i))
+                        for i in o["egress_rule"]],
+        "dnat_key": None, "peer_key": None,
+    }
+    if dp._dual_stack:
+        want["dnat_key"] = [key(row) for row in o["dnat_w_f"]]
+        want["peer_key"] = [
+            key(row) if (kind == FWD_TUNNEL and port != -1) else 0
+            for row, kind, port in zip(o["peer_w"], o["fwd_kind"],
+                                       o["out_port"])]
+    return want
+
+
+def _assert_attribution(res, want):
+    for field, col in want.items():
+        got = getattr(res, field)
+        if col is None:
+            assert got is None, field
+            continue
+        assert type(got) is list and got == col, field
+        assert [type(g) for g in got] == [type(w) for w in col], field
+
+
 @pytest.mark.parametrize("dual_stack", [False, True])
 def test_answers_and_state_equal_the_parents_path(world, dual_stack):
     cluster, services, batch = world
@@ -229,17 +271,50 @@ def test_answers_and_state_equal_the_parents_path(world, dual_stack):
             assert (getattr(res, field) == o[key]).all(), field
         assert (res.dnat_ip == iputil.unflip_u32_array(o["dnat_ip_f"])).all()
         assert res.n_miss == int(o["n_miss"])
-        for ids, key, got in ((new._cps.ingress.rule_ids, "ingress_rule",
-                               res.ingress_rule),
-                              (new._cps.egress.rule_ids, "egress_rule",
-                               res.egress_rule)):
-            want = [ids[i] if 0 <= i < len(ids) and ids[i] else None
-                    for i in o[key]]
-            assert got == want
+        _assert_attribution(res, _scalar_attribution(new, o))
         for a, b_ in zip(jax.tree_util.tree_leaves(new._state),
                          jax.tree_util.tree_leaves(old._state)):
             assert (np.asarray(a) == np.asarray(b_)).all()
     assert any(r > 0 for r in new.step_trace()["records"]["n_miss"])
+
+
+def test_wide_keys_equal_the_scalar_statement():
+    """The dual-stack columns on lanes that exercise every branch of them:
+    v6 and v4 across the tunnel, over a v4 underlay (a mapped peer key)
+    and a v6 one (a wide peer key), v6 and v4 delivered locally, to the
+    gateway, dropped."""
+    node1, node2, pod_a4, pod_a6, pod_b6 = (
+        "192.168.1.2", "fd00:aa::2", "10.10.0.5", "fd00:10::5", "fd00:10::6")
+    topo = Topology(
+        node_name="n0", gateway_ip="10.10.0.1", gateway_ip6="fd00:10::1",
+        pod_cidr="10.10.0.0/24", pod_cidr6="fd00:10:0:0::/64",
+        local_pods=[(pod_a4, 3), (pod_a6, 3), (pod_b6, 4)],
+        remote_nodes=[NodeRoute("n1", node1, "10.10.1.0/24"),
+                      NodeRoute("n1", node1, "fd00:10:0:1::/64"),
+                      NodeRoute("n2", node2, "fd00:10:0:2::/64")])
+    kw = dict(flow_slots=1 << 10, aff_slots=1 << 6, topology=topo,
+              node_ips=[node1, "fd00:10::1"], dual_stack=True, miss_chunk=16)
+    new, old = TpuflowDatapath(**kw), TpuflowDatapath(**kw)
+    lanes = [(pod_a6, "fd00:10:0:1::9", 3), (pod_a4, "10.10.1.7", 3),
+             (pod_a6, "fd00:10:0:2::9", 3),
+             (pod_a6, pod_b6, 3), ("fd00:10:0:1::9", pod_a6, 1),
+             (pod_a6, "fd00:99::1", 3), (pod_a4, "8.8.8.8", 3),
+             (pod_a6, "fd00:10::77", 3), ("fd00:bad::1", pod_b6, 3)]
+    batch = PacketBatch.from_packets([
+        Packet(src_ip=iputil.ip_to_key(s), dst_ip=iputil.ip_to_key(d),
+               proto=6, src_port=40000 + i, dst_port=80)
+        for i, (s, d, _) in enumerate(lanes)])
+    batch.in_port = np.asarray([p for *_, p in lanes], np.int32)
+    for now in (1, 2):
+        res = new.step(batch, now=now)
+        want = _scalar_attribution(new, _parent_step(old, batch, now))
+        _assert_attribution(res, want)
+    tunnel = res.fwd_kind == FWD_TUNNEL
+    assert tunnel[:3].all() and not tunnel[3:].any()
+    assert res.peer_key[:4] == [iputil.ip_to_key(node1)] * 2 + [
+        iputil.ip_to_key(node2), 0]
+    assert res.dnat_key[0] == iputil.ip_to_key("fd00:10:0:1::9")  # a wide key
+    assert res.dnat_key[1] == iputil.ip_to_key("10.10.1.7")
 
 
 # -- the commit stages ---------------------------------------------------------
