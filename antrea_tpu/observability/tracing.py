@@ -88,6 +88,15 @@ STEP_PHASES = (
 (SP_STAGE, SP_UPLOAD, SP_DISPATCH, SP_WAIT, SP_FETCH, SP_ACCOUNT,
  SP_ATTRIBUTE, SP_DONE) = range(len(STEP_PHASES) + 1)
 
+# Sub-spans: (name, the phase it lies inside).  A sub-span is NOT a
+# phase — it is not added to the telescoping sum — and only the engine
+# that has the work opens it (parallel/meshpath.MeshDatapath: `route` is
+# the shard hash, the failover mask, `_shard_placement` and the
+# permutation of the columns into replica order; `retry` is the whole of
+# `_spill_retry`).  An engine that never opens one records zeros.
+STEP_SUBSPANS = (("route", "stage"), ("retry", "account"))
+SS_ROUTE, SS_RETRY = range(len(STEP_SUBSPANS))
+
 # Steps the in-memory ring keeps (drop-oldest, drops metered).
 STEP_RING_SLOTS = 4096
 
@@ -96,12 +105,18 @@ STEP_RING_SLOTS = 4096
 # (t_done closes the last phase), so the ten stamps are monotonic and
 # the phases telescope to t_done - t_stage exactly.  The four counters
 # are taken where the transfer is issued: one per host->device upload
-# and its nbytes, one per fetched output and its nbytes.
+# and its nbytes, one per fetched output and its nbytes.  Sub-span s
+# runs <s>_t0..<s>_t1 on the same clock, inside its phase (both 0 where
+# it never opened); `spill_lanes` are the lanes the mesh placed off their
+# home replica, `retry_lanes` those of them re-served from home inside
+# the same call (0 on one chip).
 STEP_RECORD = np.dtype(
     [("seq", "<i8"), ("lanes", "<i8"), ("n_miss", "<i8"), ("t_start", "<i8")]
     + [(f"t_{p}", "<i8") for p in STEP_PHASES]
     + [("t_done", "<i8"), ("t_end", "<i8"), ("h2d_transfers", "<i8"),
        ("h2d_bytes", "<i8"), ("d2h_transfers", "<i8"), ("d2h_bytes", "<i8")]
+    + [(f"{s}_{e}", "<i8") for s, _ in STEP_SUBSPANS for e in ("t0", "t1")]
+    + [("spill_lanes", "<i8"), ("retry_lanes", "<i8")]
 )
 _N_STAMPS = len(STEP_PHASES) + 3  # start, one per phase, done, end
 
@@ -116,10 +131,12 @@ class StepTracer:
     ring row and returns the span's seconds — the ONE clock pair
     `step_hist` and the telemetry fold are fed from.  A `_step` that
     raised leaves its unreached boundaries on the end stamp (zero-width
-    phases), never an open record.  Every span is also a
-    `jax.profiler.TraceAnnotation` (`tpuflow.step`, `tpuflow.step.<phase>`)
-    — free while no profiler session runs, and in a traced run an event on
-    the device ops' timeline.
+    phases), never an open record.  `sub(k)` opens STEP_SUBSPANS[k]
+    inside the running phase and `sub_end()` closes it (one open at a
+    time; `end` closes one a raise left open).  Every span is also a
+    `jax.profiler.TraceAnnotation` (`tpuflow.step`, `tpuflow.step.<phase>`,
+    `tpuflow.step.<phase>.<sub-span>`) — free while no profiler session
+    runs, and in a traced run an event on the device ops' timeline.
     """
 
     def __init__(self):
@@ -127,6 +144,8 @@ class StepTracer:
 
         self._annotate = TraceAnnotation
         self._names = tuple(f"tpuflow.step.{p}" for p in STEP_PHASES)
+        self._sub_names = tuple(f"tpuflow.step.{p}.{s}"
+                                for s, p in STEP_SUBSPANS)
         self.slots = STEP_RING_SLOTS
         self._clock = time.perf_counter_ns  # the benchmark client's clock
         self._ring = np.zeros(self.slots, STEP_RECORD)
@@ -134,17 +153,22 @@ class StepTracer:
         self.steps_total = 0
         self.dropped = 0
         self._stamps: list = []
-        self._span = self._child = None
+        self._span = self._child = self._sub = None
+        self._sub_at = 0
+        self._subs = [0] * (2 * len(STEP_SUBSPANS))
         self._lanes = 0
         self.n_miss = 0
         self.h2d_transfers = self.h2d_bytes = 0
         self.d2h_transfers = self.d2h_bytes = 0
+        self.spill_lanes = self.retry_lanes = 0
 
     def begin(self, lanes: int) -> None:
         self._lanes = int(lanes)
         self.n_miss = 0
         self.h2d_transfers = self.h2d_bytes = 0
         self.d2h_transfers = self.d2h_bytes = 0
+        self.spill_lanes = self.retry_lanes = 0
+        self._subs = [0] * (2 * len(STEP_SUBSPANS))
         self._span = self._annotate("tpuflow.step",
                                     seq=self.steps_total + 1)
         self._span.__enter__()
@@ -159,6 +183,18 @@ class StepTracer:
         if k < len(self._names):
             self._child = self._annotate(self._names[k])
             self._child.__enter__()
+
+    def sub(self, k: int) -> None:
+        """Open STEP_SUBSPANS[k] inside the phase that is running."""
+        self._sub = self._annotate(self._sub_names[k])
+        self._sub.__enter__()
+        self._sub_at = 2 * k
+        self._subs[2 * k] = self._clock()
+
+    def sub_end(self) -> None:
+        self._subs[self._sub_at + 1] = self._clock()
+        self._sub.__exit__(None, None, None)
+        self._sub = None
 
     def since(self, k: int) -> float:
         """Seconds from where phase k began to the latest boundary."""
@@ -177,6 +213,8 @@ class StepTracer:
         return x
 
     def end(self) -> float:
+        if self._sub is not None:  # a raise inside it
+            self.sub_end()
         t = self._clock()
         if self._child is not None:
             self._child.__exit__(None, None, None)
@@ -189,7 +227,8 @@ class StepTracer:
             self.dropped += 1  # the oldest row, overwritten here
         self._rows[(seq - 1) % self.slots] = (
             seq, self._lanes, self.n_miss, *ts, self.h2d_transfers,
-            self.h2d_bytes, self.d2h_transfers, self.d2h_bytes)
+            self.h2d_bytes, self.d2h_transfers, self.d2h_bytes, *self._subs,
+            self.spill_lanes, self.retry_lanes)
         return (t - ts[0]) * 1e-9
 
     def records(self) -> np.ndarray:

@@ -361,6 +361,10 @@ class FailoverPlane:
         wants.extend(wants[i % n_real]
                      for i in range(self.probe_count - n_real))
         batch = PacketBatch.from_packets(pkts)
+        # A new probe set can be a new shape of the canary program, and a
+        # first use compiles: seconds that say nothing about a replica's
+        # health.  Run it once here, outside the probe deadline's clock.
+        o._canary_classify(batch, now=0)
         self._probe_cache = (gen, batch, wants)
         return batch, wants
 

@@ -115,7 +115,7 @@ from ..datapath.tpuflow import TpuflowDatapath, _rids
 from ..observability.telemetry import classify_regime
 from ..observability.tracing import (SP_ACCOUNT, SP_ATTRIBUTE, SP_DISPATCH,
                                      SP_DONE, SP_FETCH, SP_STAGE, SP_UPLOAD,
-                                     SP_WAIT)
+                                     SP_WAIT, SS_RETRY, SS_ROUTE)
 from ..models import forwarding as fw
 from ..models import pipeline as pl
 from ..ops import hashing
@@ -160,8 +160,10 @@ def _mesh_step_fn(mesh, meta: pl.PipelineMeta):
     drain_reclaim), exactly like the single-chip pipeline_step."""
     lane = P(DATA)
 
-    def body(state, drs, dsvc, src_f, dst_f, proto, sport, dport, now,
-             gen, valid, no_commit, flags, lens):
+    # The XLA module is named after this function (`jit_mesh_step`): a
+    # device trace tells the drain's program from the full walk's.
+    def mesh_step(state, drs, dsvc, src_f, dst_f, proto, sport, dport, now,
+                  gen, valid, no_commit, flags, lens):
         local = jax.tree.map(lambda x: x[0], state)
         local, out = pl._pipeline_step(
             local, drs, dsvc, src_f, dst_f, proto, sport, dport, now, gen,
@@ -180,7 +182,7 @@ def _mesh_step_fn(mesh, meta: pl.PipelineMeta):
         return jax.tree.map(lambda x: x[None], local), out
 
     return jax.jit(_shard_map(
-        body,
+        mesh_step,
         mesh=mesh,
         in_specs=(_state_specs(),
                   _drs_specs(agg=meta.match.prune_budget > 0),
@@ -203,9 +205,11 @@ def _mesh_step_full_fn(mesh, meta: pl.PipelineMeta, has_arp: bool):
     ARP lane does — pure-IP batches keep the no-ARP program."""
     lane = P(DATA)
 
-    def body(state, drs, dsvc, dft, src_f, dst_f, proto, sport, dport,
-             in_port, now, gen, flags, arp_op, valid, no_commit, lens,
-             prune_excl):
+    # XLA module `jit_mesh_step_full`: the served step AND its spill
+    # retry (one function at two batch shapes), and nothing else.
+    def mesh_step_full(state, drs, dsvc, dft, src_f, dst_f, proto, sport,
+                       dport, in_port, now, gen, flags, arp_op, valid,
+                       no_commit, lens, prune_excl):
         local = jax.tree.map(lambda x: x[0], state)
         local, out = fw._pipeline_step_full(
             local, drs, dsvc, dft, src_f, dst_f, proto, sport, dport,
@@ -226,7 +230,7 @@ def _mesh_step_full_fn(mesh, meta: pl.PipelineMeta, has_arp: bool):
         return jax.tree.map(lambda x: x[None], local), out
 
     return jax.jit(_shard_map(
-        body,
+        mesh_step_full,
         mesh=mesh,
         in_specs=(_state_specs(),
                   _drs_specs(agg=meta.match.prune_budget > 0),
@@ -249,14 +253,14 @@ def _mesh_canary_fn(mesh, match_meta, fused):
     must certify the pallas consumer the step kernel uses, not the
     shadow XLA path (the fused consumer is shard-aware, so it composes
     with the pmin seam like the serving dispatch)."""
-    def body(drs, src_f, dst_f, proto, dport):
+    def mesh_canary(drs, src_f, dst_f, proto, dport):
         return m.classify_batch(
             drs, src_f, dst_f, proto, dport, meta=match_meta,
             hit_combine=_pmin_rule, fused=fused,
         )["code"]
 
     return jax.jit(_shard_map(
-        body,
+        mesh_canary,
         mesh=mesh,
         in_specs=(_drs_specs(agg=match_meta.prune_budget > 0),
                   P(DATA), P(DATA), P(DATA), P(DATA)),
@@ -784,6 +788,7 @@ class MeshDatapath(TpuflowDatapath):
         has_arp = batch.arp_op is not None
         arp = (np.asarray(batch.arp_ops()).astype(np.int32) if has_arp
                else np.zeros(B, np.int32))
+        tr.sub(SS_ROUTE)  # hash, failover mask, placement, permutation
         shard = shard_of_tuples(batch.src_ip, batch.dst_ip, batch.proto,
                                 batch.src_port, batch.dst_port, D,
                                 self._topo_gen, tenant=self._tenant_id())
@@ -810,6 +815,7 @@ class MeshDatapath(TpuflowDatapath):
                  flags[perm], arp[perm],
                  np.ones(B, bool) if ext is None else ext[perm], spill,
                  lens[perm].astype(np.int32), spill)
+        tr.sub_end()
         stepf = _mesh_step_full_fn(self._mesh, self._meta_step, has_arp)
         dsvc, dft = self._shared_tables()
 
@@ -862,8 +868,10 @@ class MeshDatapath(TpuflowDatapath):
         spilled = perm[np.nonzero(
             spill if ext is None else spill & ext[perm])[0]]  # off-home
         if spilled.size:
+            tr.sub(SS_RETRY)  # staging, the call, its wait and fetch, merge
             o = self._spill_retry(batch, o, spilled, shard, flags, in_ports,
                                   arp, has_arp, lens, now)
+            tr.sub_end()
         # Recomputed from the MERGED per-lane mask: a retried lane's miss
         # image is its home-shard one, not the foreign always-miss.
         n_miss = tr.n_miss = int(o["miss"].sum())
@@ -1031,8 +1039,10 @@ class MeshDatapath(TpuflowDatapath):
         pkts = idx[sel]
         for k in o:
             o[k][pkts] = o2[k][sel]
-        self._spill_lanes_total += int(spilled.size)
-        self._spill_retried_total += int(sel.size)
+        tr.spill_lanes = int(spilled.size)
+        tr.retry_lanes = int(sel.size)
+        self._spill_lanes_total += tr.spill_lanes
+        self._spill_retried_total += tr.retry_lanes
         return o
 
     # -- sharded slow-path callbacks -----------------------------------------

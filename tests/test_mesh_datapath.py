@@ -551,3 +551,112 @@ def test_mesh_group_delta_o1_slot_path_with_parity(world, mesh):
     ev = [e for e in mdp.flightrecorder_events(kind="commit")
           if e.get("delta")]
     assert ev and ev[-1]["outcome"] == "ok" and ev[-1]["stage"] == "settle"
+
+
+# --------------------------------------------------------------------------
+# np100k_mesh4's shape at a tiny size: four data replicas, rules replicated,
+# a Zipf(1)-headed batch whose head overflows one replica's home slice
+# --------------------------------------------------------------------------
+
+KW4 = dict(flow_slots=1 << 12, aff_slots=1 << 8, canary_probes=16)
+
+
+@pytest.fixture(scope="module")
+def zipf_run(world):
+    """Two steps of one skewed batch through the 4x1 mesh, the one-chip
+    engine and the scalar twin -> (batch, spilled lanes, engines, results,
+    the mesh's two step records)."""
+    from antrea_tpu.datapath import OracleDatapath
+    from antrea_tpu.packet import PacketBatch
+    from antrea_tpu.parallel.meshpath import _shard_placement
+
+    cluster, services = world
+    pool = gen_traffic(cluster.pod_ips, 512, n_flows=256, seed=17,
+                       services=services, svc_fraction=0.3)
+    cols = np.stack([pool.src_ip.astype(np.int64), pool.dst_ip, pool.proto,
+                     pool.src_port, pool.dst_port], 1)
+    _, first = np.unique(cols, axis=0, return_index=True)
+    flows = np.sort(first)[:64]  # 64 distinct connections
+    w = 1.0 / np.arange(1, 65)  # Zipf(1): the first takes 21 % of the lanes
+    lane_flow = np.random.default_rng(1).choice(flows, 256, p=w / w.sum())
+    lane_flow[:8] = flows[:8]
+    batch = PacketBatch.from_packets([pool.packet(int(i))
+                                      for i in lane_flow])
+    shard = pm.shard_of_tuples(batch.src_ip, batch.dst_ip, batch.proto,
+                               batch.src_port, batch.dst_port, 4)
+    assert np.bincount(shard, minlength=4).max() > 64  # one home overflows
+    perm, _inv, spill = _shard_placement(shard, 4)
+    mdp = MeshDatapath(cluster.ps, services, n_data=4, n_rule=1,
+                       devices=jax.devices("cpu")[:4], **KW4)
+    sdp = TpuflowDatapath(cluster.ps, services, **KW4)
+    odp = OracleDatapath(cluster.ps, services, **KW4)
+    results = [[dp.step(batch, 100 + t) for dp in (mdp, sdp, odp)]
+               for t in range(2)]
+    return (batch, shard, perm[spill], mdp, results,
+            mdp.step_trace()["records"])
+
+
+@pytest.mark.parametrize("t", [0, 1])
+def test_zipf_head_overflow_answers_like_one_chip_and_oracle(zipf_run, t):
+    """Sharding changes no answer: verdict, Service resolution, DNAT and
+    rule ids of EVERY lane equal the one-chip engine's and the scalar
+    twin's in both steps; so do `est` and `committed` — but for the retried
+    lanes of the FIRST step: the retry is a second dispatch, so a lane
+    whose flow's home lanes were committed a moment earlier in the same
+    call reads est=1/committed=0 where an unsharded batch reads 0/1
+    (allowed, once more, by the same walk).  Every spilled lane was
+    re-served from home in the same call."""
+    batch, _shard, spilled, _mdp, results, rec = zipf_run
+    rm, rs, ro = results[t]
+    for other in (rs, ro):
+        for k in ("code", "svc_idx", "dnat_ip", "dnat_port", "reject_kind"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(rm, k)), np.asarray(getattr(other, k)),
+                err_msg=f"step{t}:{k}")
+        assert rm.ingress_rule == other.ingress_rule
+        assert rm.egress_rule == other.egress_rule
+        est, com = np.asarray(rm.est) != 0, np.asarray(rm.committed) != 0
+        est_o = np.asarray(other.est) != 0
+        com_o = np.asarray(other.committed) != 0
+        home = np.ones(batch.size, bool)
+        home[spilled] = t > 0
+        np.testing.assert_array_equal(est[home], est_o[home])
+        np.testing.assert_array_equal(com[home], com_o[home])
+        np.testing.assert_array_equal(est | com, est_o | com_o)
+    assert rec["spill_lanes"][t] == spilled.size > 0
+    assert rec["retry_lanes"][t] == rec["spill_lanes"][t]
+    if t:  # after two steps every spilled flow is established
+        allowed = np.asarray(rm.code) == 0
+        assert allowed[spilled].any()
+        assert (np.asarray(rm.est)[spilled] != 0)[allowed[spilled]].all()
+
+
+def test_replica_tables_hold_every_committed_flow_once_at_home(zipf_run):
+    """The share tied to the whole: after the run the four private tables
+    together hold every committed flow exactly once, in the replica that
+    `shard_of_tuples` names — none stranded in a foreign replica by the
+    spill, none twice."""
+    from antrea_tpu.utils import ip as iputil
+
+    batch, shard, _spilled, mdp, results, _rec = zipf_run
+    held = {}
+    for r in range(4):
+        local = jax.tree.map(lambda x, r=r: x[r], mdp._state)
+        for e in mdp._dump_flows_state(local, 103):
+            key = (iputil.ip_to_key(e["src"]), iputil.ip_to_key(e["dst"]),
+                   e["proto"], e["sport"], e["dport"])
+            held.setdefault(key, []).append(r)
+    assert sum(len(v) for v in held.values()) == len(mdp.dump_flows(103))
+    # A committed flow: allowed, so est in the second step (its first-step
+    # lanes read committed, or est where the retry served them).
+    rm = results[1][0]
+    committed = (np.asarray(rm.code) == 0) & (np.asarray(rm.est) != 0)
+    first = results[0][0]
+    assert ((np.asarray(first.committed) != 0)
+            <= committed).all() and committed.sum() > 16
+    for i in np.nonzero(committed)[0]:
+        key = (int(batch.src_ip[i]), int(batch.dst_ip[i]),
+               int(batch.proto[i]), int(batch.src_port[i]),
+               int(batch.dst_port[i]))
+        assert held.get(key) == [int(shard[i])], (i, key, held.get(key))
+    assert all(len(v) == 1 for v in held.values())

@@ -9,6 +9,10 @@ What is held:
   * the ring drops oldest and meters it; a raising `_step` leaves a closed
     record and still feeds step_hist;
   * h2d/d2h counters equal, by hand, what one batch shape uploads/fetches;
+  * the mesh's sub-spans (`route` in `stage`, `retry` in `account`) lie
+    inside their phases and out of the telescoping sum, its `spill_lanes` /
+    `retry_lanes` are `mesh_stats()`'s deltas, and the one-chip engine
+    records zeros for all of them;
   * the instrumented `_step` (hoisted staging, `block_until_ready`) answers
     and mutates state exactly like the dispatch it replaced, and its
     vectorised `attribute` phase (rule ids by table gather, masked wide
@@ -38,7 +42,8 @@ from antrea_tpu.datapath.tpuflow import _rid
 from antrea_tpu.models import forwarding as fwd
 from antrea_tpu.observability import tracing
 from antrea_tpu.observability.tracing import (STEP_PHASES, STEP_RECORD,
-                                              STEP_SCOPES, StepTracer)
+                                              STEP_SCOPES, STEP_SUBSPANS,
+                                              StepTracer)
 from antrea_tpu.packet import Packet, PacketBatch
 from antrea_tpu.simulator import gen_cluster, gen_services, gen_traffic
 from antrea_tpu.utils import ip as iputil
@@ -141,6 +146,79 @@ def test_transfer_counters_by_hand(engine):
         assert rec["d2h_bytes"] == sum(
             int(np.prod(v.shape)) * v.dtype.itemsize for v in out.values())
     assert rec["d2h_bytes"] > 20 * B * 4  # ~25 per-lane i32 outputs
+
+
+def test_sub_spans_are_zero_on_one_chip(engine):
+    """`route` and `retry` are the mesh's work: the one-chip engine never
+    opens them and places no lane off-home, so its records hold zeros."""
+    kind, dp, _ = engine
+    rec = dp.step_trace()["records"]
+    assert [f"{s}_{e}" for s, _ in STEP_SUBSPANS for e in ("t0", "t1")] + [
+        "spill_lanes", "retry_lanes"] == list(STEP_RECORD.names[-6:])
+    assert {p for _, p in STEP_SUBSPANS} <= set(STEP_PHASES)
+    if kind == "tpuflow":
+        for f in STEP_RECORD.names[-6:]:
+            assert (rec[f] == 0).all(), f
+    else:  # the mesh routes every step; it retries only what spilled
+        assert (rec["route_t1"] > rec["route_t0"]).all()
+        assert (rec["retry_lanes"] == rec["spill_lanes"]).all()
+        assert ((rec["retry_t1"] > rec["retry_t0"])
+                == (rec["spill_lanes"] > 0)).all()
+
+
+def test_mesh_sub_spans_lie_inside_their_phases(world):
+    """A batch that all homes to replica 0 of 2: half its lanes spill, and
+    the retry re-serves them.  `route` lies inside `stage`, `retry` inside
+    `account`, the seven phases still telescope to the span, and the two
+    counters are `mesh_stats()`'s deltas, step by step."""
+    from antrea_tpu.parallel import mesh as pm
+
+    dp = _make("mesh", world)
+    b = world[2]
+    shard = pm.shard_of_tuples(b.src_ip, b.dst_ip, b.proto, b.src_port,
+                               b.dst_port, 2)
+    idx = np.nonzero(shard == 0)[0][:64]
+    assert idx.size == 64
+    skew = PacketBatch(**{f: getattr(b, f)[idx] for f in (
+        "src_ip", "dst_ip", "proto", "src_port", "dst_port")})
+    before = dp.mesh_stats()
+    totals = []
+    for t in range(3):
+        dp.step(skew, now=30 + t)
+        s = dp.mesh_stats()
+        totals.append((s["spill_lanes_total"], s["spill_retried_total"]))
+    rec = dp.step_trace()["records"]
+    assert (rec["spill_lanes"] == 32).all()  # 64 lanes, 32 a home slice
+    start = (before["spill_lanes_total"], before["spill_retried_total"])
+    deltas = np.diff(np.array([start] + totals), axis=0)
+    assert deltas[:, 0].tolist() == rec["spill_lanes"].tolist()
+    assert deltas[:, 1].tolist() == rec["retry_lanes"].tolist()
+    assert (rec["t_stage"] <= rec["route_t0"]).all()
+    assert (rec["route_t0"] < rec["route_t1"]).all()
+    assert (rec["route_t1"] <= rec["t_upload"]).all()
+    assert (rec["t_account"] <= rec["retry_t0"]).all()
+    assert (rec["retry_t0"] < rec["retry_t1"]).all()
+    assert (rec["retry_t1"] <= rec["t_attribute"]).all()
+    # the sub-spans are not phases: the seven still fill the span
+    stamps = np.stack([rec[s] for s in STAMPS], axis=1)
+    assert (np.diff(stamps, axis=1) >= 0).all()
+    assert (np.diff(stamps[:, 1:-1], axis=1).sum(axis=1)
+            == rec["t_done"] - rec["t_stage"]).all()
+    # the retry's transfers are the step's: two dispatches' worth
+    assert (rec["d2h_transfers"] % 2 == 0).all()
+
+
+def test_a_raise_inside_a_sub_span_closes_it():
+    tr = StepTracer()
+    tr.begin(lanes=4)
+    tr.phase(0)
+    tr.sub(0)
+    assert tr.end() >= 0  # what `step` does when `_step` raised
+    (rec,) = tr.records()
+    assert rec["t_stage"] <= rec["route_t0"] <= rec["route_t1"] <= rec["t_end"]
+    tr.begin(lanes=4)  # the next record starts from zeros
+    tr.end()
+    assert tr.records()["route_t1"].tolist()[1] == 0
 
 
 def test_the_ring_drops_oldest_and_meters_it(world, monkeypatch):
