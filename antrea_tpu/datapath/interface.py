@@ -52,6 +52,13 @@ class StepResult:
 
     rule ids are stable string identities (compiler.ir.rule_id); None where
     no explicit rule decided (default allow / K8s default deny).
+
+    Values and shape are the contract, not a dtype: the tpuflow engines
+    hand the flags and small enums (code, est, pending, reply,
+    reject_kind, committed, snat, dsr, spoofed, l7_redirect, punt,
+    fwd_kind, dec_ttl, tc_act) on as int8 views of the step's egress
+    record (models/forwarding.EGRESS_RECORD) — compare by value, widen
+    before arithmetic that can pass 127.
     """
 
     code: np.ndarray  # 0 allow / 1 drop / 2 reject

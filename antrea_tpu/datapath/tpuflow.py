@@ -639,22 +639,26 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         v6 = None if v6 is None else tuple(up(x) for x in v6)
         vmask = up(vmask)
 
-        # ---- dispatch: the jitted call until it returns --------------------
+        # ---- dispatch: the jitted call until it returns, and the egress
+        # copies started behind it -------------------------------------------
         tr.phase(SP_DISPATCH)
-        state, out = fwd.pipeline_step_full(
+        state, rec, rest = fwd.pipeline_step_full_packed(
             self._state, self._drs, self._dsvc, self._dft, *args,
             meta=self._meta_step, v6=v6, valid=vmask,
         )
         self._state = state
         self._state_mutations += 1
+        fwd.start_egress_copies(rec, rest)
         # ---- wait: the host waiting for the device.  The first fetch
         # below would wait for the whole executable anyway; this only
         # tells the waiting from the copies. --------------------------------
         tr.phase(SP_WAIT)
-        jax.block_until_ready(out)
-        # ---- fetch: one device->host copy per output -----------------------
+        jax.block_until_ready((rec, rest))
+        # ---- fetch: the record's three blocks land (one copy each, and one
+        # per optional output beside them); the fields are row views ---------
         tr.phase(SP_FETCH)
-        o = {k: tr.fetched(np.asarray(v)) for k, v in out.items()}
+        blocks, rest = fwd.fetch_egress(rec, rest, tr.fetched)
+        o = {**fwd.unpack_egress(*blocks), **rest}
         # ---- account: counters, admission, telemetry, per-rule stats -------
         tr.phase(SP_ACCOUNT)
         tr.n_miss = int(o["n_miss"])

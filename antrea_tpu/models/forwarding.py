@@ -23,6 +23,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..compiler.compile import ACT_ALLOW, ACT_DROP
 from ..compiler.topology import (
@@ -496,4 +497,94 @@ def _pipeline_step_full(
 
 pipeline_step_full = jax.jit(
     _pipeline_step_full, static_argnames=("meta", "hit_combine")
+)
+
+
+# -- the served step's egress record -------------------------------------------
+# What the host reads back from EVERY served step, as three arrays instead
+# of one per output: the word-wide lane columns as rows of one i32 block,
+# the flags and small enums as rows of one i8 block (a row is a contiguous
+# view on the host: nothing to unpack), the scalar counters as one vector.
+# The ONE place the layout is spelled, in tracing.STEP_RECORD's style:
+# pack_egress writes by it, unpack_egress and the engines' byte counts
+# read by it.  (field, block, row, bits, signed): a value the width cannot
+# hold would wrap silently in the cast, so tests/test_egress_record.py
+# holds every enum constant of a narrow field against its width.
+EGRESS_WORDS = (
+    "svc_idx", "dnat_ip_f", "dnat_port", "ingress_rule", "egress_rule",
+    "out_port", "peer_f", "tc_port", "mcast_idx",
+)
+EGRESS_NARROW = (
+    "code", "est", "reply", "reject_kind", "committed", "miss", "snat", "dsr",
+    "spoofed", "l7_redirect", "punt", "fwd_kind", "dec_ttl", "tc_act",
+)
+EGRESS_SCALARS = ("n_miss", "n_evict", "n_reclaim")
+EGRESS_RECORD = tuple(
+    (field, block, row, bits, True)
+    for block, fields, bits in (("words", EGRESS_WORDS, 32),
+                                ("narrow", EGRESS_NARROW, 8),
+                                ("scalars", EGRESS_SCALARS, 32))
+    for row, field in enumerate(fields)
+)
+
+
+class EgressRecord(NamedTuple):
+    words: jax.Array  # (len(EGRESS_WORDS), B) i32
+    narrow: jax.Array  # (len(EGRESS_NARROW), B) i8
+    scalars: jax.Array  # (len(EGRESS_SCALARS),) i32
+
+
+def pack_egress(out: dict):
+    """The step's output dict -> (EgressRecord, rest): every field of
+    EGRESS_RECORD packed into its block, and whatever else the dict holds
+    (outputs that exist only under an option: the prune and telemetry
+    counters, dual-stack's wide `dnat_w_f` / `peer_w`) handed on as it is.
+    Runs inside the jitted step, so the host fetches three arrays."""
+    with device_scope("egress"):
+        rec = EgressRecord(
+            jnp.stack([out[f].astype(jnp.int32) for f in EGRESS_WORDS]),
+            jnp.stack([out[f].astype(jnp.int8) for f in EGRESS_NARROW]),
+            jnp.stack([out[f].astype(jnp.int32) for f in EGRESS_SCALARS]),
+        )
+    packed = {field for field, *_ in EGRESS_RECORD}
+    return rec, {k: v for k, v in out.items() if k not in packed}
+
+
+def start_egress_copies(rec: EgressRecord, rest: dict) -> None:
+    """Start the device->host copies of a dispatched step's egress: they
+    run behind the program, all at once, as soon as it has written them —
+    nobody waits for a round trip an output."""
+    for a in (*rec, *rest.values()):
+        a.copy_to_host_async()
+
+
+def fetch_egress(rec: EgressRecord, rest: dict, fetched):
+    """The egress landed on the host -> ((words, narrow, scalars), rest)
+    as numpy, every copy handed through `fetched` (the step tracer's
+    transfer counter) once."""
+    return (tuple(fetched(np.asarray(a)) for a in rec),
+            {k: fetched(np.asarray(v)) for k, v in rest.items()})
+
+
+def unpack_egress(words, narrow, scalars=()) -> dict:
+    """The fetched blocks (numpy) -> the step's output dict, field by
+    field a ROW VIEW of its block: no copy, and a narrow field keeps its
+    block's i8 — readers compare by value.  `scalars` is the one-chip
+    step's (3,); the mesh folds its (3, D) block itself and leaves it out."""
+    o = dict(zip(EGRESS_WORDS, words))
+    o.update(zip(EGRESS_NARROW, narrow))
+    o.update(zip(EGRESS_SCALARS, scalars))
+    return o
+
+
+def _pipeline_step_full_packed(*args, **kw):
+    """The SERVED step: `_pipeline_step_full` and `pack_egress` in one
+    program -> (state, EgressRecord, rest).  The XLA module is named after
+    this function (`jit__pipeline_step_full_packed`)."""
+    state, out = _pipeline_step_full(*args, **kw)
+    return (state, *pack_egress(out))
+
+
+pipeline_step_full_packed = jax.jit(
+    _pipeline_step_full_packed, static_argnames=("meta", "hit_combine")
 )

@@ -7,9 +7,10 @@ models/forwarding, ops/match), the cut models/profile.PHASE_CHAIN makes
 by differencing masked programs: every op of the step lowers under a
 path of these, nested as listed (`probe`/`refresh`/`assemble` inside
 `fast_path`; the round-loop scopes and the `classify.*` stages inside
-`miss_detect`; `eviction_scan` inside `cache_commit`).  The ONE place
-the scope names are declared: call sites go through `device_scope`,
-which refuses any other name.
+`miss_detect`; `eviction_scan` inside `cache_commit`; `egress`, the
+packing of the served step's outputs into one record, after them all).
+The ONE place the scope names are declared: call sites go through
+`device_scope`, which refuses any other name.
 """
 
 import jax
@@ -17,7 +18,7 @@ import jax
 STEP_SCOPES = (
     "fast_path", "probe", "refresh", "assemble", "forwarding", "miss_detect",
     "service_lb", "classify", "classify.summary", "classify.candidate",
-    "classify.scan", "cache_commit", "eviction_scan",
+    "classify.scan", "cache_commit", "eviction_scan", "egress",
 )
 
 

@@ -219,16 +219,17 @@ def _mesh_step_full_fn(mesh, meta: pl.PipelineMeta, has_arp: bool):
             meta=meta, hit_combine=_pmin_rule, valid=valid,
             no_commit=no_commit, prune_exclude=prune_excl,
         )
-        # scalar per shard -> (D,) vector of per-data-shard counts (the
-        # prune keys exist iff the meta carries a prune budget)
-        for k in ("n_miss", "n_evict", "n_reclaim", "n_prune_skips",
-                  "n_prune_fb", "prune_cand_hist",
-                  "tel_probe_hit", "tel_probe_stale", "tel_probe_miss",
-                  "tel_dma_hb", "tel_chance_bumps"):
-            if k in out:
-                out[k] = out[k][None]
-        return jax.tree.map(lambda x: x[None], local), out
+        # The same egress record as the one-chip step (models/forwarding
+        # .pack_egress), lanes on the blocks' second axis; the scalars one
+        # column a replica.  What rides beside it is per-shard scalars
+        # (the prune keys exist iff the meta carries a prune budget, the
+        # telemetry keys iff it carries telemetry): -> (D,) vectors.
+        rec, rest = fw.pack_egress(out)
+        rec = rec._replace(scalars=rec.scalars[:, None])
+        rest = {k: v[None] for k, v in rest.items()}
+        return jax.tree.map(lambda x: x[None], local), rec, rest
 
+    block = P(None, DATA)
     return jax.jit(_shard_map(
         mesh_step_full,
         mesh=mesh,
@@ -237,7 +238,8 @@ def _mesh_step_full_fn(mesh, meta: pl.PipelineMeta, has_arp: bool):
                   _svc_specs(), _fwd_specs(),
                   lane, lane, lane, lane, lane, lane, P(), P(),
                   lane, lane, lane, lane, lane, lane),
-        out_specs=(_state_specs(), P(DATA)),
+        out_specs=(_state_specs(), fw.EgressRecord(block, block, block),
+                   P(DATA)),
     ))
 
 
@@ -830,14 +832,15 @@ class MeshDatapath(TpuflowDatapath):
 
         # ---- dispatch / wait / fetch (the tpuflow boundaries) --------------
         tr.phase(SP_DISPATCH)
-        state, out = stepf(self._state, self._drs, dsvc, dft, *lanes[:6],
-                           *scalars, *lanes[6:])
+        state, rec, rest = stepf(self._state, self._drs, dsvc, dft,
+                                 *lanes[:6], *scalars, *lanes[6:])
         self._state = state
         self._state_mutations += 1
+        fw.start_egress_copies(rec, rest)
         tr.phase(SP_WAIT)
-        jax.block_until_ready(out)
+        jax.block_until_ready((rec, rest))
         tr.phase(SP_FETCH)
-        o = {k: tr.fetched(np.asarray(v)) for k, v in out.items()}
+        (words, narrow, counts), rest = fw.fetch_egress(rec, rest, tr.fetched)
         tr.phase(SP_ACCOUNT)
         if fo is not None:
             # Dispatch-liveness deadline: a stalled sharded dispatch is a
@@ -845,33 +848,33 @@ class MeshDatapath(TpuflowDatapath):
             # just stamped (upload included: the call uploads) — no clock
             # pair of its own.
             fo.note_dispatch(tr.since(SP_DISPATCH), now)
-        o.pop("n_miss")
-        self._evictions += int(o.pop("n_evict").sum())
-        self._reclaims += int(o.pop("n_reclaim").sum())
+        self._account_counts(counts)
         # Spilled lanes are EXCLUDED from this dispatch's prune evidence
         # (prune_exclude=spill above): their foreign-shard walk is not
         # the serving walk, and the home-routed retry below accounts
         # them instead — each lane feeds the PruneAutotuner band exactly
         # once, from the walk production actually serves (round 8; the
         # PR 10 dedupe kept the foreign evidence instead).
-        self._prune_account(o)
-        for k in ("n_prune_skips", "n_prune_fb", "prune_cand_hist"):
-            o.pop(k, None)
-        # Telemetry counters ride (D,) per-replica — pop them before the
-        # per-LANE reindex below.  Spilled lanes are excluded from this
-        # dispatch's counters too (same prune_exclude=spill mask): their
-        # serving probe is the home-routed retry's, which accounts them
-        # (each lane's probe is metered exactly once, from the walk that
-        # serves it).
-        tel_o = {k: o.pop(k) for k in tuple(o) if k.startswith("tel_")}
-        o = {k: v[inv] for k, v in o.items()}  # back to packet order
+        self._prune_account(rest)
+        # Telemetry counters ride (D,) per-replica beside the record.
+        # Spilled lanes are excluded from this dispatch's counters too
+        # (same prune_exclude=spill mask): their serving probe is the
+        # home-routed retry's, which accounts them (each lane's probe is
+        # metered exactly once, from the walk that serves it).
+        tel_o = {k: v for k, v in rest.items() if k.startswith("tel_")}
+        # Back to packet order, on the packed blocks: two gathers, not one
+        # a field.
+        words = np.take(words, inv, axis=1)
+        narrow = np.take(narrow, inv, axis=1)
         spilled = perm[np.nonzero(
             spill if ext is None else spill & ext[perm])[0]]  # off-home
         if spilled.size:
             tr.sub(SS_RETRY)  # staging, the call, its wait and fetch, merge
-            o = self._spill_retry(batch, o, spilled, shard, flags, in_ports,
-                                  arp, has_arp, lens, now)
+            self._spill_retry(batch, words, narrow, spilled, shard, flags,
+                              in_ports, arp, has_arp, lens, now)
             tr.sub_end()
+        # The fields, once, after the merge: row views of the two blocks.
+        o = fw.unpack_egress(words, narrow)
         # Recomputed from the MERGED per-lane mask: a retried lane's miss
         # image is its home-shard one, not the foreign always-miss.
         n_miss = tr.n_miss = int(o["miss"].sum())
@@ -965,10 +968,11 @@ class MeshDatapath(TpuflowDatapath):
         tr.phase(SP_DONE)
         return res
 
-    def _spill_retry(self, batch: PacketBatch, o: dict, spilled: np.ndarray,
+    def _spill_retry(self, batch: PacketBatch, words: np.ndarray,
+                     narrow: np.ndarray, spilled: np.ndarray,
                      shard: np.ndarray, flags: np.ndarray,
                      in_ports: np.ndarray, arp: np.ndarray, has_arp: bool,
-                     lens: np.ndarray, now: int) -> dict:
+                     lens: np.ndarray, now: int) -> None:
         """Second, bounded, HOME-ROUTED dispatch for hash-skew overflow.
 
         Spilled lanes' main-dispatch image is a foreign-shard walk: they
@@ -981,7 +985,7 @@ class MeshDatapath(TpuflowDatapath):
         beyond one full home slice (B/D lanes; the all-flows-one-shard
         pathology) keeps the documented provisional-spill semantics
         rather than cascading dispatches.  Merges the retried lanes'
-        outputs into `o` (packet order) and returns it."""
+        records into the two egress blocks (packet order), in place."""
         D = self._n_data
         C = batch.size // D
         by_shard = [spilled[shard[spilled] == r] for r in range(D)]
@@ -1010,15 +1014,15 @@ class MeshDatapath(TpuflowDatapath):
             batch.dst_port[idx].astype(np.int32), in_ports[idx],
             rflags, arp[idx], valid, np.zeros(idx.size, bool),
             lens[idx].astype(np.int32), ~valid)]
-        state, out = stepf(
+        state, rec, rest = stepf(
             self._state, self._drs, dsvc, dft, *lanes[:6],
             self._upload_i32(now), self._upload_i32(self._gen), *lanes[6:])
         self._state = state
         self._state_mutations += 1
-        o2 = {k: tr.fetched(np.asarray(v)) for k, v in out.items()}
-        self._evictions += int(o2.pop("n_evict").sum())
-        self._reclaims += int(o2.pop("n_reclaim").sum())
-        o2.pop("n_miss")
+        fw.start_egress_copies(rec, rest)
+        (words2, narrow2, counts), rest = fw.fetch_egress(rec, rest,
+                                                          tr.fetched)
+        self._account_counts(counts)
         # The retry owns the retried lanes' prune evidence (the main
         # dispatch excluded them via prune_exclude=spill): each lane is
         # metered exactly once, from its HOME (serving) walk — counting
@@ -1027,23 +1031,27 @@ class MeshDatapath(TpuflowDatapath):
         # (regression-pinned by the skew-batch case in
         # tests/test_match_fused.py).  Padding lanes are excluded via
         # prune_exclude=~valid above.
-        self._prune_account(o2)
-        for k in ("n_prune_skips", "n_prune_fb", "prune_cand_hist"):
-            o2.pop(k, None)
+        self._prune_account(rest)
         if self._telemetry is not None:
             # The retry owns the retried lanes' PROBE counters too (the
             # main dispatch masked them out, same as the prune evidence);
             # padding lanes ride excluded via prune_exclude=~valid.
-            self._telemetry.account(o2)
+            self._telemetry.account(rest)
         sel = np.nonzero(valid)[0]
         pkts = idx[sel]
-        for k in o:
-            o[k][pkts] = o2[k][sel]
+        words[:, pkts] = np.take(words2, sel, axis=1)
+        narrow[:, pkts] = np.take(narrow2, sel, axis=1)
         tr.spill_lanes = int(spilled.size)
         tr.retry_lanes = int(sel.size)
         self._spill_lanes_total += tr.spill_lanes
         self._spill_retried_total += tr.retry_lanes
-        return o
+
+    def _account_counts(self, counts: np.ndarray) -> None:
+        """Fold one sharded call's (3, D) scalar block.  `n_miss` is not
+        read: the step recomputes it from the MERGED per-lane mask."""
+        per = dict(zip(fw.EGRESS_SCALARS, counts.sum(axis=1).tolist()))
+        self._evictions += per["n_evict"]
+        self._reclaims += per["n_reclaim"]
 
     # -- sharded slow-path callbacks -----------------------------------------
 

@@ -98,7 +98,7 @@ def test_compile_bound_64_uneven_tenants_under_bursty():
     dp = _dp(TpuflowDatapath, None, flightrec_slots=0,
              serving_batcher=True, canonical_sizes=ladder,
              flush_deadline=2)
-    exec0 = fwd_model.pipeline_step_full._cache_size()
+    exec0 = fwd_model.pipeline_step_full_packed._cache_size()
     tids = []
     for i in range(64):
         c = shapes[i % 4]
@@ -124,7 +124,7 @@ def test_compile_bound_64_uneven_tenants_under_bursty():
         served += int(np.asarray(res.code).shape[0])
     assert served == sum(e[0].size for e in sched if e is not None)
 
-    execs = fwd_model.pipeline_step_full._cache_size() - exec0
+    execs = fwd_model.pipeline_step_full_packed._cache_size() - exec0
     bound = len(rungs) * len(ladder)
     assert 0 < execs <= bound, (
         f"{execs} step executables for 64 bursty tenants — the batcher "
@@ -295,11 +295,11 @@ def test_step_traces_identically_with_batcher_configured():
     batch = _batch(c, 32, seed=7)
     dp_off = _dp(TpuflowDatapath, c)
     r_off = dp_off.step(batch, 1.0)
-    exec0 = fwd_model.pipeline_step_full._cache_size()
+    exec0 = fwd_model.pipeline_step_full_packed._cache_size()
     dp_on = _dp(TpuflowDatapath, c, serving_batcher=True,
                 canonical_sizes=(8, 32))
     r_on = dp_on.step(batch, 1.0)
-    assert fwd_model.pipeline_step_full._cache_size() == exec0, (
+    assert fwd_model.pipeline_step_full_packed._cache_size() == exec0, (
         "step() with the batcher configured must reuse the exact "
         "executable of the batcher-less engine (valid=None is not a "
         "program change)")

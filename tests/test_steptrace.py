@@ -134,18 +134,26 @@ def test_transfer_counters_by_hand(engine):
         # (valid, no_commit=spill, prune_exclude=spill).
         assert rec["h2d_transfers"] >= 14
         assert rec["h2d_bytes"] >= 9 * B * 4 + 3 * B + 2 * 4
-    # Every output of the step program is fetched once; by its shape.
-    cols = [iputil.flip_u32(np.zeros(B, np.uint32))] * 2 + [
-        np.zeros(B, np.int32)] * 5
-    if kind == "tpuflow":
-        out = jax.eval_shape(
-            lambda *a: fwd.pipeline_step_full(
-                dp._state, dp._drs, dp._dsvc, dp._dft, *a[:6], jnp.int32(1),
-                jnp.int32(1), a[6], meta=dp._meta_step)[1], *cols)
-        assert rec["d2h_transfers"] == len(out)
-        assert rec["d2h_bytes"] == sum(
-            int(np.prod(v.shape)) * v.dtype.itemsize for v in out.values())
-    assert rec["d2h_bytes"] > 20 * B * 4  # ~25 per-lane i32 outputs
+    # The egress record, by hand from its schema: one copy a block — nine
+    # i32 rows and fourteen i8 rows of B lanes, three i32 scalars (one
+    # column a replica on the mesh) — and nothing beside it, since no
+    # option with an output of its own is on (tests/test_egress_record.py
+    # holds the program's shapes).
+    rows = {b: sum(1 for _, blk, *_ in fwd.EGRESS_RECORD if blk == b)
+            for b in ("words", "narrow", "scalars")}
+    assert rows == {"words": 9, "narrow": 14, "scalars": 3}
+    replicas = 1 if kind == "tpuflow" else 2
+    lane_bytes, retried = 9 * 4 + 14 * 1, int(rec["retry_lanes"])
+    once = lane_bytes * B + 3 * replicas * 4
+    if not retried:
+        assert (rec["d2h_transfers"], rec["d2h_bytes"]) == (3, once)
+    else:  # the mesh's spill retry fetches a second record: a power-of-
+        # two rung of lanes a replica, wide enough for what spilled
+        assert kind == "mesh" and rec["d2h_transfers"] == 6
+        rung, rest = divmod(int(rec["d2h_bytes"]) - once - 3 * replicas * 4,
+                            lane_bytes * replicas)
+        assert rest == 0 and rung & (rung - 1) == 0
+        assert retried <= rung * replicas <= B
 
 
 def test_sub_spans_are_zero_on_one_chip(engine):
@@ -423,7 +431,7 @@ def test_last_commit_after_a_direct_install(world):
 
 def _lowered_text(dp):
     i32 = jnp.zeros(B, jnp.int32)
-    return fwd.pipeline_step_full.lower(
+    return fwd.pipeline_step_full_packed.lower(
         dp._state, dp._drs, dp._dsvc, dp._dft, i32, i32, i32, i32, i32, i32,
         jnp.int32(1), jnp.int32(1), i32, None, None,
         meta=dp._meta_step).as_text(debug_info=True)

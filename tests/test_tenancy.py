@@ -308,7 +308,7 @@ def test_shared_compile_executables_track_rungs_not_tenants():
               for n, s in ((6, 1), (20, 2), (45, 3), (100, 4))]
     dp = TpuflowDatapath(flow_slots=1 << 10, aff_slots=1 << 8,
                          flightrec_slots=0, realization_slots=0)
-    exec0 = fwd_model.pipeline_step_full._cache_size()
+    exec0 = fwd_model.pipeline_step_full_packed._cache_size()
     tids = []
     for i in range(64):
         c = shapes[i % 4]
@@ -320,7 +320,7 @@ def test_shared_compile_executables_track_rungs_not_tenants():
     b = {id(c): _batch(c, 32, seed=77) for c in shapes}
     for tid, c in tids:
         dp.tenant_step(tid, b[id(c)], 100)
-    execs = fwd_model.pipeline_step_full._cache_size() - exec0
+    execs = fwd_model.pipeline_step_full_packed._cache_size() - exec0
     assert execs == len(rungs), (
         f"{execs} step executables for 64 tenants on {len(rungs)} rungs "
         f"— compile count must track rungs, not tenants")
