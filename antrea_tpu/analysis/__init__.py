@@ -8,7 +8,7 @@ Public surface:
                                                shim entry point
   PASSES                                       id -> (fn, invariant)
 
-The nine legacy `tools/check_*.py` gates live here as passes (the tools
+The eight legacy `tools/check_*.py` gates live here as passes (the tools
 remain as thin CLI shims, verdict-identical — pinned by
 tests/test_static_analysis.py), joined by the semantic passes that
 pin the hand-caught bug classes: `thread-safety`, `bounded-cache`,
@@ -32,11 +32,10 @@ from .core import (  # noqa: F401
 )
 
 # Importing the pass modules registers them (registration order is the
-# run order: the nine migrated gates first, then the semantic passes).
+# run order: the eight migrated gates first, then the semantic passes).
 from . import (  # noqa: E402,F401
     mesh,
     metrics,
-    phases,
     events,
     commit_plane,
     audit_plane,

@@ -1,6 +1,6 @@
 """Core of the unified static-analysis plane.
 
-One engine for every compile-time gate in the repo: the nine legacy
+One engine for every compile-time gate in the repo: the eight legacy
 `tools/check_*.py` drift checks (migrated here as passes — the CLIs
 remain as thin shims) and the semantic passes that pin the bug classes
 review kept catching by hand (handler-thread reads of live engine
@@ -16,7 +16,7 @@ checked, and so is the repo):
     suite runs from the tier-1 suite (tests/test_static_analysis.py)
     in ONE invocation.
   * ONE PARSED-MODULE CACHE: `SourceCache` parses each file at most
-    once per run, shared by all passes — the nine legacy tools each
+    once per run, shared by all passes — the eight legacy tools each
     re-read and re-parsed the tree; the suite now pays one walk.
   * TYPED FINDINGS: every problem is a `Finding` with file:line, the
     pass id, a stable key and a human reason — machine-readable via
@@ -271,7 +271,7 @@ def run(root: pathlib.Path | str = REPO,
 
 
 def run_cli(pass_id: str, argv: Optional[list[str]] = None) -> int:
-    """The thin-shim entry point of the nine migrated tools/check_*.py
+    """The thin-shim entry point of the eight migrated tools/check_*.py
     CLIs: run ONE pass (baseline applied, exactly like the full suite),
     print findings in the legacy DRIFT format, exit 0/1 — verdict parity
     with the pre-migration tools is pinned by

@@ -17,9 +17,12 @@ Three mechanisms, one plane:
   1. cache revalidation scan — each `audit_scan` samples a rotating cursor
      window of live flow-cache entries, reconstructs their 5-tuples,
      re-classifies them through the engine's fresh-walk path (tpuflow: the
-     EAGER `_pipeline_trace` machinery the canary uses, so no XLA
-     recompile; oracle: `fresh_walk`) and diffs cached verdict, rule
-     attribution and service selection.  Conntrack-committed (eternal-gen)
+     EAGER `_pipeline_trace` machinery the canary uses, at the one lane
+     count `_audit_fresh_state` pads a window's rows to, so a scan reuses
+     the kernels of the scan before it — all but the walk's six
+     delta-patch loops, whose bodies close over the probe columns and
+     compile per call; oracle: `fresh_walk`) and diffs cached verdict,
+     rule attribution and service selection.  Conntrack-committed (eternal-gen)
      entries legitimately outlive policy changes, so they are checked
      against the structural invariants instead (a committed or reply entry
      MUST cache ALLOW; a generation-tagged entry must NOT) — a verdict-bit

@@ -65,6 +65,18 @@ STAGE_SETTLE = "settle"
 STAGE_WATCHDOG = "watchdog"
 
 
+def pad_probes(probes: list, lanes: int) -> list:
+    """`probes` cycled up to `lanes` entries (unchanged when already that
+    long).  The fresh-walk probes run EAGERLY, and eager jax keeps one
+    compiled kernel per (op, shape): a probe batch with a lane count of
+    its own recompiles every kernel of the walk.  Callers pad to a
+    canonical lane count with REAL probes (a cycled lane classifies like
+    its original, so no padding lane needs masking) and read back only
+    the first len(probes) lanes."""
+    n = len(probes)
+    return probes + [probes[i % n] for i in range(lanes - n)]
+
+
 class BundleQuarantinedError(RuntimeError):
     """An incremental delta was rejected because the datapath is degraded
     (serving the last-known-good bundle after a rollback): only a
@@ -396,12 +408,10 @@ class CommitPlane:
             ]
         n_real = len(pkts)
         if pkts:
-            # Pad to a FIXED lane count by cycling the real probes: every
-            # canary round then shares per-table-shape kernels (eager jax
-            # caches compiled kernels per op shape — a scoped delta canary
-            # with its own batch size would recompile them all).  Only the
+            # A FIXED lane count: every canary round (a scoped delta
+            # canary included) shares per-table-shape kernels.  Only the
             # real lanes are diffed.
-            pkts.extend(pkts[i % n_real] for i in range(self.probes - n_real))
+            pkts = pad_probes(pkts, self.probes)
             got = np.asarray(o._canary_classify(
                 PacketBatch.from_packets(pkts),
                 # Fresh probe clock, disjoint from any plausible packet

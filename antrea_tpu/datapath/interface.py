@@ -457,8 +457,8 @@ class Datapath(ABC):
                        miss_source_rate=None,
                        miss_source_burst=None) -> None:
         """Constructor hook: validate + build the engine (async mode is
-        v4-only for now, like profile() probes — the queue columns are
-        narrow).  autotune_drain replaces the fixed drain_batch with the
+        v4-only for now — the queue columns are narrow).  autotune_drain
+        replaces the fixed drain_batch with the
         queue-pressure hysteresis controller (drain_batch seeds the
         starting rung); overlap_commits enables the two-slot deferred
         drain-commit staging (the double-buffered churn datapath);
@@ -578,20 +578,6 @@ class Datapath(ABC):
         """Engine/queue/epoch counters for the metrics plane (None when
         synchronous)."""
         return None if self._slowpath is None else self._slowpath.stats()
-
-    def profile(self, batch: PacketBatch, fresh: Optional[PacketBatch] = None,
-                **kw) -> dict:
-        """Phase-timed churn-loop breakdown (the profiling plane; see
-        models/profile.py): run `batch` as the established hot set with a
-        rolling fresh-flow window drawn from `fresh`, and return
-        {"phases_s": {phase: seconds}, "total_s", "pps", ...}.  Phase
-        names are implementation-defined (the tpuflow kernel reports the
-        six-phase device chain; the oracle a coarse host-timed split).
-        Observable state is left untouched — profiling steps run on a
-        scratch copy."""
-        raise NotImplementedError(
-            f"{type(self).__name__} does not implement profile()"
-        )
 
 
 @dataclass

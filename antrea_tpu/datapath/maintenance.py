@@ -44,9 +44,7 @@ tests test_cache_audit.py used to enumerate by hand):
 Observability: `maintenance_stats()` (scraped as
 antrea_tpu_maintenance_ticks_total through
 antrea_tpu_maintenance_scheduler_lag), the agent API's GET /maintenance
-route, `antctl maintenance`, and the profiler's maintenance mode
-(models/profile.MAINT_PHASE_CHAIN, `profile(mode="maintenance")`,
-`bench_profile.py --mode maintenance`).
+route and `antctl maintenance`.
 """
 
 from __future__ import annotations

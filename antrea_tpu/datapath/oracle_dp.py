@@ -10,7 +10,6 @@ deltas, used to diff verdicts against tpuflow.
 
 from __future__ import annotations
 
-import copy
 import time
 from typing import Optional
 
@@ -158,15 +157,13 @@ class OracleDatapath(TenantedDatapath, MaintainableDatapath,
         # differential harness constructs both engines from one kwarg set.
         if fused and dual_stack and prune_budget > 0:
             raise ConfigError(
-                "the one-kernel fast path (fused=True with prune_budget "
-                "> 0) is v4-only; dual-stack instances use the staged "
-                "kernel (drop fused or prune_budget, or dual_stack)")
+                "fused=True with prune_budget > 0 is v4-only (drop fused "
+                "or prune_budget, or dual_stack)")
         if autotune_prune:
             from ..ops.match import PruneAutotuner
 
             prune_budget = PruneAutotuner(prune_budget).budget
         self._prune_budget = int(prune_budget)
-        self._fused = bool(fused)
         self._gates = feature_gates or DEFAULT_GATES
         self._dual_stack = dual_stack
         self._node_ips = list(node_ips or [])
@@ -775,193 +772,6 @@ class OracleDatapath(TenantedDatapath, MaintainableDatapath,
         slot = live[0]
         o.flow[slot]["code"] ^= 1
         return f"flipped cached verdict bit of slot {slot}"
-
-    def profile(self, batch: PacketBatch, fresh: Optional[PacketBatch] = None,
-                *, now: int = 1000, mode: str = "sync", **_kw) -> dict:
-        """Coarse host-timed phase split (the scalar twin of the kernel's
-        six-phase device chain, TpuflowDatapath.profile): fast_path =
-        cache lookup of every lane, classify = the fresh ServiceLB+
-        classifier walk of the lanes that MISS (mirroring what step()
-        actually pays — a warmed hot set classifies nothing), and
-        commit_residual = full step minus both (the commit bookkeeping +
-        output assembly).  State and counters are snapshotted and
-        restored — profiling is observable-state-neutral.
-
-        mode="async" reports the decoupled-regime names (async_fast_path /
-        drain_classify / drain_commit_residual) over the same coarse
-        split — on the scalar engine the fast-lookup and miss-walk costs
-        ARE the fast-step and drain costs.  mode="overlap" reports the
-        overlapped-regime names over the identical split: the scalar
-        engine is host-sequential, so its overlap numbers ARE its async
-        numbers — the honest statement that there is nothing to overlap
-        here, kept mode-for-mode so harnesses can call either twin.
-        mode="maintenance" additionally times one fused maintenance pass
-        (_epoch_maintain, the cache-maintain task of the unified
-        scheduler) as `maint_sweep` / `maintenance_s` — the scalar twin
-        of MAINT_PHASE_CHAIN's rider.  mode="prune" reports the
-        prune-regime names over the identical split: the scalar walk has
-        no aggregate layer (its per-packet AND is already O(matched
-        rules)), so its candidate-gather number IS its classify number —
-        the honest twin statement, kept mode-for-mode."""
-        if mode not in ("sync", "async", "overlap", "maintenance", "prune",
-                        "fused", "telemetry"):
-            raise ValueError(f"unknown profile mode {mode!r}")
-        if mode == "prune" and self._prune_budget <= 0:
-            # Twin-parity with TpuflowDatapath.profile: both engines
-            # refuse the mode on an unpruned instance.
-            raise ValueError(
-                "profile(mode='prune') needs prune_budget > 0 "
-                "(the two-level kernel is compiled out at 0)")
-        if mode == "prune" and self._fused and self._prune_budget > 0:
-            # Twin-parity: a one-pass-capable instance serves the fused
-            # kernel — staged-prune labels would misattribute it.
-            raise ValueError(
-                "profile(mode='prune') attributes the STAGED pruned "
-                "kernel, but this instance serves the one-pass fast "
-                "path — use mode='fused' (or construct with "
-                "fused=False) for an honest attribution")
-        if mode == "fused" and not (self._fused and self._prune_budget > 0):
-            # Twin-parity: both engines refuse the mode unless the
-            # instance is one-pass-capable (fused + pruned).
-            raise ValueError(
-                "profile(mode='fused') needs the one-kernel fast path "
-                "(construct with fused=True and prune_budget > 0)")
-        from ..models.pipeline import GEN_ETERNAL
-
-        o = self._oracle
-        gen_w = self._gen % GEN_ETERNAL
-        if mode == "telemetry":
-            # Telemetry-counter structure check — the scalar twin of
-            # TpuflowDatapath.profile(mode="telemetry"): read-only cache
-            # lookups of the probe batch split into the same
-            # TELEMETRY_COUNTERS keys (probe_stale / chance_bumps /
-            # dma_hb stay 0: no generation-stale split, no replacement
-            # counter, no DMA on the scalar walk).  State untouched.
-            n_hit = 0
-            for i in range(batch.size):
-                p = batch.packet(i)
-                _slot, e = o.lookup(o.flow, p, o._flow_hash(p), now, gen_w)
-                if e is not None:
-                    n_hit += 1
-            return {
-                "mode": "telemetry",
-                "batch": batch.size,
-                "counters": {
-                    "probe_hit": n_hit,
-                    "probe_stale": 0,
-                    "probe_miss": batch.size - n_hit,
-                    "chance_bumps": 0,
-                    "dma_hb": 0,
-                },
-            }
-        probes = [batch] + ([fresh] if fresh is not None else [])
-        packets = [b.packet(i) for b in probes for i in range(b.size)]
-        misses = []
-        t0 = time.perf_counter()
-        for p in packets:
-            h = o._flow_hash(p)
-            _slot, e = o.lookup(o.flow, p, h, now, gen_w)
-            if e is None:
-                misses.append(p)
-        t_fast = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        for p in misses:
-            o.fresh_walk(o.aff, p, o._flow_hash(p), now)
-        t_cls = time.perf_counter() - t0
-        snap = (copy.deepcopy(o.flow), copy.deepcopy(o.aff), o.evictions,
-                dict(self._stats_in), dict(self._stats_out),
-                dict(self._bytes_in), dict(self._bytes_out),
-                self._default_allow, self._default_deny)
-        hist_snap = (list(self.step_hist._counts), self.step_hist.sum,
-                     self.step_hist.count)
-        muts0 = self._state_mutations
-        t_maint = 0.0
-        try:
-            t0 = time.perf_counter()
-            for b in probes:
-                self.step(b, now)
-            total = time.perf_counter() - t0
-            if mode == "maintenance":
-                # The maintenance rider, inside the snapshot/restore
-                # bracket like the steps: state-neutral to the caller.
-                t0 = time.perf_counter()
-                self._epoch_maintain(now)
-                t_maint = time.perf_counter() - t0
-                total += t_maint
-        finally:
-            (o.flow, o.aff, o.evictions, si, so, bi, bo,
-             self._default_allow, self._default_deny) = (
-                snap[0], snap[1], snap[2], snap[3], snap[4], snap[5],
-                snap[6], snap[7], snap[8])
-            self._stats_in = Counter(si)
-            self._stats_out = Counter(so)
-            self._bytes_in = Counter(bi)
-            self._bytes_out = Counter(bo)
-            (self.step_hist._counts, self.step_hist.sum,
-             self.step_hist.count) = hist_snap
-            self._state_mutations = muts0
-        n = len(packets)
-        if mode == "async":
-            phases = {
-                "async_fast_path": t_fast,
-                "drain_classify": t_cls,
-                "drain_commit_residual": max(total - t_fast - t_cls, 0.0),
-            }
-        elif mode == "overlap":
-            phases = {
-                "overlap_fast_path": t_fast,
-                "overlap_classify": t_cls,
-                "overlap_commit_residual": max(total - t_fast - t_cls, 0.0),
-            }
-        elif mode == "maintenance":
-            phases = {
-                "maint_fast_path": t_fast,
-                "maint_classify": t_cls,
-                "maint_commit_residual": max(
-                    total - t_fast - t_cls - t_maint, 0.0),
-                "maint_sweep": t_maint,
-            }
-        elif mode == "prune":
-            phases = {
-                "prune_fast_path": t_fast,
-                "prune_candidate_gather": t_cls,
-                "prune_commit_residual": max(total - t_fast - t_cls, 0.0),
-            }
-        elif mode == "fused":
-            # The scalar walk has no kernel to fuse: its classify time IS
-            # its one-pass time — the honest twin statement, mode-for-mode.
-            phases = {
-                "fused_fast_path": t_fast,
-                "fused_onepass": t_cls,
-                "fused_commit_residual": max(total - t_fast - t_cls, 0.0),
-            }
-        else:
-            phases = {
-                "fast_path": t_fast,
-                "classify": t_cls,
-                "commit_residual": max(total - t_fast - t_cls, 0.0),
-            }
-        out = {
-            "batch": n,
-            "fresh_per_step": 0 if fresh is None else fresh.size,
-            "misses": len(misses),
-            "phases_s": phases,
-            "total_s": total,
-            "pps": n / max(total, 1e-9),
-            "phase_fractions": {k: v / max(total, 1e-9)
-                                for k, v in phases.items()},
-        }
-        if mode == "maintenance":
-            out["mode"] = "maintenance"
-            out["maintenance_s"] = t_maint
-            out["maintenance_fraction"] = t_maint / max(total, 1e-9)
-        elif mode == "prune":
-            out["mode"] = "prune"
-            out["prune_budget"] = self._prune_budget
-        elif mode == "fused":
-            out["mode"] = "fused"
-            out["prune_budget"] = self._prune_budget
-        return out
 
     def trace(self, batch: PacketBatch, now: int) -> list[dict]:
         """Read-only per-packet trace, same semantics as TpuflowDatapath:

@@ -58,7 +58,7 @@ from ..utils import ip as iputil
 from ..config import ConfigError
 from . import persist
 from .audit import AuditableDatapath
-from .commit import TransactionalDatapath
+from .commit import TransactionalDatapath, pad_probes
 from .interface import Datapath, DatapathStats, DatapathType, StepResult
 from .maintenance import MaintainableDatapath
 from .slowpath import ADMIT_HOLD
@@ -178,25 +178,20 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
                 "autotune_prune retunes the aggregate-prune K budget, but "
                 "prune_budget=0 disables the aggregate layer — set an "
                 "initial prune_budget (e.g. 4) to autotune from")
-        # One-kernel fast path (round 8): fused=True over an aggregate-
-        # pruned (prune_budget > 0) v4 world upgrades the slow path to
-        # the one-pass pallas kernel (models/pipeline meta.onepass).
-        # fused without the aggregate layer keeps the staged consumer
-        # fusion — the kernel's prune stage IS the aggregate layer, so
-        # there is nothing to fuse it with; fused + dual_stack + pruning
-        # is rejected outright (the one-pass kernel is v4-only, like the
-        # async slow path), rather than silently downgrading.
+        # fused=True over an aggregate-pruned (prune_budget > 0) world
+        # feeds the pruned classify's candidate matrices to the Pallas
+        # consumer (ops/match._pruned_consumer_call).  That pair has only
+        # ever been held to the oracle on v4 worlds, so with dual_stack
+        # it is rejected outright rather than served unproven.
         if fused and dual_stack and prune_budget > 0:
             raise ConfigError(
-                "the one-kernel fast path (fused=True with prune_budget "
-                "> 0) is v4-only; dual-stack instances use the staged "
-                "kernel (drop fused or prune_budget, or dual_stack)")
+                "fused=True with prune_budget > 0 is v4-only (drop fused "
+                "or prune_budget, or dual_stack)")
         self._prune_tuner = None
         if autotune_prune:
             self._prune_tuner = PruneAutotuner(prune_budget)
             prune_budget = self._prune_tuner.budget  # snap to the ladder
         self._prune_budget = int(prune_budget)
-        self._fused = bool(fused)
         self._prune_skips = 0
         self._prune_fallbacks = 0
         self._prune_classified = 0
@@ -241,11 +236,10 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             ct_other_new_s=ct_other_new_s,
             ct_other_est_s=ct_other_est_s,
             # Cache misses classify through the fused pallas consumer
-            # (ops/match cold-path study) — the production switch for the
-            # path bench.py measures; off by default so CPU-bound suites
-            # avoid interpret-mode pallas.  With prune_budget > 0 this
-            # upgrades to the one-kernel fast path (round 8; the combo
-            # check above already rejected dual_stack).
+            # (ops/match cold-path study); off by default so CPU-bound
+            # suites avoid interpret-mode pallas.  With prune_budget > 0
+            # it consumes the pruned classify's candidate matrices (the
+            # combo check above already rejected dual_stack).
             fused=fused,
             # Thrash-resistant replacement (the 2-bit second-chance
             # counter, models/pipeline CHANCE_SHIFT); off by default so
@@ -1339,8 +1333,8 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
     def _audit_fresh(self, rows: list, now: int) -> list[dict]:
         """Fresh-walk re-proof of audited entries through the CURRENT
         compiled tables — the canary's EAGER `_pipeline_trace` machinery
-        (audit batch shapes vary per scan, so a jitted probe would pay an
-        XLA compile per scan); state untouched."""
+        (rule-table shapes change per bundle, so a jitted probe would pay
+        an XLA compile per install); state untouched."""
         return self._audit_fresh_state(self._state, rows, now)
 
     def _audit_dsvc(self):
@@ -1353,11 +1347,22 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
     def _audit_fresh_state(self, state: pl.PipelineState, rows: list,
                            now: int) -> list[dict]:
         """_audit_fresh over an explicit state pytree (the mesh engine
-        re-proves each row against its home replica's local slice)."""
+        re-proves each row against its home replica's local slice).
+
+        This function owns the probe SHAPE: the row count varies per scan
+        (the denials of a window, split per replica on a mesh), so the
+        rows are cycled up to a power-of-two lane count, at least the
+        audit window — ONE shape for every cursor scan, a pow2 rung above
+        it for a full sweep or a reshard certification — whose kernels the
+        eager walk compiles once (commit.pad_probes); only the real lanes
+        are returned."""
+        if not rows:
+            return []
         pkts = [Packet(src_ip=r["src"], dst_ip=r["dst"], proto=r["proto"],
                        src_port=r["sport"], dst_port=r["dport"])
                 for r in rows]
-        batch = PacketBatch.from_packets(pkts)
+        lanes = 1 << (max(len(pkts), self._audit.window) - 1).bit_length()
+        batch = PacketBatch.from_packets(pad_probes(pkts, lanes))
         o = pl._pipeline_trace(
             state,
             self._drs,
@@ -1451,126 +1456,6 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         self._state = self._state._replace(flow=self._state.flow._replace(
             meta=m.at[slot, M1C].set(m[slot, M1C] ^ 1)))
         return f"flipped cached verdict bit of slot {slot}"
-
-    def profile(self, batch: PacketBatch, fresh: Optional[PacketBatch] = None,
-                *, n_new: Optional[int] = None, now: int = 1000,
-                k_small: int = 2, k_big: int = 8, repeats: int = 2,
-                mode: str = "sync") -> dict:
-        """On-device churn-loop phase breakdown (models/profile.py):
-        `batch` is warmed as the established hot set; each timed step
-        replaces its first n_new lanes with a rolling window of fresh
-        flows from `fresh` (None -> never-miss regime).  The datapath's
-        own state is untouched — the profiler steps a scratch copy.
-
-        mode="async" profiles the DECOUPLED regime instead (the
-        datapath/slowpath cadence: fast dispatch + coalesced drain
-        dispatch per step) and attributes the drain phases
-        (profile.ASYNC_PHASE_CHAIN); mode="overlap" profiles the
-        double-buffered regime (drain of window i-1 overlapping the fast
-        step of window i, profile.OVERLAP_PHASE_CHAIN) — diffing the two
-        breakdowns attributes the overlap win phase by phase.  `fresh`
-        is required for both.  Any mode profiles on any instance — the
-        mode is a meta variant, not an engine dependency."""
-        from ..models import profile as prof
-
-        if batch.has_v6 or (fresh is not None and fresh.has_v6):
-            raise ValueError(
-                "profile() probes are v4-only; dual-stack instances "
-                "profile their v4 lanes (the wide fast path is shared)"
-            )
-        hot = prof._dev_cols(batch)
-        pool = prof._dev_cols(fresh) if fresh is not None else None
-        if mode == "telemetry":
-            # Telemetry-counter structure check (observability/
-            # telemetry.py): ONE instrumented step over the live state —
-            # the counters compiled in via a meta variant regardless of
-            # how the instance was built, and the step purely functional
-            # (no donation), so the served state, meters and histograms
-            # are untouched.  Returns the tel_* split of the probe batch
-            # keyed by TELEMETRY_COUNTERS name — the bench_profile
-            # --mode telemetry harness pins both twins' key sets.
-            _, out = pl._pipeline_step(
-                self._state, self._drs, self._dsvc, *hot,
-                jnp.int32(now), jnp.int32(self._gen),
-                meta=self._meta._replace(telemetry=True),
-            )
-            return {
-                "mode": "telemetry",
-                "batch": batch.size,
-                "counters": {k[4:]: int(np.asarray(v))
-                             for k, v in out.items()
-                             if k.startswith("tel_")},
-            }
-        if mode == "async":
-            return prof.profile_churn_async(
-                self._meta, self._state, self._drs, self._dsvc, hot, pool,
-                n_new=n_new, now0=now, gen=self._gen,
-                k_small=k_small, k_big=k_big, repeats=repeats,
-            )
-        if mode == "overlap":
-            return prof.profile_churn_overlap(
-                self._meta, self._state, self._drs, self._dsvc, hot, pool,
-                n_new=n_new, now0=now, gen=self._gen,
-                k_small=k_small, k_big=k_big, repeats=repeats,
-            )
-        if mode == "maintenance":
-            # The unified background plane's cadence (MAINT_PHASE_CHAIN):
-            # async churn with the scheduler's fused maintenance pass
-            # riding every step; `maintenance_s` is the plane's own
-            # attributed cost.
-            return prof.profile_churn_maintenance(
-                self._meta, self._state, self._drs, self._dsvc, hot, pool,
-                n_new=n_new, now0=now, gen=self._gen,
-                k_small=k_small, k_big=k_big, repeats=repeats,
-            )
-        if mode == "prune":
-            # Two-level prune attribution (PRUNE_PHASE_CHAIN): the async
-            # drain cadence with the classify entry split into
-            # summary-gather (PH_CLS_SUM) vs candidate-gather (PH_CLS) —
-            # requires a pruned instance, there is nothing to attribute
-            # otherwise.
-            if self._prune_budget <= 0:
-                raise ValueError(
-                    "profile(mode='prune') needs prune_budget > 0 "
-                    "(the two-level kernel is compiled out at 0)")
-            if self._meta.onepass:
-                # The chain's candidate-gather entry would silently
-                # measure the whole one-pass kernel (resolve + commit
-                # pack included) under staged-prune labels — the
-                # bench_profile --mode prune harness pins onepass=False
-                # for exactly this reason.
-                raise ValueError(
-                    "profile(mode='prune') attributes the STAGED pruned "
-                    "kernel, but this instance serves the one-pass fast "
-                    "path — use mode='fused' (or construct with "
-                    "fused=False) for an honest attribution")
-            return prof.profile_churn_prune(
-                self._meta, self._state, self._drs, self._dsvc, hot, pool,
-                n_new=n_new, now0=now, gen=self._gen,
-                k_small=k_small, k_big=k_big, repeats=repeats,
-            )
-        if mode == "fused":
-            # One-kernel regime attribution (FUSED_PHASE_CHAIN): the
-            # async drain cadence over the one-pass meta — requires a
-            # fused + pruned instance (there is no one-pass kernel to
-            # attribute otherwise).
-            if not (self._meta.onepass):
-                raise ValueError(
-                    "profile(mode='fused') needs the one-kernel fast "
-                    "path (construct with fused=True and prune_budget "
-                    "> 0)")
-            return prof.profile_churn_fused(
-                self._meta, self._state, self._drs, self._dsvc, hot, pool,
-                n_new=n_new, now0=now, gen=self._gen,
-                k_small=k_small, k_big=k_big, repeats=repeats,
-            )
-        if mode != "sync":
-            raise ValueError(f"unknown profile mode {mode!r}")
-        return prof.profile_churn(
-            self._meta, self._state, self._drs, self._dsvc, hot, pool,
-            n_new=n_new, now0=now, gen=self._gen,
-            k_small=k_small, k_big=k_big, repeats=repeats,
-        )
 
     def trace(self, batch: PacketBatch, now: int) -> list[dict]:
         """Traceflow analog: per-packet stage observations, state untouched.
@@ -1743,22 +1628,15 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             fused=self._pipe_kw["fused"],
             key_words=10 if self._dual_stack else 4,
             count_flow_stats=self._flow_stats,
-            # Round 8: the one-pass kernel engages when the consumer
-            # fusion AND the aggregate layer are both on (v4 layout
-            # guaranteed by the constructor combo check).
-            onepass=bool(self._pipe_kw["fused"]
-                         and match_meta.prune_budget > 0
-                         and not self._dual_stack),
             second_chance=bool(self._pipe_kw["second_chance"]),
             telemetry=bool(self._pipe_kw["telemetry"]),
         )
-        pl.require_onepass_lowers(self._meta, drs)
-        # Async-mode step/drain variants of the meta: the FAST step masks
-        # the whole slow path out (phases=0 — misses keep the admission
-        # policy's provisional image, models/pipeline miss_code) and the
-        # DRAIN step classifies one coalesced queue batch in a SINGLE
-        # slow-path round (miss_chunk == drain_batch), amortizing the
-        # per-round fixed costs the phase profiler exposed; drain_reclaim
+        # Async-mode step/drain variants of the meta: the FAST step
+        # compiles the whole slow path out (defer_misses — misses keep the
+        # admission policy's provisional image, models/pipeline miss_code)
+        # and the DRAIN step classifies one coalesced queue batch in a
+        # SINGLE slow-path round (miss_chunk == drain_batch), amortizing
+        # the per-round fixed costs; drain_reclaim
         # fuses the aging/revalidation of touched rows into its commit
         # pass (round 6).  With the autotuner on, drain chunks move on a
         # closed rung ladder — _drain_meta derives the per-rung meta on
@@ -1766,7 +1644,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         # one compiled drain variant per rung, never a recompile storm).
         if self._async:
             self._meta_step = self._meta._replace(
-                phases=0,
+                defer_misses=True,
                 miss_code=(ACT_DROP
                            if self._slowpath.admission == ADMIT_HOLD
                            else ACT_ALLOW),

@@ -63,7 +63,6 @@ from ..apis.controlplane import PROTO_TCP
 from ..compiler.compile import ACT_ALLOW, ACT_REJECT, CompiledPolicySet
 from ..compiler.services import ServiceTables
 from ..ops import hashing
-from ..ops import match as _m
 from ..ops.match import (PRUNE_HIST_BOUNDS, DeviceRuleSet, StaticMeta,
                          classify_batch, to_device, to_host)
 from ..ops.scopes import device_scope
@@ -132,34 +131,6 @@ def no_commit_mask(dst, proto, flags, xp=np):
         (xp.asarray(proto) == PROTO_TCP)
         & ((xp.asarray(flags) & _TEARDOWN_FLAGS) != 0)
     )
-
-# Slow-path phase bits (PipelineMeta.phases): a PROFILING surface, not a
-# correctness knob — masking a phase substitutes cheap defaults so the
-# on-device cost of each churn-loop section can be isolated by telescoped
-# differencing (models/profile.py; round-5 verdict weak #1: the churn
-# regime was never profiled).  Production datapaths always run PH_ALL.
-#   PH_SLOW    miss-detect scaffolding: index compaction, the chunked
-#              round loop, output scatters (the lax.cond body itself)
-#   PH_LB      ServiceLB frontend lookup + affinity + endpoint choice
-#   PH_CLS     the conjunctive-match classifier on the post-DNAT tuple
-#   PH_CLS_SUM the classifier's AGGREGATE phase alone (round-7 two-level
-#              pruning, ops/match summary_only): summary gathers + AND +
-#              short-circuit defaults, no candidate gather and no
-#              fallback.  Only meaningful under PH_CLS's absence and a
-#              prune_budget > 0 meta (a no-op bit otherwise) — the
-#              profiler entry that splits summary-gather from
-#              candidate-gather cost.
-#   PH_COMMIT  flow-cache insert prep + both-direction scatters + learn
-#   PH_EVICT   eviction accounting (requires PH_COMMIT: it audits the
-#              insert targets)
-PH_SLOW = 1
-PH_LB = 2
-PH_CLS = 4
-PH_COMMIT = 8
-PH_EVICT = 16
-PH_CLS_SUM = 32
-PH_ALL = PH_SLOW | PH_LB | PH_CLS | PH_COMMIT | PH_EVICT | PH_CLS_SUM
-
 
 def _prune_bucket_counts(cand: jax.Array, mask: jax.Array) -> jax.Array:
     """Per-lane candidate-superblock counts -> per-bucket counts PLUS a
@@ -313,15 +284,14 @@ class PipelineMeta(NamedTuple):
     # word form, the xxreg3 analog).  Static, so pure-v4 worlds compile the
     # narrow fast path unchanged.
     key_words: int = 4
-    # Slow-path phase mask (PH_* bits).  Two legitimate uses: the profiler
-    # compiles cumulative chains of it (models/profile), and the ASYNC
-    # slow-path engine (datapath/slowpath) runs its fast step at phases=0 —
-    # misses then keep the fast-path default image, get admitted to the
-    # miss queue, and are classified later by a coalesced drain step at
-    # PH_ALL.  Synchronous production datapaths always run PH_ALL.
-    phases: int = PH_ALL
+    # The ASYNC engine's fast step (datapath/slowpath): the slow path is
+    # compiled out — misses keep the fast-path default image (miss_code),
+    # are admitted to the miss queue, and are classified later by a
+    # coalesced drain step, which like every synchronous step runs the
+    # slow path.  Set by the engine for that one program, not an option.
+    defer_misses: bool = False
     # Fast-path default verdict for UNclassified miss lanes (only
-    # observable when PH_SLOW is masked, i.e. in the async fast step):
+    # observable under defer_misses, i.e. in the async fast step):
     # the miss-queue admission policy — ACT_ALLOW = provisional
     # default-forward (the OVS "normal" upcall treatment), ACT_DROP =
     # hold until the background engine classifies (datapath/slowpath).
@@ -338,20 +308,13 @@ class PipelineMeta(NamedTuple):
     # maintain_scan run only on epoch-stale heal.  Off (False) for
     # synchronous steps so their compiled program is unchanged.
     drain_reclaim: bool = False
-    # One-kernel fast path (round 8): the slow path runs as ONE pallas
-    # pass over the full batch (probe decode + aggregate prune +
-    # candidate DMA + first-match + resolve + commit-row packing in
-    # VMEM) instead of the chunked round loop — requires the aggregate
-    # layer (match.prune_budget > 0) and the narrow (v4) key layout.
-    # False keeps the staged program bit-identical.
-    onepass: bool = False
     # Thrash-resistant replacement (the 2-bit second-chance counter, see
     # CHANCE_SHIFT above).  False keeps the compiled step bit-identical.
     second_chance: bool = False
     # Hot-path telemetry (observability/telemetry.py): the step emits
     # cheap in-kernel counter outputs — cache probe hit/stale/miss
-    # splits, DMA half-blocks issued by the one-pass kernel, and
-    # second-chance protection bumps — as tel_* keys in the output dict.
+    # splits and second-chance protection bumps (tel_dma_hb is carried
+    # but always 0: its kernel is gone) — as tel_* keys in the output dict.
     # Everything is derived XLA-side from values the step already
     # gathers (kr0/ts0 from _cache_lookup, the guard's protected mask),
     # so False compiles the whole plane out: no extra gathers, no extra
@@ -375,55 +338,6 @@ class PipelineMeta(NamedTuple):
             self.ct_other_new_s if self.ct_other_new_s is not None else t,
             self.ct_other_est_s if self.ct_other_est_s is not None else t,
         )
-
-
-def require_onepass_lowers(meta: PipelineMeta, drs: DeviceRuleSet) -> None:
-    """Construction-time check that the one-pass kernel `meta` selects
-    compiles where `drs` lives: one tile of the real kernel (this world's
-    aggregate widths, phases and K) goes through the TPU compiler, and a
-    refusal becomes a typed ConfigError quoting it — instead of a raw
-    compiler exception out of the first step().  Off-TPU the interpreter
-    runs the kernel, so there is nothing to check."""
-    mm = meta.match
-    if not meta.onepass or _m.pallas_interpret(mm):
-        return
-    from ..config import ConfigError
-
-    ing, eg = drs.ingress, drs.egress
-    b, blk = _m._FUSE_TB, _m.AGG_BLOCK
-    s_in, s_out = ing.at.agg.shape[1], eg.at.agg.shape[1]
-    call = _m._onepass_call(
-        b, s_in, s_out, mm.prune_budget, mm.prune_budget, mm.in_phases,
-        mm.out_phases, mm.svcref, True, meta.timeouts, meta.flow_slots,
-        meta.pref_mask, False)
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-    def u32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.uint32)
-
-    tabs = [ing.at, ing.peer, ing.svc, eg.at, eg.peer, eg.svc]
-    if mm.svcref:
-        tabs.append(eg.svc)
-    # Operand order of slow_onepass's call below.
-    args = ([i32(b, 8), i32(b, 4), i32(b, 4), i32(b, 4), i32(b, 4), i32(b, 8)]
-            + [u32(b, s_in)] * 3 + [u32(b, s_out)] * 3
-            + [i32(b, 8), i32(1, 4)]
-            + [u32(t.inc.size // blk, blk) for t in tabs]
-            + [i32(*ing.action.shape), i32(*eg.action.shape)])
-    try:
-        jax.jit(call).lower(*args).compile()
-    except Exception as e:  # noqa: BLE001 — the compiler's refusals are
-        # NotImplementedError (Pallas lowering), jax-private Mosaic types
-        # or JaxRuntimeError (XLA); all mean the same thing here.
-        raise ConfigError(
-            f"fused=True with prune_budget={mm.prune_budget} selects the "
-            f"one-pass kernel, which does not lower on "
-            f"{jax.devices()[0].device_kind}: {type(e).__name__}: "
-            f"{' '.join(str(e).split())[:300]} — use fused=True alone "
-            f"(staged consumer) or prune_budget alone (pruned consumer)"
-        ) from e
 
 
 def svc_to_host(st: ServiceTables) -> DeviceServiceTables:
@@ -562,8 +476,8 @@ def _second_chance_guard(flow: FlowCache, slot2, keys2, ins2, now, meta, A,
     round while the scalar twin bumps once per step — colliding
     challengers in a later round may then evict an entry the oracle
     keeps.  The evicted flow re-misses and re-classifies to the same
-    verdict (the PR 6 lost-update discipline); the one-pass kernel and
-    single-round passes match the oracle exactly.
+    verdict (the PR 6 lost-update discipline); single-round passes match
+    the oracle exactly.
 
     -> (flow', ins2', n_protected) — n_protected is the lane count the
     guard suppressed this pass (the telemetry `chance_bumps` counter),
@@ -626,42 +540,6 @@ def _unpack_rules(rp):
     return (rp & 0xFFFF) - 1, ((rp >> 16) & 0xFFFF) - 1
 
 
-def _fused_pack_rows(src_f, dst_f, proto, sport, dport, pp, f_code, svc_idx,
-                     dnat_ip, dnat_port, snat_m, dsr_m, f_ri, f_ro,
-                     miss_m, nc_m, now, gen_w, n_slots, pmask):
-    """XLA twin of the one-pass kernel's commit-row packing (round 8):
-    the same _pack_meta1/_pack_rules/flow-hash formulas, producing the
-    interleave-ready forward + reply rows for a set of lanes.  Used by
-    the rule-sharded one-pass (rows pack post-pmin) and the fallback-
-    lane override; the in-kernel pack mirrors it field for field
-    (parity-pinned by tests/test_match_fused.py).  -> dict(committed,
-    ins, rev_ins, rev_slot, keys8, meta8)."""
-    committed = miss_m & (f_code == ACT_ALLOW) & ~nc_m
-    ins = miss_m & ~nc_m
-    rev_ins = ins & committed & (dsr_m == 0)
-    egen = jnp.where(committed, GEN_ETERNAL, gen_w)
-    pg_ins = proto | 0x100 | (egen << 9)
-    m1 = _pack_meta1(f_code, svc_idx, dnat_port)
-    rules_p = _pack_rules(f_ri, f_ro)
-    pref_col = jnp.zeros_like(proto) + (now & pmask)
-    zcol = (pref_col
-            | jnp.where(snat_m > 0, REPLY_BIT, 0)
-            | jnp.where(dsr_m > 0, DSR_BIT, 0))
-    rev_h = hashing.flow_hash(_raw_bits(dnat_ip), _raw_bits(src_f), proto,
-                              dnat_port, sport, xp=jnp)
-    rev_slot = (rev_h & jnp.uint32(n_slots - 1)).astype(jnp.int32)
-    rev_pg = proto | 0x100 | (GEN_ETERNAL << 9) | REPLY_BIT
-    keys8 = jnp.stack(
-        [src_f, dst_f, pp, pg_ins,
-         dnat_ip, src_f, (dnat_port << 16) | sport, rev_pg], axis=1)
-    meta8 = jnp.stack(
-        [dnat_ip, m1, rules_p, zcol,
-         dst_f, _pack_meta1(f_code, svc_idx, dport), rules_p, pref_col],
-        axis=1)
-    return dict(committed=committed, ins=ins, rev_ins=rev_ins,
-                rev_slot=rev_slot, keys8=keys8, meta8=meta8)
-
-
 class PolicyCapacityError(ValueError):
     """A compiled policy set exceeds a hard datapath capacity bound (e.g.
     the 16-bit packed rule-attribution space).  DETERMINISTIC: the same
@@ -703,7 +581,6 @@ def make_pipeline(
     count_flow_stats: bool = False,
     prune_budget: int = 0,
     second_chance: bool = False,
-    onepass: Optional[bool] = None,
     telemetry: bool = False,
 ):
     """-> (step fn, initial PipelineState, (DeviceRuleSet, DeviceServiceTables)).
@@ -738,13 +615,6 @@ def make_pipeline(
         fused=fused,
         key_words=10 if dual_stack else 4,
         count_flow_stats=count_flow_stats,
-        # fused=True over an aggregate-pruned v4 world upgrades to the
-        # one-kernel fast path (round 8); fused without the aggregate
-        # layer (or with wide keys) keeps the staged consumer fusion.
-        # An explicit onepass=False pins the staged kernel (the
-        # bench_profile --mode prune regime); onepass=True demands it.
-        onepass=(bool(fused and prune_budget > 0 and not dual_stack)
-                 if onepass is None else bool(onepass)),
         second_chance=second_chance,
         telemetry=telemetry,
     )
@@ -928,10 +798,8 @@ def _cache_lookup(flow, slot, addr, pp, pg_cur, pg_est, now, proto, meta):
     key rows are [addr..., pp, pg].
 
     -> (hit, est, rpl, meta_row (B,4), key_row, ts_col) where meta_row/
-    key_row/ts_col are the gathered cache rows (the one-pass kernel
-    re-derives the probe from the SAME gathered rows, so the two probe
-    decodes cannot diverge).  rpl flags reply-direction (reverse-tuple)
-    hits: their meta row carries the un-DNAT rewrite (original service
+    key_row/ts_col are the gathered cache rows.  rpl flags
+    reply-direction (reverse-tuple) hits: their meta row carries the un-DNAT rewrite (original service
     frontend ip/port) instead of a DNAT resolution.
 
     Freshness is per-state (entry_timeout): half-open TCP and non-TCP
@@ -988,10 +856,6 @@ def _pipeline_step(
     M = meta.miss_chunk
     dump = N
     A = meta.key_words - 2  # address columns: 2 (v4) / 8 (dual-stack wide)
-    if meta.onepass and (A != 2 or meta.match.prune_budget <= 0):
-        raise ValueError(
-            "the one-kernel fast path (onepass) requires the narrow v4 "
-            "key layout and an aggregate-pruned meta (prune_budget > 0)")
 
     with device_scope("fast_path"), device_scope("probe"):
         src_raw = _raw_bits(src_f)
@@ -1255,7 +1119,7 @@ def _pipeline_step(
         # ADMITTED miss lanes default to meta.miss_code: ACT_ALLOW in
         # synchronous mode (overwritten by the slow path anyway), the
         # admission policy's provisional verdict in the async fast step
-        # (PH_SLOW masked, misses queued for the background engine —
+        # (defer_misses: misses queued for the background engine —
         # datapath/slowpath).  Valid-masked lanes (SpoofGuard/ARP/IGMP-punt,
         # handled BEFORE the pipeline) are NOT misses and keep the plain
         # ALLOW image their kind overrides expect (forwarding.py) — a hold
@@ -1360,73 +1224,35 @@ def _pipeline_step(
                 is6_m = None
                 wide_m = None
 
-            if meta.phases & PH_LB:
-                (svc_idx, no_ep, dnat_ip, dnat_port, snat_m, dsr_m, dnat_w,
-                 learn) = _service_lb(
-                    aff_snap, dsvc, h_m, s_f, d_f, p_m, dp_m, now,
-                    meta.aff_slots, wide=wide_m,
-                )
-            else:
-                # Phase masked (profiling): no service resolution — lanes
-                # keep their literal destination, nothing learns.
-                svc_idx = jnp.full((M,), MISS, jnp.int32)
-                no_ep = jnp.zeros((M,), bool)
-                dnat_ip, dnat_port = d_f, dp_m
-                snat_m = dsr_m = jnp.zeros((M,), jnp.int32)
-                dnat_w = daddr_m if A == 8 else None
-                learn = {
-                    "mask": jnp.zeros((M,), bool),
-                    "aslot": jnp.zeros((M,), jnp.int32),
-                    "client": s_f if A == 2 else saddr_m,
-                    "svc": svc_idx,
-                    "ep": jnp.zeros((M,), jnp.int32),
-                }
+            (svc_idx, no_ep, dnat_ip, dnat_port, snat_m, dsr_m, dnat_w,
+             learn) = _service_lb(
+                aff_snap, dsvc, h_m, s_f, d_f, p_m, dp_m, now,
+                meta.aff_slots, wide=wide_m,
+            )
 
-            cls = None
-            if meta.phases & PH_CLS:
-                # Lanes classify on their POST-DNAT tuple (EndpointDNAT
-                # before the policy tables, ref pipeline.go table order);
-                # v6 lanes' post-DNAT words (dnat_w) double as the
-                # classifier's v6 lanes (same flipped-word layout the
-                # interval tables expect).
-                cls = classify_batch(
-                    drs, s_f, dnat_ip, p_m, dnat_port,
-                    meta=meta.match, hit_combine=hit_combine,
-                    # The fused consumer is shard-aware (global word
-                    # offsets from word_idx), so it composes with
-                    # hit_combine.
-                    fused=meta.fused,
-                    v6=None if wide_m is None else (saddr_m, dnat_w, is6_m),
-                    svc_ref=_svc_ref_of(svc_idx, dsvc),
-                )
-            elif prune_on and (meta.phases & PH_CLS_SUM):
-                # Summary-only classify (round-7 profiling surface): the
-                # aggregate gathers + AND + short-circuit defaults, no
-                # candidate gather, no fallback — PRUNE_PHASE_CHAIN's
-                # summary-gather vs candidate-gather split.
-                cls = classify_batch(
-                    drs, s_f, dnat_ip, p_m, dnat_port,
-                    meta=meta.match, hit_combine=hit_combine,
-                    fused=meta.fused,
-                    v6=None if wide_m is None else (saddr_m, dnat_w, is6_m),
-                    svc_ref=_svc_ref_of(svc_idx, dsvc),
-                    summary_only=True,
-                )
-            if cls is not None:
-                code = jnp.where(
-                    no_ep, ACT_REJECT, cls["code"]).astype(jnp.int32)
-                # SvcReject happens in EndpointDNAT, BEFORE the policy
-                # tables (ref pipeline.go table order): no rule
-                # attribution for it.
-                rule_in = jnp.where(no_ep, MISS, cls["ingress_rule"])
-                rule_out = jnp.where(no_ep, MISS, cls["egress_rule"])
-            else:
-                # Phase masked (profiling): every lane default-allows
-                # (SvcReject still applies — it is an LB decision).
-                code = jnp.where(no_ep, ACT_REJECT, ACT_ALLOW).astype(jnp.int32)
-                rule_in = jnp.full((M,), MISS, jnp.int32)
-                rule_out = jnp.full((M,), MISS, jnp.int32)
-            if prune_on and cls is not None:
+            # Lanes classify on their POST-DNAT tuple (EndpointDNAT
+            # before the policy tables, ref pipeline.go table order);
+            # v6 lanes' post-DNAT words (dnat_w) double as the
+            # classifier's v6 lanes (same flipped-word layout the
+            # interval tables expect).
+            cls = classify_batch(
+                drs, s_f, dnat_ip, p_m, dnat_port,
+                meta=meta.match, hit_combine=hit_combine,
+                # The fused consumer is shard-aware (global word
+                # offsets from word_idx), so it composes with
+                # hit_combine.
+                fused=meta.fused,
+                v6=None if wide_m is None else (saddr_m, dnat_w, is6_m),
+                svc_ref=_svc_ref_of(svc_idx, dsvc),
+            )
+            code = jnp.where(
+                no_ep, ACT_REJECT, cls["code"]).astype(jnp.int32)
+            # SvcReject happens in EndpointDNAT, BEFORE the policy
+            # tables (ref pipeline.go table order): no rule
+            # attribution for it.
+            rule_in = jnp.where(no_ep, MISS, cls["ingress_rule"])
+            rule_out = jnp.where(no_ep, MISS, cls["egress_rule"])
+            if prune_on:
                 # Prune observability (valid lanes only — padding lanes
                 # classify garbage tuples and must not meter).
                 # prune_exclude (round 8): lanes another dispatch owns
@@ -1471,9 +1297,6 @@ def _pipeline_step(
 
             # Insert into the flow cache: ALLOW entries as ETERNAL
             # (conntrack commit), denials tagged with the current gen.
-            # Phase-gated (PH_COMMIT; the eviction audit additionally
-            # requires PH_COMMIT since it reads the insert targets) so the
-            # profiler can isolate the commit scatters' cost.
             @device_scope("cache_commit")
             def do_commit(flow, aff, n_evict, n_reclaim, tel_sc):
                 egen = jnp.where(committed_m, GEN_ETERNAL, gen_w)
@@ -1572,43 +1395,42 @@ def _pipeline_step(
                     if tel_on:
                         tel_sc = tel_sc + sc_n
 
-                if meta.phases & PH_EVICT:
-                    with device_scope("eviction_scan"):
-                        # Eviction accounting (round-2 verdict weak #5:
-                        # quantify the direct-mapped collision cost): an
-                        # insert over a live entry whose TUPLE differs (cols
-                        # 0-2 + proto/direction bits of col 3 — a same-tuple
-                        # rewrite is an update, not an eviction).
-                        tgt2 = jnp.where(ins2, slot2, dump)
-                        okr = flow.keys[tgt2]
-                        id3 = 0xFF | REPLY_BIT
-                        tuple_differs = (
-                            (okr[:, : A + 1] != keys2[:, : A + 1]).any(axis=1)
-                            | ((okr[:, A + 1] & id3) != (keys2[:, A + 1] & id3))
+                with device_scope("eviction_scan"):
+                    # Eviction accounting (round-2 verdict weak #5:
+                    # quantify the direct-mapped collision cost): an
+                    # insert over a live entry whose TUPLE differs (cols
+                    # 0-2 + proto/direction bits of col 3 — a same-tuple
+                    # rewrite is an update, not an eviction).
+                    tgt2 = jnp.where(ins2, slot2, dump)
+                    okr = flow.keys[tgt2]
+                    id3 = 0xFF | REPLY_BIT
+                    tuple_differs = (
+                        (okr[:, : A + 1] != keys2[:, : A + 1]).any(axis=1)
+                        | ((okr[:, A + 1] & id3) != (keys2[:, A + 1] & id3))
+                    )
+                    overwrote = ins2 & (okr[:, A + 1] != 0) & tuple_differs
+                    if meta.drain_reclaim:
+                        # Fused maintenance (overlapped drain): a target
+                        # row that is DEAD to lookups — idle-expired per
+                        # its per-state timeout, or a stale-generation
+                        # denial — is reclaimed occupancy, not a live
+                        # eviction; the drain round ages/revalidates the
+                        # rows it touches in the pass that already
+                        # gathered them (the ts/conf reads ride the same
+                        # tgt2 the audit uses).
+                        om3 = flow.meta[tgt2, ZC]
+                        otmo = entry_timeout(
+                            (om3 >> 29) & 1, okr[:, A + 1] & 0xFF,
+                            meta.timeouts,
                         )
-                        overwrote = ins2 & (okr[:, A + 1] != 0) & tuple_differs
-                        if meta.drain_reclaim:
-                            # Fused maintenance (overlapped drain): a target
-                            # row that is DEAD to lookups — idle-expired per
-                            # its per-state timeout, or a stale-generation
-                            # denial — is reclaimed occupancy, not a live
-                            # eviction; the drain round ages/revalidates the
-                            # rows it touches in the pass that already
-                            # gathered them (the ts/conf reads ride the same
-                            # tgt2 the audit uses).
-                            om3 = flow.meta[tgt2, ZC]
-                            otmo = entry_timeout(
-                                (om3 >> 29) & 1, okr[:, A + 1] & 0xFF,
-                                meta.timeouts,
-                            )
-                            ogen = (okr[:, A + 1] >> 9) & GEN_ETERNAL
-                            dead = ((now - flow.ts[tgt2]) > otmo) | (
-                                (ogen != GEN_ETERNAL) & (ogen != gen_w)
-                            )
-                            n_reclaim = n_reclaim + (overwrote & dead).sum(
-                                dtype=jnp.int32)
-                            overwrote = overwrote & ~dead
-                        n_evict = n_evict + overwrote.sum(dtype=jnp.int32)
+                        ogen = (okr[:, A + 1] >> 9) & GEN_ETERNAL
+                        dead = ((now - flow.ts[tgt2]) > otmo) | (
+                            (ogen != GEN_ETERNAL) & (ogen != gen_w)
+                        )
+                        n_reclaim = n_reclaim + (overwrote & dead).sum(
+                            dtype=jnp.int32)
+                        overwrote = overwrote & ~dead
+                    n_evict = n_evict + overwrote.sum(dtype=jnp.int32)
 
                 if meta.count_flow_stats:
                     # Fresh entries start at this packet's contribution on
@@ -1661,10 +1483,8 @@ def _pipeline_step(
                 )
                 return flow, aff, n_evict, n_reclaim, tel_sc
 
-            if meta.phases & PH_COMMIT:
-                flow, aff, n_evict, n_reclaim, tel_sc = do_commit(
-                    flow, aff, n_evict, n_reclaim,
-                    tel_sc if tel_on else None)
+            flow, aff, n_evict, n_reclaim, tel_sc = do_commit(
+                flow, aff, n_evict, n_reclaim, tel_sc if tel_on else None)
             return (r + 1, n_evict, n_reclaim, flow, aff, out_code, out_svc,
                     out_dnat_ip, out_dnat_port, out_rule_in, out_rule_out,
                     out_committed, out_snat, out_dsr) + (
@@ -1691,438 +1511,6 @@ def _pipeline_step(
                            out_snat, out_dsr, n_evict, n_reclaim) + tuple(
                            carry[14:14 + n_extra])
 
-    def slow_onepass(args):
-        """Round-8 one-kernel slow path (meta.onepass): the whole miss
-        walk — probe decode, aggregate prune, candidate DMA, first
-        match, resolve, commit-row packing — runs as ONE pallas pass
-        over the full batch (ops/match._onepass_call) instead of the
-        chunked round loop; only the gathers feeding it, the fallback
-        redispatch and the commit scatters remain XLA (the study-note
-        walls: gather/scatter engines are XLA-only on this toolchain).
-        v4 + prune_budget > 0 only (make_pipeline gates)."""
-        flow, aff, outs = args
-        (out_code0, out_svc0, out_dnat0, out_dport0, out_ri0, out_ro0,
-         out_cmt0, out_snat0, out_dsr0, n_evict, n_reclaim) = outs[:11]
-        pr_sk0, pr_fb0, pr_hist0 = outs[11:14]
-        if tel_on:
-            tel_hb, tel_sc = outs[14:16]
-            if meta.phases & PH_CLS:
-                # DMA half-blocks the one-pass kernel issues for this
-                # dispatch: its main loop walks EVERY _OP_HB half-block
-                # of the padded batch unconditionally (the double-buffer
-                # schedule, ops/match round-8 study note), so the count
-                # is a physical constant of the batch shape — replicated
-                # -safe, and the denominator the candidate-hist numbers
-                # are read against.
-                tel_hb = tel_hb + jnp.int32(
-                    (B + (-B) % _m._FUSE_TB) // _m._OP_HB)
-        aff_snap = aff
-        validm = jnp.ones(B, bool) if valid is None else (valid != 0)
-        ncm = (jnp.zeros(B, bool) if no_commit is None
-               else (no_commit != 0))
-        z = jnp.zeros(B, jnp.int32)
-        BIGS = jnp.full((B,), _m.BIG, jnp.int32)
-
-        # ---- ServiceLB over the full batch (PH_LB) --------------------
-        if meta.phases & PH_LB:
-            (svc_idx, no_ep, dnat_ip, dnat_port, snat_m, dsr_m, _dw,
-             learn) = _service_lb(aff_snap, dsvc, h, src_f, dst_f, proto,
-                                  dport, now, meta.aff_slots)
-        else:
-            svc_idx = jnp.full((B,), MISS, jnp.int32)
-            no_ep = jnp.zeros((B,), bool)
-            dnat_ip, dnat_port = dst_f, dport
-            snat_m = dsr_m = z
-            learn = {"mask": jnp.zeros((B,), bool), "aslot": z,
-                     "client": src_f, "svc": svc_idx, "ep": z}
-
-        # ---- classification probes on the POST-DNAT tuple -------------
-        ing, eg = drs.ingress, drs.egress
-        svc_key = (proto << 16) | dnat_port
-        sref = _svc_ref_of(svc_idx, dsvc) if meta.match.svcref else None
-
-        def midx(tab, x):
-            # Miss-masked interval rows: hit/invalid lanes gather the hot
-            # row 0 (the steady-state volume guard) and spawn nothing.
-            return jnp.where(miss, _m._dim_index(tab, x, None, None), 0)
-
-        iv6 = (midx(ing.at, dnat_ip), midx(ing.peer, src_f),
-               midx(ing.svc, svc_key), midx(eg.at, src_f),
-               midx(eg.peer, dnat_ip), midx(eg.svc, svc_key))
-        iv_ref = (midx(eg.svc, _m._svcref_key(svc_key, sref))
-                  if meta.match.svcref else z)
-        iso_in = drs.iso_in.val[midx(drs.iso_in, dnat_ip)]
-        iso_out = drs.iso_out.val[midx(drs.iso_out, src_f)]
-
-        d = drs.ip_delta if meta.match.delta_slots > 0 else None
-        delta_fb = jnp.zeros(B, bool)
-        if d is not None:
-            iso_in = _m._patch_iso(iso_in, dnat_ip, d, 0)
-            iso_out = _m._patch_iso(iso_out, src_f, d, 1)
-
-        aggs = [ing.at.agg[iv6[0]], ing.peer.agg[iv6[1]],
-                ing.svc.agg[iv6[2]], eg.at.agg[iv6[3]],
-                eg.peer.agg[iv6[4]], eg.svc.agg[iv6[5]]]
-        if meta.match.svcref:
-            aggs[5] = aggs[5] | eg.svc.agg[iv_ref]
-        if d is not None:
-            aggs[0] = _m._patch_agg(aggs[0], dnat_ip, d, d.at_in)
-            aggs[1] = _m._patch_agg(aggs[1], src_f, d, d.peer_in)
-            aggs[3] = _m._patch_agg(aggs[3], src_f, d, d.at_out)
-            aggs[4] = _m._patch_agg(aggs[4], dnat_ip, d, d.peer_out)
-
-            # Delta-affected lanes force the full-width fallback: SET
-            # slots are conservative in the aggregate (patched above),
-            # but CLEAR slots only resolve at full precision — the
-            # candidate words the kernel DMAs are unpatched, so a lane a
-            # pending delta touches must never trust them (exactness
-            # before speed; deltas are the rare between-recompiles case).
-            def dfb(i, acc):
-                return (acc | _m._delta_lane_match(src_f, d, i, None)
-                        | _m._delta_lane_match(dnat_ip, d, i, None))
-
-            delta_fb = jax.lax.fori_loop(0, d.n, dfb, delta_fb)
-
-        K = meta.match.prune_budget
-        sharded = hit_combine is not None
-        resolve = not sharded
-        interp = _m.pallas_interpret(meta.match)
-        s_in = aggs[0].shape[1]
-        s_out = aggs[3].shape[1]
-        w0i = ing.word_idx[0]
-        w0o = eg.word_idx[0]
-        run_kernel = bool(meta.phases & PH_CLS)
-        summary = (not run_kernel) and bool(meta.phases & PH_CLS_SUM)
-
-        def full_hits(safe):
-            """Full-width (exact) re-walk of compacted fallback lanes —
-            the `_classify_pruned` fallback discipline, delta patches
-            applied at full precision."""
-            ra = ing.at.inc[iv6[0][safe]]
-            rp = ing.peer.inc[iv6[1][safe]]
-            rs = ing.svc.inc[iv6[2][safe]]
-            oa = eg.at.inc[iv6[3][safe]]
-            opr = eg.peer.inc[iv6[4][safe]]
-            osv = eg.svc.inc[iv6[5][safe]]
-            if meta.match.svcref:
-                osv = osv | eg.svc.inc[iv_ref[safe]]
-            if d is not None:
-                ra = _m._patch_rows(ra, dnat_ip[safe], d, d.at_in)
-                rp = _m._patch_rows(rp, src_f[safe], d, d.peer_in)
-                oa = _m._patch_rows(oa, src_f[safe], d, d.at_out)
-                opr = _m._patch_rows(opr, dnat_ip[safe], d, d.peer_out)
-            return (_m._phase_hits(ra & rp & rs, ing.word_idx,
-                                   meta.match.in_phases)
-                    + _m._phase_hits(oa & opr & osv, eg.word_idx,
-                                     meta.match.out_phases))
-
-        def fb_switch(fbb, carried, fixup):
-            """Pow2-rung compacted redispatch of the fallback lanes (the
-            in-jit _spill_retry shape shared with _classify_pruned)."""
-            fb_idx = jnp.nonzero(fbb, size=B, fill_value=B)[0].astype(
-                jnp.int32)
-            n_fb = fbb.sum(dtype=jnp.int32)
-            rungs = []
-            r = _m._FB_MIN
-            while r < B:
-                rungs.append(r)
-                r *= 4
-            rungs = sorted(set(min(x, B) for x in rungs + [B]))
-
-            def apply_rung(r):
-                def go(c):
-                    idx = fb_idx[:r]
-                    safe = jnp.minimum(idx, B - 1)
-                    tgt = jnp.where(idx < B, idx, B)
-                    return fixup(c, safe, tgt)
-
-                return go
-
-            branches = [lambda c: c] + [apply_rung(r) for r in rungs]
-            sel = jnp.where(
-                n_fb == 0, 0,
-                1 + sum(((n_fb > r).astype(jnp.int32)
-                         for r in rungs[:-1]), start=jnp.int32(0)))
-            return jax.lax.switch(sel, branches, carried)
-
-        def resolve_fresh(hits6, iso_i, iso_o, noep):
-            """Shared hit->fresh-image resolution (the slow-path verdict
-            overlay: SvcReject precedes the policy tables)."""
-            in_code, in_rule = _m._resolve(ing.action, hits6[:3], iso_i)
-            out_code, out_rule = _m._resolve(eg.action, hits6[3:], iso_o)
-            cls_code = jnp.where(out_code != ACT_ALLOW, out_code, in_code)
-            f_code = jnp.where(noep, ACT_REJECT, cls_code).astype(jnp.int32)
-            f_ri = jnp.where(noep, MISS, in_rule)
-            f_ro = jnp.where(noep, MISS, out_rule)
-            return f_code, f_ri, f_ro
-
-        # Cached-image decode (start-of-batch rows — the merge source).
-        c_code, c_svc, c_dport = _unpack_meta1(mr[:, 1])
-        c_dnat = mr[:, 0]
-        c_ri, c_ro = _unpack_rules(mr[:, 2])
-        c_snat_b = (mr[:, 3] >> 31) & 1
-        c_dsr_b = (mr[:, 3] >> 30) & 1
-
-        def merged_images(f_code, f_ri, f_ro):
-            o_code = jnp.where(hit, c_code,
-                               jnp.where(miss, f_code, ACT_ALLOW))
-            o_svc = jnp.where(hit, c_svc, jnp.where(miss, svc_idx, MISS))
-            o_dnat = jnp.where(hit, c_dnat, jnp.where(miss, dnat_ip, dst_f))
-            o_dport = jnp.where(hit, c_dport,
-                                jnp.where(miss, dnat_port, dport))
-            o_ri = jnp.where(hit, c_ri, jnp.where(miss, f_ri, MISS))
-            o_ro = jnp.where(hit, c_ro, jnp.where(miss, f_ro, MISS))
-            o_snat = jnp.where(hit & ~rpl, c_snat_b,
-                               jnp.where(miss, snat_m, 0))
-            o_dsr = jnp.where(hit & ~rpl, c_dsr_b,
-                              jnp.where(miss, dsr_m, 0))
-            return (o_code, o_svc, o_dnat, o_dport, o_ri, o_ro, o_snat,
-                    o_dsr)
-
-        skipv = z
-        fbv = z
-        candv = z
-        if run_kernel:
-            # ---- the one-pass kernel --------------------------------------
-            pad = (-B) % _m._FUSE_TB
-
-            def padr(x):
-                if not pad:
-                    return x
-                return jnp.pad(x, ((0, pad), (0, 0)))
-
-            pkt = padr(jnp.stack(
-                [src_f, dst_f, proto, sport, dport, pp, z, z], axis=1))
-            prb = padr(jnp.stack([ts0, iso_in, iso_out, z], axis=1))
-            mskm = padr(jnp.stack(
-                [validm.astype(jnp.int32), ncm.astype(jnp.int32),
-                 delta_fb.astype(jnp.int32), z], axis=1))
-            lbm = padr(jnp.stack(
-                [svc_idx, no_ep.astype(jnp.int32), dnat_ip, dnat_port,
-                 snat_m, dsr_m, z, z], axis=1))
-            ivm = padr(jnp.stack(list(iv6) + [iv_ref, z], axis=1))
-            scal = jnp.stack([
-                jnp.asarray(now, jnp.int32), gen_w,
-                jnp.asarray(w0i, jnp.int32), jnp.asarray(w0o, jnp.int32),
-            ]).reshape(1, 4)
-            inc_tabs = (ing.at, ing.peer, ing.svc, eg.at, eg.peer, eg.svc)
-            inc2 = [t.inc.reshape(-1, _m.AGG_BLOCK) for t in inc_tabs]
-            if meta.match.svcref:
-                inc2.append(eg.svc.inc.reshape(-1, _m.AGG_BLOCK))
-            acts = (ing.action, eg.action) if resolve else ()
-            call = _m._onepass_call(
-                B + pad, s_in, s_out, K, K, meta.match.in_phases,
-                meta.match.out_phases, meta.match.svcref, resolve,
-                meta.timeouts, N, pmask, interp)
-            res = call(pkt, padr(kr0), prb, padr(mr), mskm, lbm,
-                       *[padr(a) for a in aggs], ivm, scal, *inc2, *acts)
-            res = [x[:B] for x in res]
-            if resolve:
-                main, keys8, meta8, aux = res
-                o_code, o_ri, o_ro = main[:, 0], main[:, 1], main[:, 2]
-                o_svc, o_dnat, o_dport = main[:, 3], main[:, 4], main[:, 5]
-                o_snat, o_dsr = main[:, 6], main[:, 7]
-                committed = main[:, 8] != 0
-                rev_ins = main[:, 9] != 0
-                rev_slot = main[:, 10]
-                ins = main[:, 14] != 0
-                skipv, fbv, candv = aux[:, 0], aux[:, 1], aux[:, 2]
-
-                def fix_resolve(c, safe, tgt):
-                    (o_code, o_ri, o_ro, committed, rev_ins, keys8,
-                     meta8) = c
-                    h6 = full_hits(safe)
-                    f_code, f_ri, f_ro = resolve_fresh(
-                        h6, iso_in[safe], iso_out[safe], no_ep[safe])
-                    rows = _fused_pack_rows(
-                        src_f[safe], dst_f[safe], proto[safe], sport[safe],
-                        dport[safe], pp[safe], f_code, svc_idx[safe],
-                        dnat_ip[safe], dnat_port[safe], snat_m[safe],
-                        dsr_m[safe], f_ri, f_ro, miss[safe], ncm[safe],
-                        now, gen_w, N, pmask)
-                    return (
-                        o_code.at[tgt].set(f_code, mode="drop"),
-                        o_ri.at[tgt].set(f_ri, mode="drop"),
-                        o_ro.at[tgt].set(f_ro, mode="drop"),
-                        committed.at[tgt].set(rows["committed"],
-                                              mode="drop"),
-                        rev_ins.at[tgt].set(rows["rev_ins"], mode="drop"),
-                        keys8.at[tgt].set(rows["keys8"], mode="drop"),
-                        meta8.at[tgt].set(rows["meta8"], mode="drop"),
-                    )
-
-                (o_code, o_ri, o_ro, committed, rev_ins, keys8,
-                 meta8) = fb_switch(
-                    fbv > 0,
-                    (o_code, o_ri, o_ro, committed, rev_ins, keys8, meta8),
-                    fix_resolve)
-                images = (o_code, o_svc, o_dnat, o_dport, o_ri, o_ro,
-                          o_snat, o_dsr)
-                rows = dict(committed=committed, ins=ins, rev_ins=rev_ins,
-                            rev_slot=rev_slot, keys8=keys8, meta8=meta8)
-            else:
-                hits8, aux = res
-                hits6 = tuple(hits8[:, i] for i in range(6))
-
-                def fix_hits(c, safe, tgt):
-                    h6 = full_hits(safe)
-                    return tuple(
-                        cur.at[tgt].set(new, mode="drop")
-                        for cur, new in zip(c, h6))
-
-                hits6 = fb_switch(aux[:, 1] > 0, hits6, fix_hits)
-                in_hits = tuple(hit_combine(x) for x in hits6[:3])
-                out_hits = tuple(hit_combine(x) for x in hits6[3:])
-                # Shard-local prune observables -> the replicated view
-                # (the _classify_pruned min-combine discipline).
-                skipv = hit_combine(aux[:, 0])
-                fbv = 1 - hit_combine(1 - aux[:, 1])
-                candv = -hit_combine(-aux[:, 2])
-                f_code, f_ri, f_ro = resolve_fresh(
-                    in_hits + out_hits, iso_in, iso_out, no_ep)
-                images = merged_images(f_code, f_ri, f_ro)
-                rows = _fused_pack_rows(
-                    src_f, dst_f, proto, sport, dport, pp, f_code, svc_idx,
-                    dnat_ip, dnat_port, snat_m, dsr_m, f_ri, f_ro, miss,
-                    ncm, now, gen_w, N, pmask)
-        else:
-            if summary:
-                # PH_CLS_SUM tier: aggregate AND + short-circuit only —
-                # live lanes take the default-verdict image (the
-                # profiling surface, never a production path).
-                g_in = aggs[0] & aggs[1] & aggs[2]
-                g_out = aggs[3] & aggs[4] & aggs[5]
-                nc_in = jnp.where(miss, (g_in != jnp.uint32(0)).sum(
-                    axis=1, dtype=jnp.int32), 0)
-                nc_out = jnp.where(miss, (g_out != jnp.uint32(0)).sum(
-                    axis=1, dtype=jnp.int32), 0)
-                skipv = (miss & (nc_in == 0) & (nc_out == 0)).astype(
-                    jnp.int32)
-                candv = jnp.maximum(nc_in, nc_out)
-                if hit_combine is not None:
-                    skipv = hit_combine(skipv)
-                    candv = -hit_combine(-candv)
-            f_code, f_ri, f_ro = resolve_fresh(
-                (BIGS,) * 6, iso_in, iso_out, no_ep)
-            if not summary:
-                # Neither classify bit: the staged default-allow image.
-                f_code = jnp.where(no_ep, ACT_REJECT, ACT_ALLOW).astype(
-                    jnp.int32)
-                f_ri = jnp.full((B,), MISS, jnp.int32)
-                f_ro = jnp.full((B,), MISS, jnp.int32)
-            images = merged_images(f_code, f_ri, f_ro)
-            rows = _fused_pack_rows(
-                src_f, dst_f, proto, sport, dport, pp, f_code, svc_idx,
-                dnat_ip, dnat_port, snat_m, dsr_m, f_ri, f_ro, miss, ncm,
-                now, gen_w, N, pmask)
-
-        (o_code, o_svc, o_dnat, o_dport, o_ri, o_ro, o_snat,
-         o_dsr) = images
-        committed = rows["committed"]
-        ins = rows["ins"]
-        rev_ins = rows["rev_ins"]
-        rev_slot = rows["rev_slot"]
-        keys8 = rows["keys8"]
-        meta8 = rows["meta8"]
-
-        # ---- prune observability (exactly-once per lane; the mesh's
-        # spilled lanes are excluded — their home retry owns the evidence).
-        pv = validm if prune_exclude is None else (validm & ~prune_exclude)
-        pr_sk = pr_sk0 + ((skipv > 0) & pv).sum(dtype=jnp.int32)
-        pr_fb = pr_fb0 + ((fbv > 0) & pv).sum(dtype=jnp.int32)
-        if run_kernel or summary:
-            pr_hist = pr_hist0 + _prune_bucket_counts(candv, miss & pv)
-        else:
-            pr_hist = pr_hist0
-
-        # ---- commit: interleaved [fwd, rev] scatters off the packed rows
-        if meta.phases & PH_COMMIT:
-            slot2 = jnp.stack([slot, rev_slot], axis=1).reshape(2 * B)
-            keys2 = jnp.stack([keys8[:, :4], keys8[:, 4:]],
-                              axis=1).reshape(2 * B, 4)
-            meta2 = jnp.stack([meta8[:, :4], meta8[:, 4:]],
-                              axis=1).reshape(2 * B, 4)
-            ins2 = jnp.stack([ins, rev_ins], axis=1).reshape(2 * B)
-
-            if meta.second_chance:
-                flow, ins2, sc_n = _second_chance_guard(
-                    flow, slot2, keys2, ins2, now, meta, A, dump)
-                if tel_on:
-                    tel_sc = tel_sc + sc_n
-
-            if meta.phases & PH_EVICT:
-                tgt2 = jnp.where(ins2, slot2, dump)
-                okr = flow.keys[tgt2]
-                id3 = 0xFF | REPLY_BIT
-                tuple_differs = (
-                    (okr[:, : A + 1] != keys2[:, : A + 1]).any(axis=1)
-                    | ((okr[:, A + 1] & id3) != (keys2[:, A + 1] & id3))
-                )
-                overwrote = ins2 & (okr[:, A + 1] != 0) & tuple_differs
-                if meta.drain_reclaim:
-                    om3 = flow.meta[tgt2, 3]
-                    otmo = entry_timeout(
-                        (om3 >> 29) & 1, okr[:, A + 1] & 0xFF,
-                        meta.timeouts)
-                    ogen = (okr[:, A + 1] >> 9) & GEN_ETERNAL
-                    dead = ((now - flow.ts[tgt2]) > otmo) | (
-                        (ogen != GEN_ETERNAL) & (ogen != gen_w))
-                    n_reclaim = n_reclaim + (overwrote & dead).sum(
-                        dtype=jnp.int32)
-                    overwrote = overwrote & ~dead
-                n_evict = n_evict + overwrote.sum(dtype=jnp.int32)
-
-            if meta.count_flow_stats:
-                lv = (jnp.zeros(B, jnp.int32) if lens is None
-                      else jnp.maximum(lens, 0))
-                pk2 = jnp.stack([jnp.ones(B, jnp.int32), z],
-                                axis=1).reshape(2 * B)
-                oc2 = jnp.stack([lv, z], axis=1).reshape(2 * B)
-                z2 = jnp.zeros(2 * B, jnp.int32)
-                new_pkts = _scatter_last(flow.pkts, slot2, pk2, ins2, dump)
-                new_octets = _scatter_last(flow.octets, slot2, oc2, ins2,
-                                           dump)
-                new_pkts_hi = _scatter_last(flow.pkts_hi, slot2, z2, ins2,
-                                            dump)
-                new_octets_hi = _scatter_last(flow.octets_hi, slot2, z2,
-                                              ins2, dump)
-            else:
-                new_pkts, new_octets = flow.pkts, flow.octets
-                new_pkts_hi, new_octets_hi = flow.pkts_hi, flow.octets_hi
-            flow = FlowCache(
-                keys=_scatter_last_rows(flow.keys, slot2, keys2, ins2,
-                                        dump),
-                meta=_scatter_last_rows(flow.meta, slot2, meta2, ins2,
-                                        dump),
-                ts=_scatter_last(flow.ts, slot2,
-                                 jnp.full((2 * B,), now, jnp.int32), ins2,
-                                 dump),
-                pkts=new_pkts,
-                octets=new_octets,
-                pkts_hi=new_pkts_hi,
-                octets_hi=new_octets_hi,
-            )
-            lm = learn["mask"] & miss
-            adump = meta.aff_slots
-            aff = AffinityTable(
-                key_client=_scatter_last(aff.key_client, learn["aslot"],
-                                         learn["client"], lm, adump),
-                key_svc=_scatter_last(aff.key_svc, learn["aslot"],
-                                      learn["svc"], lm, adump),
-                ep=_scatter_last(aff.ep, learn["aslot"], learn["ep"], lm,
-                                 adump),
-                ts=_scatter_last(aff.ts, learn["aslot"],
-                                 jnp.full((B,), now, jnp.int32), lm,
-                                 adump),
-            )
-
-        return flow, aff, (
-            outbuf(o_code), outbuf(o_svc), outbuf(o_dnat), outbuf(o_dport),
-            outbuf(o_ri), outbuf(o_ro),
-            outbuf(committed.astype(jnp.int32)), outbuf(o_snat),
-            outbuf(o_dsr), n_evict, n_reclaim, pr_sk, pr_fb, pr_hist) + (
-            (tel_hb, tel_sc) if tel_on else ())
-
     def noop(args):
         return args
 
@@ -2137,14 +1525,12 @@ def _pipeline_step(
                                            jnp.int32)) if prune_on else ()) + (
                                  (jnp.int32(0), jnp.int32(0))
                                  if tel_on else ()))
-        if meta.phases & PH_SLOW:
-            slow_body = slow_onepass if meta.onepass else slow
-            flow, aff, outs = jax.lax.cond(n_miss > 0, slow_body, noop,
-                                           slow_init)
-        else:
-            # Slow path masked out entirely (profiling floor): misses keep the
-            # fast-path default image and commit nothing.
+        if meta.defer_misses:
+            # The async fast step: misses keep the fast-path default image
+            # and commit nothing; the engine queues them for a drain step.
             flow, aff, outs = slow_init
+        else:
+            flow, aff, outs = jax.lax.cond(n_miss > 0, slow, noop, slow_init)
     (out_code, out_svc, out_dnat_ip, out_dnat_port,
      out_rule_in, out_rule_out, out_committed, out_snat, out_dsr,
      n_evict, n_reclaim) = outs[:11]
@@ -2171,7 +1557,7 @@ def _pipeline_step(
             "committed": out_committed[:B],
             # Per-lane cache-miss mask (1 = this lane took / would take the
             # slow path).  In synchronous mode an informational overlay; in
-            # the async fast step (PH_SLOW masked) it is the miss-queue
+            # the async fast step (defer_misses) it is the miss-queue
             # ADMISSION mask the engine consumes (datapath/slowpath).
             "miss": miss.astype(jnp.int32),
             # SNAT-mark classification (pipeline.go SNATMark analog): external
@@ -2363,8 +1749,7 @@ maintain_scan = jax.jit(_maintain_scan, static_argnames=("timeouts",))
 # The continuous revalidator runs OFF the hot step, like age_scan and
 # canary_scan: nothing here is reachable from pipeline_step, so with the
 # audit plane idle the compiled step is bit-identical to a plane-less
-# build (tests/test_cache_audit.py verifies the lowered HLO, the same way
-# tools/check_phases.py pins the PH_* masks).
+# build (tests/test_cache_audit.py verifies the lowered HLO).
 
 
 def _audit_gather(state: PipelineState, cursor: jax.Array, *, window: int):

@@ -5,8 +5,9 @@ The reference treats its datapath as a black box it can only poll from
 outside (conntrack dumps via pkg/agent/flowexporter); this build OWNS the
 datapath, so the kernel itself is instrumented: with
 PipelineMeta.telemetry set, every step emits cheap counter outputs —
-cache probe hit/stale/miss splits, DMA half-blocks issued by the
-one-pass kernel, second-chance protection bumps — derived XLA-side from
+cache probe hit/stale/miss splits, second-chance protection bumps (and
+`dma_hb`, the half-block counter of the removed one-pass kernel, which
+reads 0 on every path: ROADMAP debt) — derived XLA-side from
 values the step already gathers (models/pipeline.py tel_* keys), and
 `telemetry=False` lowers to HLO bit-identical with the uninstrumented
 step.  `TelemetryPlane` is the host-side accumulator both engines and
@@ -38,8 +39,7 @@ never backpressure on the hot step.
 Surfaces: `GET /telemetry` (agent/apiserver.py), `antctl telemetry`,
 `telemetry.json` in the support bundle, the telemetry metric families
 (metrics.render_metrics — one counter family per name here, the regime
-histogram, the regression meter), and bench.py's `steady_telemetry_pps`
-overhead line.
+histogram, the regression meter).
 """
 
 from __future__ import annotations

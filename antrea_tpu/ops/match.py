@@ -657,6 +657,18 @@ def _delta_lane_match(ip_f, dt: DeltaTable, i, wide):
     return m4 | m6
 
 
+def _over_active_slots(dt: DeltaTable, body, init):
+    """`body` folded over the active delta slots, in append order.  The
+    eager fresh walks (canary, audit re-proof) trace `body` anew on every
+    call — it closes over the probe columns — and so compile the loop
+    again each time; with no slot active (a concrete n of 0: the state
+    between a bundle and the next membership delta) they skip it.  Under
+    jit n is traced and the loop lowers as it always did."""
+    if not isinstance(dt.n, jax.core.Tracer) and int(dt.n) == 0:
+        return init
+    return jax.lax.fori_loop(0, dt.n, body, init)
+
+
 def _patch_rows(rows: jax.Array, ip_f: jax.Array, dt: DeltaTable, masks,
                 wide=None) -> jax.Array:
     """Apply the active delta slots to gathered incidence rows (B, W).
@@ -671,7 +683,7 @@ def _patch_rows(rows: jax.Array, ip_f: jax.Array, dt: DeltaTable, masks,
         rows = jnp.where((m & (s < 0))[:, None], rows & ~mask, rows)
         return rows
 
-    return jax.lax.fori_loop(0, dt.n, body, rows)
+    return _over_active_slots(dt, body, rows)
 
 
 def _patch_iso(bit: jax.Array, ip_f: jax.Array, dt: DeltaTable, which: int,
@@ -686,7 +698,7 @@ def _patch_iso(bit: jax.Array, ip_f: jax.Array, dt: DeltaTable, which: int,
         bit = jnp.where(m & (s < 0), 0, bit)
         return bit
 
-    return jax.lax.fori_loop(0, dt.n, body, bit)
+    return _over_active_slots(dt, body, bit)
 
 
 def _agg_mask(mask_w: jax.Array) -> jax.Array:
@@ -715,7 +727,7 @@ def _patch_agg(rows: jax.Array, ip_f: jax.Array, dt: DeltaTable, masks,
         am = _agg_mask(masks[i])[None, :]
         return jnp.where(m[:, None], rows | am, rows)
 
-    return jax.lax.fori_loop(0, dt.n, body, rows)
+    return _over_active_slots(dt, body, rows)
 
 
 def _patch_cand(cw: jax.Array, widx: jax.Array, ip_f: jax.Array,
@@ -733,7 +745,7 @@ def _patch_cand(cw: jax.Array, widx: jax.Array, ip_f: jax.Array,
         cw = jnp.where((m & (s < 0))[:, None, None], cw & ~mw, cw)
         return cw
 
-    return jax.lax.fori_loop(0, dt.n, body, cw)
+    return _over_active_slots(dt, body, cw)
 
 
 def _phase_first_from_base(mu: jax.Array, base: jax.Array, phases):
@@ -941,8 +953,7 @@ def _phase_hits(match: jax.Array, word_idx: jax.Array, phases: tuple[int, int, i
 # is taken by classify_batch_fused below: XLA performs the 6 gathers, ONE
 # pallas kernel consumes each gathered byte exactly once (AND + per-phase
 # first-set-bit in VMEM, contiguous 1MB block DMAs), measured 6.3ms vs
-# 7.1ms (5.2M vs 4.6M pps).  The honest gap to the 10M target is
-# reported, not hidden, in bench.py's cold extras.
+# 7.1ms (5.2M vs 4.6M pps).
 #
 # Round-5 follow-up (round-4 verdict weak #1 asked whether the
 # 1.9ms/batch of non-gather time could be overlapped or folded; same
@@ -986,7 +997,7 @@ def _phase_hits(match: jax.Array, word_idx: jax.Array, phases: tuple[int, int, i
 # was ruled out:
 #   OVERLAPPED (shipped, models/pipeline + datapath/slowpath):
 #   (a) eviction-scan + aging + revalidation folded into the drain's
-#       commit pass (meta.drain_reclaim): the PH_EVICT audit already
+#       commit pass (meta.drain_reclaim): the eviction audit already
 #       gathers each insert target's old key row; reading its ts/conf in
 #       the same pass classifies dead rows (idle-expired / stale-gen) as
 #       reclaims, so the engine's stale-epoch heal needs ONE fused
@@ -1017,14 +1028,8 @@ def _phase_hits(match: jax.Array, word_idx: jax.Array, phases: tuple[int, int, i
 #   (e) true cross-op concurrency: a TensorCore runs one XLA op at a
 #       time, so "overlap" here means removing redundant passes, copies
 #       and host round-trips from the serial schedule, not co-executing
-#       fast and drain — the honest mechanism, and why the decomposition
-#       (bench_cold_study.py case 5: fast alone / drain alone /
-#       serialized / overlapped) is the proof obligation: the overlapped
-#       step time must approach max-ish(fast, drain) only through the
-#       removed work, and serialized-minus-overlapped IS the recovered
-#       serialization.  Not measured on a chip: the overlapped cadence
-#       has no on-chip record (bench_profile.py --mode overlap is the
-#       attribution tool).
+#       fast and drain.  Not measured on a chip: the overlapped cadence
+#       has no on-chip record.
 #
 # Round-7: aggregated-bitmap pruning (ROADMAP item 2's kernel half; the
 # two-level classify shipped below as _classify_pruned).  Why this is NOT
@@ -1053,65 +1058,18 @@ def _phase_hits(match: jax.Array, word_idx: jax.Array, phases: tuple[int, int, i
 # full width in a pow2-rung lax.switch (the PR 9 _spill_retry shape,
 # in-jit), metered as match_prune_fallbacks_total and fed to the
 # K-budget autotuner (PruneAutotuner, the PR 6 DrainAutotuner pattern).
-# Decomposition + fallback-rate-vs-K + match-density sweeps:
-# bench_cold_study.py case 6; per-phase attribution: PRUNE_PHASE_CHAIN
-# (prune_summary_gather vs prune_candidate_gather) under the ±15% gate.
 # Not measured on a chip.  The pruned consumer compiles and serves
 # oracle-exact on a v5e (chip_smoke.py, PR 21); its rate and fallback
 # rate there are not measured.
 #
-# Round-8: the one-kernel fast path (_onepass_call + models/pipeline
-# meta.onepass; ROADMAP item 1).  The round-4/7 residual past the gather
-# bound (~2.36ms/batch) is XLA's STAGE BOUNDARIES: cache probe,
-# aggregate AND, candidate gather, first-match and LB/verdict resolution
-# are separate fusions with HBM materialization between them, and every
-# boundary re-reads what the previous stage wrote.  The one-pass kernel
-# keeps per-lane state in VMEM end to end: probe decode, aggregate AND +
-# zero-AND short circuit, candidate-superblock DMA (double-buffered —
-# half-block j+1's AND/top-K/DMA-issue overlap the wait on half-block
-# j's copies), the shared _phase_first_from_base scan, verdict
-# resolution and commit-ROW packing, one pass per batch.  What stays
-# XLA, and why (the measured walls above): the index-driven row gathers
-# feeding the kernel (cache row, aggregate rows, LB probe chain — note 1
-# bounds any DMA-descriptor fetch of scattered rows at 38 GB/s; XLA's
-# gather engine is the only fast fetch path), the commit SCATTERS (study
-# idea (d): no arbitrary-VMEM-scatter path on this Mosaic, and the cache
-# exceeds VMEM — but their INPUT ROWS are now kernel outputs, so the
-# classify->commit materialization is gone), and the pow2-rung fallback
-# redispatch (full-width rows are an XLA gather by the same note-1
-# wall).  Under rule sharding the kernel emits GLOBAL hits for the pmin
-# seam and resolution runs post-allreduce (`resolve=False`) — the
-# cross-shard first-match needs the ICI combine between scan and
-# resolve, physics no fusion removes.  HONEST RISKS on a chip
-# (never measured — see the PR 21 note below): the candidate path issues
-# ~6*K small DMAs per live lane plus 4 single-word action DMAs — at the
-# note-1 ~200ns/DMA fixed cost the double buffer must hide ~(6K+4)*200ns
-# per lane behind the AND/scan compute, and a large K x rung product can
-# exceed VMEM scratch; the operator's answer to either is the staged
-# kernel (construct with fused=False — bit-identical verdicts, the
-# parity suite pins it), never a wrong verdict.  WHAT THE COUNTERS
-# DECIDE (hot-path telemetry, observability/telemetry.py): with
-# PipelineMeta.telemetry on, every dispatch emits tel_dma_hb — the
-# _OP_HB half-blocks this schedule walked, a physical constant of the
-# padded batch shape (models/pipeline.py derives it next to the probe
-# hit/stale/miss split) — and those counters are the PRODUCTION inputs
-# to the batching call above: dma_hb x (6K+4) x ~200ns is the fixed DMA
-# cost the double buffer must currently be hiding, so a steady-regime
-# p99 that climbs (the sentinel's perf-regression verdict) while
-# dma_hb/step holds flat means the overlap stopped covering the
-# descriptor cost — the operator reads that as "fall back to
-# fused=False" from the journal, before any bench run reproduces it.
-# Off-TPU (pallas_interpret) the whole kernel runs under the interpreter,
-# which is what tests/test_match_fused.py certifies.
-# PR 21, v5e / jax 0.9.0 / libtpu 0.0.34: THE ONE-PASS KERNEL DOES NOT
-# LOWER.  Pallas has no TPU lowering for the in-kernel lax.top_k; with
-# that replaced by K min-extractions Mosaic next refuses the 32-word
-# candidate-row DMA ("Slice shape along dimension 1 must be aligned to
-# tiling (128), but is 32") — the candidate granule (AGG_BLOCK words)
-# is a quarter of a lane tile, so repairing it is a table-layout
-# redesign, not a local fix.  models/pipeline.require_onepass_lowers
-# turns the compiler's refusal into a typed ConfigError at construction;
-# nothing falls back on its own.
+# Round-8 tried the whole slow path as ONE Pallas pass (probe decode,
+# aggregate AND, double-buffered candidate-superblock DMA, first match,
+# resolve and commit-row packing in VMEM) to remove the HBM round trips
+# between XLA's stages.  It never lowered on a v5e (PR 21, jax 0.9.0 /
+# libtpu 0.0.34: Pallas has no TPU lowering for the in-kernel lax.top_k,
+# and with that replaced Mosaic refuses the 32-word candidate-row DMA —
+# AGG_BLOCK is a quarter of a 128-lane tile, a table-layout redesign, not
+# a local fix) and is gone.
 
 
 def _resolve(action: jax.Array, hits, pod_iso: jax.Array):
@@ -1119,16 +1077,6 @@ def _resolve(action: jax.Array, hits, pod_iso: jax.Array):
     h0, hk, hb = hits
     a0 = action[jnp.clip(h0, 0, action.shape[0] - 1)]
     ab = action[jnp.clip(hb, 0, action.shape[0] - 1)]
-    return _resolve_from_actions(a0, ab, hits, pod_iso)
-
-
-def _resolve_from_actions(a0: jax.Array, ab: jax.Array, hits,
-                          pod_iso: jax.Array):
-    """_resolve with the two action gathers already performed — the seam
-    the one-pass kernel (round 8) resolves through: it fetches a0/ab by
-    per-lane DMA instead of an XLA gather, then runs the IDENTICAL phase
-    resolution, so the two paths cannot drift."""
-    h0, hk, hb = hits
     has0 = h0 < BIG
     hask = hk < BIG
     hasb = hb < BIG
@@ -1224,7 +1172,6 @@ def classify_batch(
     fused: bool = False,
     v6=None,
     svc_ref=None,
-    summary_only: bool = False,
 ):
     """-> dict with final/egress/ingress codes and deciding rule indices.
 
@@ -1255,16 +1202,12 @@ def classify_batch(
     interpret mode (slow; parity tests only).
 
     meta.prune_budget > 0 routes through the two-level aggregated-bitmap
-    path (_classify_pruned, round 7); summary_only is its profiling
-    sub-mode (aggregate phase only, live lanes take defaults — the
-    PH_CLS_SUM surface, never a production verdict path) and is ignored
-    when pruning is off.
+    path (_classify_pruned, round 7).
     """
     if meta.prune_budget > 0 and drs.ingress.at.agg is not None:
         return _classify_pruned(
             drs, src_ip_f, dst_ip_f, proto, dst_port, meta=meta,
             hit_combine=hit_combine, fused=fused, v6=v6, svc_ref=svc_ref,
-            summary_only=summary_only,
         )
     ing, eg = drs.ingress, drs.egress
     svc_key = (proto << 16) | dst_port
@@ -1588,7 +1531,6 @@ def _classify_pruned(
     fused: bool = False,
     v6=None,
     svc_ref=None,
-    summary_only: bool = False,
 ):
     """Two-level pruned classify (classify_batch's round-7 fast path).
 
@@ -1614,9 +1556,6 @@ def _classify_pruned(
       prune_cand (B,) i32  — candidate superblocks, max over directions
                              and rule shards (what the per-shard K budget
                              must cover)
-
-    summary_only (the PH_CLS_SUM profiling surface): stop after phase 1 —
-    every live lane takes the default-verdict image, nothing falls back.
     """
     ing, eg = drs.ingress, drs.egress
     B = src_ip_f.shape[0]
@@ -1750,80 +1689,75 @@ def _classify_pruned(
         return _phase_hits(ra & rp & rs, dd.word_idx, dc["phases"])
 
     with device_scope("classify.scan"):
-        if summary_only:
-            in_hits = (BIGS, BIGS, BIGS)
-            out_hits = (BIGS, BIGS, BIGS)
-            fb = no_fb
-        else:
-            def phase2(_):
-                mats_in, ke_in = cand_mats(dir_in, g_in)
-                mats_out, ke_out = cand_mats(dir_out, g_out)
-                if fused:
-                    pad = (-B) % _FUSE_TB
-                    if pad:
-                        mats_in = tuple(jnp.pad(x, ((0, pad), (0, 0)))
-                                        for x in mats_in)
-                        mats_out = tuple(jnp.pad(x, ((0, pad), (0, 0)))
-                                         for x in mats_out)
-                    call = _pruned_consumer_call(
-                        B + pad, ke_in * AGG_BLOCK, ke_out * AGG_BLOCK,
-                        meta.in_phases, meta.out_phases,
-                        pallas_interpret(meta),
-                    )
-                    hits = call(*mats_in, *mats_out)[:B]
-                    hits6 = tuple(hits[:, i] for i in range(6))
-                else:
-                    ia, ipr, isv, bi = mats_in
-                    oa, opr, osv, bo = mats_out
-                    hits6 = (_phase_first_from_base(ia & ipr & isv, bi,
-                                                    meta.in_phases)
-                             + _phase_first_from_base(oa & opr & osv, bo,
-                                                      meta.out_phases))
-                fb = (nc_in > ke_in) | (nc_out > ke_out)
-                fb_idx = jnp.nonzero(fb, size=B, fill_value=B)[0].astype(
-                    jnp.int32)
-                n_fb = fb.sum(dtype=jnp.int32)
-                rungs = []
-                r = _FB_MIN
-                while r < B:
-                    rungs.append(r)
-                    r *= 4
-                rungs = sorted(set(min(r, B) for r in rungs + [B]))
-
-                def apply_rung(r):
-                    def go(h6):
-                        idx = fb_idx[:r]
-                        safe = jnp.minimum(idx, B - 1)
-                        ih = full_dir_hits(dir_in, safe)
-                        oh = full_dir_hits(dir_out, safe)
-                        tgt = jnp.where(idx < B, idx, B)  # B drops (OOB)
-                        return tuple(
-                            cur.at[tgt].set(new, mode="drop")
-                            for cur, new in zip(h6, ih + oh)
-                        )
-
-                    return go
-
-                branches = [lambda h6: h6] + [apply_rung(r) for r in rungs]
-                sel = jnp.where(
-                    n_fb == 0,
-                    0,
-                    1 + sum(((n_fb > r).astype(jnp.int32) for r in rungs[:-1]),
-                            start=jnp.int32(0)),
+        def phase2(_):
+            mats_in, ke_in = cand_mats(dir_in, g_in)
+            mats_out, ke_out = cand_mats(dir_out, g_out)
+            if fused:
+                pad = (-B) % _FUSE_TB
+                if pad:
+                    mats_in = tuple(jnp.pad(x, ((0, pad), (0, 0)))
+                                    for x in mats_in)
+                    mats_out = tuple(jnp.pad(x, ((0, pad), (0, 0)))
+                                     for x in mats_out)
+                call = _pruned_consumer_call(
+                    B + pad, ke_in * AGG_BLOCK, ke_out * AGG_BLOCK,
+                    meta.in_phases, meta.out_phases,
+                    pallas_interpret(meta),
                 )
-                hits6 = jax.lax.switch(sel, branches, hits6)
-                return hits6 + (fb,)
+                hits = call(*mats_in, *mats_out)[:B]
+                hits6 = tuple(hits[:, i] for i in range(6))
+            else:
+                ia, ipr, isv, bi = mats_in
+                oa, opr, osv, bo = mats_out
+                hits6 = (_phase_first_from_base(ia & ipr & isv, bi,
+                                                meta.in_phases)
+                         + _phase_first_from_base(oa & opr & osv, bo,
+                                                  meta.out_phases))
+            fb = (nc_in > ke_in) | (nc_out > ke_out)
+            fb_idx = jnp.nonzero(fb, size=B, fill_value=B)[0].astype(
+                jnp.int32)
+            n_fb = fb.sum(dtype=jnp.int32)
+            rungs = []
+            r = _FB_MIN
+            while r < B:
+                rungs.append(r)
+                r *= 4
+            rungs = sorted(set(min(r, B) for r in rungs + [B]))
 
-            def all_dead(_):
-                # Aggregate-AND-zero short circuit for the whole batch (the
-                # adversarial / default-deny cold shape): no candidate
-                # gather, no fallback — straight to the default verdicts.
-                return (BIGS,) * 6 + (no_fb,)
+            def apply_rung(r):
+                def go(h6):
+                    idx = fb_idx[:r]
+                    safe = jnp.minimum(idx, B - 1)
+                    ih = full_dir_hits(dir_in, safe)
+                    oh = full_dir_hits(dir_out, safe)
+                    tgt = jnp.where(idx < B, idx, B)  # B drops (OOB)
+                    return tuple(
+                        cur.at[tgt].set(new, mode="drop")
+                        for cur, new in zip(h6, ih + oh)
+                    )
 
-            res = jax.lax.cond(
-                ((nc_in > 0) | (nc_out > 0)).any(), phase2, all_dead, None
+                return go
+
+            branches = [lambda h6: h6] + [apply_rung(r) for r in rungs]
+            sel = jnp.where(
+                n_fb == 0,
+                0,
+                1 + sum(((n_fb > r).astype(jnp.int32) for r in rungs[:-1]),
+                        start=jnp.int32(0)),
             )
-            in_hits, out_hits, fb = res[0:3], res[3:6], res[6]
+            hits6 = jax.lax.switch(sel, branches, hits6)
+            return hits6 + (fb,)
+
+        def all_dead(_):
+            # Aggregate-AND-zero short circuit for the whole batch (the
+            # adversarial / default-deny cold shape): no candidate
+            # gather, no fallback — straight to the default verdicts.
+            return (BIGS,) * 6 + (no_fb,)
+
+        res = jax.lax.cond(
+            ((nc_in > 0) | (nc_out > 0)).any(), phase2, all_dead, None
+        )
+        in_hits, out_hits, fb = res[0:3], res[3:6], res[6]
 
         skip = ((nc_in == 0) & (nc_out == 0)).astype(jnp.int32)
         cand = jnp.maximum(nc_in, nc_out)
@@ -1860,390 +1794,6 @@ def _classify_pruned(
     }
 
 
-# ---------------------------------------------------------------------------
-# One-kernel fast path (round 8): ONE pallas pass per batch that keeps
-# per-lane state in VMEM end-to-end — flow-cache probe (key compare +
-# freshness + generation against the XLA-gathered cache row), aggregate
-# AND with the zero-AND short-circuit, double-buffered candidate-
-# superblock DMA (half-block j+1's aggregate AND + DMA issue overlap the
-# wait on half-block j's candidates), first-match via the SHARED
-# _phase_first_from_base discipline, and (single-chip `resolve` variant)
-# verdict resolution + cached/fresh output merge + cache-commit ROW
-# packing in the same pass.  The commit SCATTERS stay XLA (study note (d):
-# Mosaic has no arbitrary-VMEM-scatter path and the cache exceeds VMEM)
-# but their input rows are kernel outputs — the inter-stage HBM
-# materializations (probe image, LB image, classify image, packed rows)
-# are gone.  Under rule-axis sharding (`resolve=False`) the kernel emits
-# GLOBAL hit indices for the pmin seam and resolution runs post-allreduce,
-# the same physics as every other sharded first-match path.
-# ---------------------------------------------------------------------------
-
-_OP_HB = 64  # lane half-block: the candidate-DMA double-buffer granule
-
-
-@lru_cache(maxsize=16)
-def _onepass_call(b, s_in, s_out, k_in, k_out, in_phases, out_phases,
-                  svcref, resolve, timeouts, n_slots, pref_mask, interpret):
-    """Build the one-pass kernel (the `_pruned_consumer_call` seam grown
-    three stages: probe in, candidate gather in-kernel via DMA, resolve/
-    commit-pack out).  Static key = every shape/phase/flag, so a
-    prune-budget retune (k_in/k_out move on PRUNE_LADDER) is a meta-only
-    swap hitting this cache — one compiled variant per rung, no storms.
-
-    Inputs (per grid tile of _FUSE_TB lanes; all i32/u32):
-      pkt  (tb, 8)  [src_f, dst_f, proto, sport, dport, pp, 0, 0]
-      kr   (tb, 4)  gathered flow-cache key row
-      prb  (tb, 4)  [ts, iso_in, iso_out, 0]
-      mrow (tb, 4)  gathered flow-cache meta row
-      msk  (tb, 4)  [valid, no_commit, fb_force, 0]
-      lb   (tb, 8)  [svc_idx, no_ep, dnat_ip_f, dnat_port, snat, dsr, 0, 0]
-      agg x6 (tb, s) aggregate rows (delta-agg patched, miss-index-masked)
-      iv   (tb, 8)  SMEM interval rows [in_at, in_peer, in_svc, out_at,
-                    out_peer, out_svc, svc_ref, 0]
-      scal (1, 4)   SMEM [now, gen_w, w0_in, w0_out]
-      inc2 x6       ANY (rows*S, AGG_BLOCK) u32 — the DMA source tables
-      act  x2       ANY (w*32,) i32 (resolve variant only)
-
-    Outputs: resolve -> (main (b,16), keys8 (b,8), meta8 (b,8), aux (b,4));
-    hits-only -> (hits8 (b,8), aux (b,4)).  main columns:
-    [code, rule_in, rule_out, svc, dnat_ip_f, dnat_port, snat, dsr,
-     committed, rev_ins, rev_slot, hit, est, rpl, ins, 0]; aux columns:
-    [skip, fb, cand, 0]."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from ..compiler.compile import ACT_REJECT
-    from ..models.pipeline import (DSR_BIT, GEN_ETERNAL, MISS, REPLY_BIT,
-                                   _pack_meta1, _pack_rules, _unpack_meta1,
-                                   _unpack_rules, entry_timeout)
-    from . import hashing
-
-    tb = _FUSE_TB
-    hb = _OP_HB
-    nh = tb // hb
-    ke_in = min(k_in, s_in)
-    ke_out = min(k_out, s_out)
-    # (S, Ke, direction) per dim slot; slot 6 = the svcref second svc row.
-    dims = [(s_in, ke_in, 0)] * 3 + [(s_out, ke_out, 1)] * 3
-    if svcref:
-        dims = dims + [(s_out, ke_out, 1)]
-    n_inc = len(dims)
-
-    def kernel(*refs):
-        (pkt, kr, prb, mrow, msk, lb,
-         g_ia, g_ip, g_is, g_oa, g_op, g_os, iv, scal) = refs[:14]
-        inc2 = refs[14:14 + n_inc]
-        pos = 14 + n_inc
-        if resolve:
-            act_in, act_out = refs[pos:pos + 2]
-            pos += 2
-        n_out = 4 if resolve else 2
-        outs = refs[pos:pos + n_out]
-        scratch = refs[pos + n_out:]
-        bufs = [[scratch[2 * d + s] for s in range(2)] for d in range(n_inc)]
-        sp = 2 * n_inc
-        cidx = [[scratch[sp + 2 * dirn + s] for s in range(2)]
-                for dirn in range(2)]
-        sp += 4
-        cvld = [[scratch[sp + 2 * dirn + s] for s in range(2)]
-                for dirn in range(2)]
-        sp += 4
-        sems = [scratch[sp], scratch[sp + 1]]
-        sp += 2
-        if resolve:
-            hbuf, abuf, asem = scratch[sp:sp + 3]
-
-        # ---- tile-wide per-lane state (VMEM vectors) ----------------------
-        src_f = pkt[:, 0]
-        dst_f = pkt[:, 1]
-        proto = pkt[:, 2]
-        sport = pkt[:, 3]
-        dport = pkt[:, 4]
-        pp = pkt[:, 5]
-        now = scal[0, 0]
-        gen_w = scal[0, 1]
-        w0_in = scal[0, 2]
-        w0_out = scal[0, 3]
-        valid = msk[:, 0] != 0
-        nc = msk[:, 1] != 0
-        fb_force = msk[:, 2] != 0
-
-        # Flow-cache probe: the _cache_lookup discipline on the gathered
-        # row (key compare + generation + per-state freshness), in VMEM.
-        krr = kr[:]
-        kpg = krr[:, 3]
-        pg_cur = proto | 0x100 | (gen_w << 9)
-        pg_est = proto | 0x100 | (GEN_ETERNAL << 9)
-        pg_rpl = pg_est | REPLY_BIT
-        key_hit = ((krr[:, 0] == src_f) & (krr[:, 1] == dst_f)
-                   & (krr[:, 2] == pp)
-                   & ((kpg == pg_cur) | (kpg == pg_est) | (kpg == pg_rpl)))
-        mr = mrow[:]
-        ts = prb[:, 0]
-        iso_in = prb[:, 1]
-        iso_out = prb[:, 2]
-        if timeouts[0] == timeouts[1] == timeouts[2] == timeouts[3]:
-            timeout = timeouts[1]
-        else:
-            timeout = entry_timeout((mr[:, 3] >> 29) & 1, proto, timeouts)
-        fresh = (now - ts) <= timeout
-        hit = key_hit & fresh & valid
-        est = hit & ((kpg == pg_est) | (kpg == pg_rpl))
-        rpl = hit & (kpg == pg_rpl)
-        miss = ~hit & valid
-
-        # Aggregate AND + zero-AND short circuit (non-miss lanes gathered
-        # row 0 — masked dead here so they spawn no candidates).
-        g_in = g_ia[:] & g_ip[:] & g_is[:]
-        g_out = g_oa[:] & g_op[:] & g_os[:]
-        nc_in = jnp.where(
-            miss, (g_in != jnp.uint32(0)).sum(axis=1, dtype=jnp.int32), 0)
-        nc_out = jnp.where(
-            miss, (g_out != jnp.uint32(0)).sum(axis=1, dtype=jnp.int32), 0)
-        skip = miss & (nc_in == 0) & (nc_out == 0)
-        fb = miss & ((nc_in > ke_in) | (nc_out > ke_out) | fb_force)
-        cand = jnp.maximum(nc_in, nc_out)
-
-        # ---- candidate selection + double-buffered DMA per half-block -----
-        def select(j, slot):
-            """Aggregate top-K for half-block j -> cidx/cvld[.][slot]."""
-            off = j * hb
-            miss_h = miss[off:off + hb]
-            for dirn, (g, S, K) in enumerate(
-                    ((g_in, s_in, ke_in), (g_out, s_out, ke_out))):
-                gh = g[off:off + hb]
-                score = jnp.where(
-                    (gh != jnp.uint32(0)) & miss_h[:, None],
-                    jax.lax.broadcasted_iota(jnp.int32, (hb, S), 1), S)
-                neg, _i = jax.lax.top_k(-score, K)
-                c = -neg  # ascending superblock ids, S = fill
-                cvld[dirn][slot][:, :K] = (c < S).astype(jnp.int32)
-                cidx[dirn][slot][:, :K] = jnp.minimum(c, S - 1)
-
-        def dma_half(j, slot, start):
-            """Issue (or wait) the candidate-row copies for half j."""
-            off = j * hb
-
-            def lane_body(i, _):
-                for d, (S, K, dirn) in enumerate(dims):
-                    ivd = iv[off + i, d]
-                    for k in range(K):
-                        row = ivd * S + cidx[dirn][slot][i, k]
-                        cp = pltpu.make_async_copy(
-                            inc2[d].at[row], bufs[d][slot].at[i, k],
-                            sems[slot])
-                        if start:
-                            cp.start()
-                        else:
-                            cp.wait()
-                return 0
-
-            jax.lax.fori_loop(0, hb, lane_body, 0)
-
-        def first_match(j, slot):
-            """Candidate AND + the shared per-element-base first-match."""
-            off = j * hb
-
-            def mats(d3, dirn, S, K, w0):
-                ca = bufs[d3][slot][:]
-                cpr = bufs[d3 + 1][slot][:]
-                cs = bufs[d3 + 2][slot][:]
-                if svcref and dirn == 1:
-                    cs = cs | bufs[6][slot][:]
-                # Fill candidates contribute nothing: zero ONE dim.
-                ca = jnp.where(cvld[dirn][slot][:, :K][:, :, None] != 0, ca,
-                               jnp.uint32(0))
-                m = (ca & cpr & cs).reshape(hb, K * AGG_BLOCK)
-                jj = jnp.arange(AGG_BLOCK, dtype=jnp.int32)[None, None, :]
-                base = ((w0 + cidx[dirn][slot][:, :K][:, :, None] * AGG_BLOCK
-                         + jj) * 32).reshape(hb, K * AGG_BLOCK)
-                return m, base
-
-            m_i, b_i = mats(0, 0, s_in, ke_in, w0_in)
-            m_o, b_o = mats(3, 1, s_out, ke_out, w0_out)
-            return (_phase_first_from_base(m_i, b_i, in_phases)
-                    + _phase_first_from_base(m_o, b_o, out_phases))
-
-        def emit(j, hits6):
-            """Resolve + merge + commit-row pack for half j (resolve
-            variant) or raw hit emission (sharded variant)."""
-            off = j * hb
-            sl = slice(off, off + hb)
-            if not resolve:
-                outs[0][sl, :] = jnp.stack(
-                    list(hits6) + [jnp.zeros(hb, jnp.int32)] * 2, axis=1)
-                outs[1][sl, :] = jnp.stack(
-                    [skip[sl].astype(jnp.int32), fb[sl].astype(jnp.int32),
-                     cand[sl], jnp.zeros(hb, jnp.int32)], axis=1)
-                return
-            i0, ik, ib, o0, ok_, ob = hits6
-            # Per-lane action DMA for the deciding phase-0/baseline rules
-            # (the _resolve gathers, fetched from the ANY-space tables).
-            na = act_in.shape[0]
-            nb = act_out.shape[0]
-            hbuf[:] = jnp.stack([
-                jnp.clip(i0, 0, na - 1), jnp.clip(ib, 0, na - 1),
-                jnp.clip(o0, 0, nb - 1), jnp.clip(ob, 0, nb - 1)], axis=1)
-
-            def act_loop(start):
-                def body(i, _):
-                    for k, ref in ((0, act_in), (1, act_in),
-                                   (2, act_out), (3, act_out)):
-                        cp = pltpu.make_async_copy(
-                            ref.at[pl.ds(hbuf[i, k], 1)],
-                            abuf.at[i, pl.ds(k, 1)], asem)
-                        if start:
-                            cp.start()
-                        else:
-                            cp.wait()
-                    return 0
-
-                jax.lax.fori_loop(0, hb, body, 0)
-
-            act_loop(True)
-            act_loop(False)
-
-            in_code, in_rule = _resolve_from_actions(
-                abuf[:, 0], abuf[:, 1], (i0, ik, ib), iso_in[sl])
-            out_code, out_rule = _resolve_from_actions(
-                abuf[:, 2], abuf[:, 3], (o0, ok_, ob), iso_out[sl])
-            cls_code = jnp.where(out_code != ACT_ALLOW, out_code, in_code)
-
-            # LB/no-endpoint overlay (SvcReject precedes the policy
-            # tables) -> the fresh (slow-path) image of each lane.
-            no_ep = lb[sl, 1] != 0
-            f_code = jnp.where(no_ep, ACT_REJECT, cls_code).astype(jnp.int32)
-            f_ri = jnp.where(no_ep, MISS, in_rule)
-            f_ro = jnp.where(no_ep, MISS, out_rule)
-            svc_idx = lb[sl, 0]
-            dnat_ip = lb[sl, 2]
-            dnat_port = lb[sl, 3]
-            snat_m = lb[sl, 4]
-            dsr_m = lb[sl, 5]
-
-            # Cached image decode + the hit/miss/default merge — the
-            # fast-path output images, produced in the same pass.
-            h_h = hit[sl]
-            m_h = miss[sl]
-            r_h = rpl[sl]
-            c_code, c_svc, c_dport = _unpack_meta1(mr[sl, 1])
-            c_dnat = mr[sl, 0]
-            c_ri, c_ro = _unpack_rules(mr[sl, 2])
-            c_snat = (mr[sl, 3] >> 31) & 1
-            c_dsr = (mr[sl, 3] >> 30) & 1
-            o_code = jnp.where(h_h, c_code,
-                               jnp.where(m_h, f_code, ACT_ALLOW))
-            o_svc = jnp.where(h_h, c_svc, jnp.where(m_h, svc_idx, MISS))
-            o_dnat = jnp.where(h_h, c_dnat,
-                               jnp.where(m_h, dnat_ip, dst_f[sl]))
-            o_dport = jnp.where(h_h, c_dport,
-                                jnp.where(m_h, dnat_port, dport[sl]))
-            o_ri = jnp.where(h_h, c_ri, jnp.where(m_h, f_ri, MISS))
-            o_ro = jnp.where(h_h, c_ro, jnp.where(m_h, f_ro, MISS))
-            o_snat = jnp.where(h_h & ~r_h, c_snat,
-                               jnp.where(m_h, snat_m, 0))
-            o_dsr = jnp.where(h_h & ~r_h, c_dsr, jnp.where(m_h, dsr_m, 0))
-
-            committed = m_h & (f_code == ACT_ALLOW) & ~nc[sl]
-            ins = m_h & ~nc[sl]
-            rev_ins = ins & committed & (dsr_m == 0)
-
-            # Commit-row packing (forward + reply-direction conntrack
-            # rows) — the scatter consumes these verbatim.
-            egen = jnp.where(committed, GEN_ETERNAL, gen_w)
-            pg_ins = proto[sl] | 0x100 | (egen << 9)
-            m1 = _pack_meta1(f_code, svc_idx, dnat_port)
-            rules_p = _pack_rules(f_ri, f_ro)
-            pref_col = jnp.full((hb,), 0, jnp.int32) + (now & pref_mask)
-            zcol = (pref_col
-                    | jnp.where(snat_m > 0, REPLY_BIT, 0)
-                    | jnp.where(dsr_m > 0, DSR_BIT, 0))
-            raw = lambda x: x ^ jnp.int32(-(2 ** 31))  # noqa: E731
-            rev_h = hashing.flow_hash(
-                raw(dnat_ip), raw(src_f[sl]), proto[sl], dnat_port,
-                sport[sl], xp=jnp)
-            rev_slot = (rev_h & jnp.uint32(n_slots - 1)).astype(jnp.int32)
-            rev_pg = proto[sl] | 0x100 | (GEN_ETERNAL << 9) | REPLY_BIT
-            outs[0][sl, :] = jnp.stack(
-                [o_code, o_ri, o_ro, o_svc, o_dnat, o_dport, o_snat, o_dsr,
-                 committed.astype(jnp.int32), rev_ins.astype(jnp.int32),
-                 rev_slot, h_h.astype(jnp.int32), est[sl].astype(jnp.int32),
-                 r_h.astype(jnp.int32), ins.astype(jnp.int32),
-                 jnp.zeros(hb, jnp.int32)], axis=1)
-            outs[1][sl, :] = jnp.stack(
-                [src_f[sl], dst_f[sl], pp[sl], pg_ins,
-                 dnat_ip, src_f[sl], (dnat_port << 16) | sport[sl], rev_pg],
-                axis=1)
-            outs[2][sl, :] = jnp.stack(
-                [dnat_ip, m1, rules_p, zcol,
-                 dst_f[sl], _pack_meta1(f_code, svc_idx, dport[sl]),
-                 rules_p, pref_col], axis=1)
-            outs[3][sl, :] = jnp.stack(
-                [skip[sl].astype(jnp.int32), fb[sl].astype(jnp.int32),
-                 cand[sl], jnp.zeros(hb, jnp.int32)], axis=1)
-
-        # Software pipeline: select+issue half 0, then for each half j
-        # overlap half j+1's aggregate AND / top-K / DMA issue with the
-        # wait on half j's candidate copies — the double buffer.
-        select(0, 0)
-        dma_half(0, 0, start=True)
-        for j in range(nh):
-            if j + 1 < nh:
-                select(j + 1, (j + 1) % 2)
-                dma_half(j + 1, (j + 1) % 2, start=True)
-            dma_half(j, j % 2, start=False)
-            emit(j, first_match(j, j % 2))
-
-    grid = (b // tb,)
-    tile = lambda w: pl.BlockSpec((tb, w), lambda i: (i, 0))  # noqa: E731
-    in_specs = (
-        [tile(8), tile(4), tile(4), tile(4), tile(4), tile(8)]
-        + [tile(s_in)] * 3 + [tile(s_out)] * 3
-        + [pl.BlockSpec((tb, 8), lambda i: (i, 0),
-                        memory_space=pltpu.SMEM),
-           pl.BlockSpec((1, 4), lambda i: (0, 0),
-                        memory_space=pltpu.SMEM)]
-        + [pl.BlockSpec(memory_space=pl.ANY)] * n_inc
-        + ([pl.BlockSpec(memory_space=pl.ANY)] * 2 if resolve else [])
-    )
-    if resolve:
-        out_shape = (jax.ShapeDtypeStruct((b, 16), jnp.int32),
-                     jax.ShapeDtypeStruct((b, 8), jnp.int32),
-                     jax.ShapeDtypeStruct((b, 8), jnp.int32),
-                     jax.ShapeDtypeStruct((b, 4), jnp.int32))
-        out_specs = (pl.BlockSpec((tb, 16), lambda i: (i, 0)),
-                     tile(8), tile(8), tile(4))
-    else:
-        out_shape = (jax.ShapeDtypeStruct((b, 8), jnp.int32),
-                     jax.ShapeDtypeStruct((b, 4), jnp.int32))
-        out_specs = (tile(8), tile(4))
-    scratch = []
-    for (S, K, _dirn) in dims:
-        for _s in range(2):
-            scratch.append(pltpu.VMEM((hb, K, AGG_BLOCK), jnp.uint32))
-    for _dirn in range(2):
-        for _s in range(2):
-            scratch.append(pltpu.VMEM((hb, max(ke_in, ke_out)), jnp.int32))
-    for _dirn in range(2):
-        for _s in range(2):
-            scratch.append(pltpu.VMEM((hb, max(ke_in, ke_out)), jnp.int32))
-    scratch += [pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA]
-    if resolve:
-        scratch += [pltpu.VMEM((hb, 4), jnp.int32),
-                    pltpu.VMEM((hb, 4), jnp.int32),
-                    pltpu.SemaphoreType.DMA]
-    return pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-        interpret=interpret,
-        name="classify_onepass",
-    )
-
-
 def flip_ips(a: np.ndarray) -> np.ndarray:
     """Host helper: u32 IP array -> sign-flipped i32 (kernel input layout)."""
     return iputil.flip_u32(a)
@@ -2253,7 +1803,7 @@ def flip_ips(a: np.ndarray) -> np.ndarray:
 # the big incidence tensors stay runtime inputs instead of baked-in constants.
 _classify_jit = jax.jit(
     classify_batch,
-    static_argnames=("meta", "hit_combine", "fused", "summary_only"),
+    static_argnames=("meta", "hit_combine", "fused"),
 )
 
 

@@ -3,10 +3,9 @@ layer names its sections without depending on the observability plane
 (`observability/tracing` re-exports both names beside STEP_PHASES).
 
 `jax.named_scope` names of the step program (models/pipeline,
-models/forwarding, ops/match), the cut models/profile.PHASE_CHAIN makes
-by differencing masked programs: every op of the step lowers under a
-path of these, nested as listed (`probe`/`refresh`/`assemble` inside
-`fast_path`; the round-loop scopes and the `classify.*` stages inside
+models/forwarding, ops/match) — how the device's layers are told apart
+in a profiler trace: every op of the step lowers under a path of these,
+nested as listed (`probe`/`refresh`/`assemble` inside `fast_path`; the round-loop scopes and the `classify.*` stages inside
 `miss_detect`; `eviction_scan` inside `cache_commit`; `egress`, the
 packing of the served step's outputs into one record, after them all).
 The ONE place the scope names are declared: call sites go through

@@ -760,13 +760,6 @@ class MeshDatapath(TpuflowDatapath):
                             source_rate=source_rate,
                             source_burst=source_burst)
 
-    # -- unsupported single-chip surfaces ------------------------------------
-
-    def profile(self, batch, fresh=None, **kw) -> dict:
-        raise NotImplementedError(
-            "profile() is a single-chip surface; the multichip regime is "
-            "measured by bench.py's multichip section")
-
     # -- the sharded step ----------------------------------------------------
 
     def _step(self, batch: PacketBatch, now: int, valid=None) -> StepResult:

@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache for the repo's entry scripts.
 
 One helper, called by every script that compiles at deployment size
-(chip_smoke.py, bench*.py, dissemination/agent_proc.py) before its first
+(chip_smoke.py, dissemination/agent_proc.py) before its first
 trace.  Library modules set nothing on import.  The tier-1 CPU cache stays
 where tests/conftest.py puts it (outside the checkout, so it is never
 copied to a chip machine along with the tree).

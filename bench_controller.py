@@ -4,7 +4,7 @@ xLargeScale shape (networkpolicy_controller_perf_test.go:46-52 —
 25k namespaces / 100k pods / 75k NetworkPolicies; reference: 5.84-6.42 s,
 1522-1708 MB, Go).
 
-Prints ONE json line like bench.py.  vs_baseline is wall / 6.13s (the
+Prints ONE json line.  vs_baseline is wall / 6.13s (the
 midpoint of the reference's recorded range) — LOWER is better here, so the
 ratio is reported as reference_time / our_time (>1 means faster than the
 reference).

@@ -9,9 +9,6 @@ import copy
 import pytest
 
 import chip_smoke as cs
-from antrea_tpu.config import ConfigError
-from antrea_tpu.datapath import make_datapath
-from antrea_tpu.models import pipeline as pl
 
 TINY = cs.Sizes(n_rules=120, n_nodes=4, pods_per_node=8, n_services=12,
                 batch=192, n_flows=64, twin_lanes=192, flow_slots=1 << 12,
@@ -31,11 +28,18 @@ def test_rehearsal_default_and_staged_consumer(capsys):
     assert '"claim": null' in log.splitlines()[-1]
 
 
+def test_rehearsal_fused_pruned(capsys):
+    """Catches `fused` + `prune_budget` building anything but the Pallas
+    consumer over the candidate matrices, or that engine leaving the twin."""
+    cs.run(TINY, want="cpu", engines=_engines("fused_pruned"))
+    log = capsys.readouterr().out
+    assert log.count('"lanes_compared": 384, "lanes_mismatched": 0') == 1
+    assert '"fused_pruned": "served"' in log.splitlines()[-1]
+
+
 @pytest.mark.slow
-def test_rehearsal_pruned_and_onepass():
-    """Off-TPU the interpreter runs the one-pass kernel, so the engine the
-    chip refuses is built and held to the twin like the others."""
-    cs.run(TINY, want="cpu", engines=_engines("pruned", "onepass"))
+def test_rehearsal_pruned():
+    cs.run(TINY, want="cpu", engines=_engines("pruned"))
 
 
 def test_gate_names_the_backend_before_building(monkeypatch):
@@ -55,18 +59,3 @@ def test_comparison_finds_a_flipped_lane_and_a_misattributed_denial():
                   if want.code[i] != 0 and not want.committed[i])
     got.ingress_rule[denied] = "someone-else"
     assert cs.mismatched_lanes(got, want).tolist() == sorted({7, denied})
-
-
-def test_refused_kernel_is_a_typed_error_quoting_the_compiler():
-    """What REFUSED_ON_TPU relies on: where the operands live on a TPU the
-    one-pass knob set is probed through the compiler at construction and a
-    refusal surfaces as ConfigError.  Here the rules' platform is forced to
-    "tpu" on the CPU backend, whose compiler refuses any non-interpreted
-    Pallas kernel."""
-    dp = make_datapath("tpuflow", flow_slots=1 << 12, aff_slots=1 << 10,
-                       fused=True, prune_budget=4)
-    pl.require_onepass_lowers(dp._meta, dp._drs)  # cpu operands: no probe
-    on_tpu = dp._meta._replace(
-        match=dp._meta.match._replace(platform="tpu"))
-    with pytest.raises(ConfigError, match="does not lower on"):
-        pl.require_onepass_lowers(on_tpu, dp._drs)

@@ -1,9 +1,10 @@
-"""One-kernel fast path (ISSUE 15 tentpole, ops/match round 8) + its
-satellites: bitwise fused-vs-staged-vs-oracle parity across the fallback,
-svcref, delta-slot, mesh and async-drain regimes; no-pallas HLO pinning at
+"""fused=True over the pruned classify (the Pallas consumer of the
+candidate matrices, ops/match._pruned_consumer_call): bitwise
+fused-vs-XLA-scan-vs-oracle parity across the fallback, svcref,
+delta-slot, mesh and async-drain regimes; no-pallas HLO pinning at
 fused=False; canary+audit certification of a fused instance; the
-interpret-mode CPU smoke; the spill-retry prune-accounting dedupe; the
-second-chance replacement seed; and per-source admission rate limiting."""
+spill-retry prune-accounting dedupe; the second-chance replacement seed;
+and per-source admission rate limiting."""
 
 import numpy as np
 import pytest
@@ -54,11 +55,8 @@ def _assert_result_parity(a, b, ctx, est=True):
 
 def _assert_state_parity(a, b, ctx):
     """Commit-row parity: the two engines' flow caches must be bitwise
-    identical — the one-pass kernel's packed rows and the staged path's
-    XLA-packed rows land the same words in the same slots.  Row N (the
-    dump row, the masked-scatter junk target no lookup ever reads) is
-    excluded: its junk content legitimately differs between the round
-    structures."""
+    identical.  Row N (the dump row, the masked-scatter junk target no
+    lookup ever reads) is excluded."""
     for name in ("keys", "meta", "ts"):
         av = np.asarray(getattr(a._state.flow, name))[:-1]
         bv = np.asarray(getattr(b._state.flow, name))[:-1]
@@ -71,18 +69,16 @@ def _assert_state_parity(a, b, ctx):
 
 
 def test_fused_step_parity_steady_cold_fallback_and_delta():
-    """A multi-superblock world at K=1 exercises the in-kernel candidate
-    path AND the pow2-rung fallback; the fused step must be bitwise
-    equal to the staged pruned engine (outputs AND commit rows) and to
-    the scalar oracle, cold (all-miss) and steady (all-hit) alike.
+    """A multi-superblock world at K=1 exercises the candidate path AND
+    the pow2-rung fallback; the fused step must be bitwise equal to the
+    XLA-scan pruned engine (outputs AND commit rows) and to the scalar
+    oracle, cold (all-miss) and steady (all-hit) alike.
 
     The SAME engines then take pending membership deltas (one world, one
     compile set — the tier-1 wall-clock discipline): SET slots patch the
-    aggregate rows conservatively, but the in-kernel candidate words are
-    unpatched by design — every lane a slot's range touches must take
-    the full-width fallback (where _patch_rows applies the delta
-    exactly), bitwise on traffic aimed straight at the added/removed
-    members."""
+    aggregate rows conservatively and the candidate words exactly
+    (_patch_cand), bitwise on traffic aimed straight at the
+    added/removed members."""
     cluster = gen_cluster(2500, seed=12)
     fd = _fused(cluster.ps, prune=1, delta_slots=16)
     sd = _staged(cluster.ps, prune=1, delta_slots=16)
@@ -125,22 +121,17 @@ def test_fused_step_parity_steady_cold_fallback_and_delta():
             break
     batch = PacketBatch.from_packets(pkts[:tr.size])
     assert batch.size == tr.size  # shares the steady step's compile
-    fb0 = fd.prune_stats()["fallbacks_total"]
     rf, rs, ro = (fd.step(batch, now=10), sd.step(batch, now=10),
                   od.step(batch, now=10))
     _assert_result_parity(rf, rs, "delta staged")
     _assert_result_parity(rf, ro, "delta oracle")
     _assert_state_parity(fd, sd, "delta state")
-    # Every lane touched a delta slot's range -> all were fallback-forced.
-    assert fd.prune_stats()["fallbacks_total"] - fb0 == batch.size
 
 
 def test_fused_churn_and_teardown_parity():
     """Churn shape: fresh flows every step plus FIN teardown of
-    established ones — the commit/reclaim/teardown interleavings the
-    one-pass kernel's packed rows must reproduce bitwise.  (Runs on the
-    interpret-smoke world so the fused compile is shared across the
-    tier-1 suite.)"""
+    established ones — the commit/reclaim/teardown interleavings must
+    reproduce bitwise."""
     cluster = gen_cluster(600, seed=3)
     fd = _fused(cluster.ps)
     sd = _staged(cluster.ps)
@@ -153,7 +144,7 @@ def test_fused_churn_and_teardown_parity():
 
 def test_fused_svcref_parity():
     """toServices (svcref) worlds OR a second aggregate row and a second
-    in-kernel candidate DMA — frontends of the referenced Service drop,
+    candidate gather — frontends of the referenced Service drop,
     direct-to-endpoint traffic does not, bitwise vs the oracle."""
     import test_toservices as t
 
@@ -179,7 +170,7 @@ def test_fused_svcref_parity():
 
 def test_fused_mesh_parity():
     """The rule-sharded mesh: the kernel emits GLOBAL hits for the pmin
-    seam (resolve/commit-pack post-allreduce) — verdict + attribution
+    seam — verdict + attribution
     parity vs the scalar oracle on (data x rule) = (2, 2).  (The oracle
     is the comparator here — fused-vs-staged parity is pinned by the
     single-chip regimes above, and the oracle twin costs no second XLA
@@ -220,7 +211,7 @@ def test_fused_mesh_parity():
 
 
 def test_fused_async_drain_parity():
-    """The async engine's coalesced drains run the one-pass kernel
+    """The async engine's coalesced drains run the fused consumer
     (miss_chunk == the popped block); verdict + established parity vs
     the oracle twin across admit -> drain -> re-hit."""
     cluster = gen_cluster(600, seed=3)
@@ -238,15 +229,14 @@ def test_fused_async_drain_parity():
 
 
 # ---------------------------------------------------------------------------
-# HLO pinning at fused=False + interpret smoke
+# HLO pinning at fused=False
 # ---------------------------------------------------------------------------
 
 
 def test_step_hlo_no_pallas_and_identical_with_fused_disabled():
-    """fused=False must stay the staged program: (1) its lowered step
-    carries NO pallas custom-call, and (2) an explicit onepass=False over
-    fused+pruned knobs (the bench_profile --mode prune contract) lowers
-    BIT-IDENTICALLY to the plain staged pruned instance."""
+    """fused=False must stay the XLA-scan program: spelling the default
+    knobs out lowers BIT-IDENTICALLY to the plain pruned instance, and
+    fused=True lowers a different one."""
     cluster = gen_cluster(300, seed=7)
     cps = compile_policy_set(cluster.ps)
     from antrea_tpu.compiler.services import compile_services
@@ -264,34 +254,11 @@ def test_step_hlo_no_pallas_and_identical_with_fused_disabled():
                 meta=step.meta).as_text()
 
     staged = lowered(prune_budget=2)
-    # Explicit onepass=False / default knobs lower BIT-IDENTICALLY to the
-    # plain staged pruned program (the fused=False contract; the vs-HEAD
-    # half of the acceptance bar was verified against the pre-PR tree).
-    pinned_off = lowered(prune_budget=2, fused=False, onepass=False)
-    assert pinned_off == staged
+    assert lowered(prune_budget=2, fused=False) == staged
     assert lowered(prune_budget=2, second_chance=False) == staged
-    # The one-pass program is genuinely different (on the CPU tier the
-    # kernel lowers through interpret mode, so the evidence is program
-    # inequality + the scatter structure, not a custom-call marker).
-    fused = lowered(prune_budget=2, fused=True)
-    assert fused != staged
-
-
-def test_fused_interpret_smoke():
-    """The whole one-pass kernel — probe, DMA double-buffer, first
-    match, resolve, commit-row pack — executes under pallas interpret
-    mode on the CPU tier (the conftest platform), end to end."""
-    assert jax.devices()[0].platform == "cpu"
-    cluster = gen_cluster(600, seed=3)
-    fd = _fused(cluster.ps)
-    assert fd._meta.onepass
-    tr = gen_traffic(cluster.pod_ips, batch=96, seed=4)
-    r = fd.step(tr, now=1)
-    assert len(list(r.code)) == 96
-    st = fd.prune_stats()
-    assert st["classified_total"] > 0
-    r2 = fd.step(tr, now=2)
-    assert int(np.asarray(r2.est).sum()) > 0  # commits landed
+    # On the CPU tier the kernel lowers through interpret mode, so the
+    # evidence is program inequality, not a custom-call marker.
+    assert lowered(prune_budget=2, fused=True) != staged
 
 
 # ---------------------------------------------------------------------------
@@ -302,15 +269,13 @@ def test_fused_interpret_smoke():
 def test_canary_and_audit_certify_fused_instance():
     """The eager twin walks carry the fused meta: a fused instance's
     install canary and a full audit sweep certify the serving
-    configuration (zero mismatches, zero divergences).  (Same world and
-    shapes as the interpret smoke — the serving-step compile is shared;
-    the planes themselves run eager twin walks.)"""
+    configuration (zero mismatches, zero divergences)."""
     cluster = gen_cluster(600, seed=3)
     dp = TpuflowDatapath(cluster.ps, miss_chunk=32, fused=True,
                          prune_budget=2, flow_slots=1 << 10,
                          aff_slots=1 << 6, canary_probes=16,
                          flightrec_slots=64, realization_slots=16)
-    assert dp._meta.onepass and dp._meta.fused
+    assert dp._meta.fused and dp._meta.match.prune_budget == 2
     tr = gen_traffic(cluster.pod_ips, batch=96, seed=10)
     dp.step(tr, now=1)
     gen0 = dp.generation
@@ -350,7 +315,7 @@ def test_canary_and_audit_certify_fused_instance():
 @pytest.mark.slow
 def test_fused_autotune_retune_is_meta_only():
     """A PruneAutotuner retune under the fused path swaps K in the meta
-    (a new jit-cached one-pass variant per rung) — serving stays
+    (a new jit-cached step variant per rung) — serving stays
     parity-correct across the move."""
     cluster = gen_cluster(2500, seed=2)
     fd = _fused(cluster.ps, prune=1, autotune_prune=True)
@@ -364,7 +329,7 @@ def test_fused_autotune_retune_is_meta_only():
         fd.step(tr_t, now=1 + t)
         sd.step(tr_t, now=1 + t)
     # The K=1 fallback pressure retunes UP, and the then-clean K=2 rung
-    # retunes back DOWN — both moves serve through jit-cached one-pass
+    # retunes back DOWN — both moves serve through jit-cached step
     # variants (every move is a meta-only swap).
     assert fd.prune_stats()["retunes_total"] > 0, (
         "fallback pressure never retuned K")
@@ -380,64 +345,14 @@ def test_fused_autotune_retune_is_meta_only():
 
 
 # ---------------------------------------------------------------------------
-# Profile mode + config errors
+# Config errors
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_profile_fused_mode_both_engines():
-    from antrea_tpu.models.profile import FUSED_PHASE_CHAIN
-
-    cluster = gen_cluster(400, seed=5)
-    kw = dict(flow_slots=1 << 8, aff_slots=1 << 4, canary_probes=0,
-              flightrec_slots=0, realization_slots=0)
-    fd = TpuflowDatapath(cluster.ps, miss_chunk=32, fused=True,
-                         prune_budget=2, **kw)
-    od = OracleDatapath(cluster.ps, fused=True, prune_budget=2, **kw)
-    hot = gen_traffic(cluster.pod_ips, batch=64, seed=6)
-    fresh = gen_traffic(cluster.pod_ips, batch=64, seed=7)
-    prof = fd.profile(hot, fresh, n_new=16, k_small=1, k_big=2, repeats=1,
-                      mode="fused")
-    names = [n for n, _m in FUSED_PHASE_CHAIN]
-    assert list(prof["phases_s"].keys()) == names
-    assert prof["mode"] == "fused" and prof["prune_budget"] == 2
-    assert abs(sum(prof["phases_s"].values()) - prof["total_s"]) < 1e-9
-    po = od.profile(hot, fresh, mode="fused")
-    assert po["mode"] == "fused"
-    assert set(po["phases_s"]) == {"fused_fast_path", "fused_onepass",
-                                   "fused_commit_residual"}
-    # Both engines refuse the mode on a non-one-pass instance.
-    sd = TpuflowDatapath(cluster.ps, miss_chunk=32, prune_budget=2, **kw)
-    on = OracleDatapath(cluster.ps, prune_budget=2, **kw)
-    for dp in (sd, on):
-        with pytest.raises(ValueError):
-            dp.profile(hot, fresh, mode="fused")
-
-
-def test_profile_fused_mode_surface():
-    """Tier-1 shard of the profile surface (the full device-timed chain
-    runs in the slow tier): the scalar twin's fused names and both
-    engines' refusal on a non-one-pass instance."""
-    cluster = gen_cluster(400, seed=5)
-    kw = dict(flow_slots=1 << 8, aff_slots=1 << 4, canary_probes=0,
-              flightrec_slots=0, realization_slots=0)
-    od = OracleDatapath(cluster.ps, fused=True, prune_budget=2, **kw)
-    hot = gen_traffic(cluster.pod_ips, batch=32, seed=6)
-    po = od.profile(hot, mode="fused")
-    assert po["mode"] == "fused" and po["prune_budget"] == 2
-    assert set(po["phases_s"]) == {"fused_fast_path", "fused_onepass",
-                                   "fused_commit_residual"}
-    sd = TpuflowDatapath(cluster.ps, miss_chunk=32, prune_budget=2, **kw)
-    on = OracleDatapath(cluster.ps, prune_budget=2, **kw)
-    for dp in (sd, on):
-        with pytest.raises(ValueError):
-            dp.profile(hot, mode="fused")
 
 
 def test_fused_config_errors():
     cluster = gen_cluster(200, seed=5)
-    # One-pass is v4-only: fused + pruned + dual_stack rejected, both
-    # engines, at construction.
+    # fused + pruned has only been proven on v4 worlds: with dual_stack
+    # it is rejected, both engines, at construction.
     for cls in (TpuflowDatapath, OracleDatapath):
         with pytest.raises(ConfigError):
             cls(cluster.ps, fused=True, prune_budget=2, dual_stack=True,

@@ -49,7 +49,15 @@ def _assert_parity(o_ref, o_pruned, ctx):
 # ---------------------------------------------------------------------------
 
 
-def test_pruned_kernel_parity_and_fallback():
+# The adversarial regimes below run once through the XLA scan of the
+# candidate matrices and once through their Pallas consumer (`fused`):
+# a consumer that mis-reads a fill candidate or a fallback lane shows here.
+BOTH_CONSUMERS = pytest.mark.parametrize("fused", [False, True],
+                                         ids=["xla_scan", "fused"])
+
+
+@BOTH_CONSUMERS
+def test_pruned_kernel_parity_and_fallback(fused):
     """A multi-superblock world at K=1 exercises the pow2-rung fallback;
     K=4 exercises the pure candidate path — both must be bitwise equal
     to the unpruned kernel, and spot-equal to the scalar oracle."""
@@ -62,7 +70,7 @@ def test_pruned_kernel_parity_and_fallback():
     for k in (1, 4):
         drs1, meta1 = m.to_device(cps, prune_budget=k)
         assert drs1.ingress.at.agg is not None
-        o1 = _classify(drs1, meta1, tr)
+        o1 = _classify(drs1, meta1, tr, fused=fused)
         _assert_parity(o0, o1, f"K={k}")
         saw_fb = saw_fb or o1["prune_fb"].any()
     assert saw_fb, "the world never exercised the fallback redispatch"
@@ -80,23 +88,6 @@ def test_pruned_fused_consumer_parity():
     o0 = _classify(drs0, meta0, tr)
     o1 = _classify(drs1, meta1, tr, fused=True)
     _assert_parity(o0, o1, "fused")
-
-
-def test_summary_only_defaults_and_skips():
-    """summary_only (the PH_CLS_SUM surface) must report the same skip
-    mask as the full pruned walk, take zero fallbacks, and resolve every
-    live lane to the default-verdict image."""
-    cluster = gen_cluster(400, seed=5)
-    cps = compile_policy_set(cluster.ps)
-    tr = gen_traffic(cluster.pod_ips, batch=96, seed=6)
-    drs1, meta1 = m.to_device(cps, prune_budget=2)
-    o_full = _classify(drs1, meta1, tr)
-    o_sum = _classify(drs1, meta1, tr, summary_only=True)
-    assert np.array_equal(o_full["prune_skip"], o_sum["prune_skip"])
-    assert not o_sum["prune_fb"].any()
-    # Skip lanes short-circuit identically in both modes.
-    sk = o_sum["prune_skip"].astype(bool)
-    assert np.array_equal(o_full["code"][sk], o_sum["code"][sk])
 
 
 # ---------------------------------------------------------------------------
@@ -117,7 +108,8 @@ def _dense_ps(n_rules: int):
     )
 
 
-def test_dense_world_full_fallback_parity():
+@BOTH_CONSUMERS
+def test_dense_world_full_fallback_parity(fused):
     # > 1024 ingress rules => at least 2 superblocks; every one a
     # candidate for web-bound traffic, so K=1 lanes ALL fall back.
     ps = _dense_ps(1100)
@@ -132,7 +124,7 @@ def test_dense_world_full_fallback_parity():
     drs1, meta1 = m.to_device(cps, prune_budget=1)
     assert drs1.ingress.at.agg.shape[1] >= 2
     o0 = _classify(drs0, meta0, tr)
-    o1 = _classify(drs1, meta1, tr)
+    o1 = _classify(drs1, meta1, tr, fused=fused)
     _assert_parity(o0, o1, "dense")
     # 100% fallback: the degenerate case degrades to the unpruned
     # dispatch shape (ONE bounded full-width redispatch covering every
@@ -143,8 +135,9 @@ def test_dense_world_full_fallback_parity():
     assert int(o1["code"][0]) == int(oracle.classify(pkts[0]).code) == 0
     # Both engines: the datapaths agree step-for-step on this world too.
     dp = TpuflowDatapath(ps, flow_slots=1 << 8, aff_slots=1 << 6,
-                         miss_chunk=16, prune_budget=1, canary_probes=0,
-                         flightrec_slots=0, realization_slots=0)
+                         miss_chunk=16, prune_budget=1, fused=fused,
+                         canary_probes=0, flightrec_slots=0,
+                         realization_slots=0)
     od = OracleDatapath(ps, flow_slots=1 << 8, prune_budget=1,
                         canary_probes=0, flightrec_slots=0,
                         realization_slots=0)
@@ -153,7 +146,8 @@ def test_dense_world_full_fallback_parity():
     assert dp.prune_stats()["fallbacks_total"] == batch.size
 
 
-def test_aggregate_false_positive_world():
+@BOTH_CONSUMERS
+def test_aggregate_false_positive_world(fused):
     """Per-dimension aggregate bits all set on the same word, 3-way AND
     empty: the candidate gather must find nothing and the lane must take
     the DEFAULT verdict with zero fallbacks — a false positive costs a
@@ -178,7 +172,7 @@ def test_aggregate_false_positive_world():
                  src_port=31000, dst_port=80)
     batch = PacketBatch.from_packets([pkt] * 8)
     drs1, meta1 = m.to_device(cps, prune_budget=4)
-    o1 = _classify(drs1, meta1, batch)
+    o1 = _classify(drs1, meta1, batch, fused=fused)
     drs0, meta0 = m.to_device(cps)
     o0 = _classify(drs0, meta0, batch)
     _assert_parity(o0, o1, "false-positive")
@@ -497,74 +491,6 @@ def test_prune_config_errors():
             eng(cluster.ps, prune_budget=-1)
         with pytest.raises(ConfigError):
             eng(cluster.ps, autotune_prune=True)
-
-
-def test_profile_prune_mode_both_engines():
-    """Structure + telescoped-sum identity on an abbreviated chain (the
-    summary/candidate seam — the full 7-entry chain compiles seven
-    pruned-pipeline variants and runs in the slow tier below)."""
-    from antrea_tpu.models import profile as prof_mod
-
-    cluster = gen_cluster(60, n_nodes=2, pods_per_node=4, seed=5)
-    hot = gen_traffic(cluster.pod_ips, 32, n_flows=16, seed=6)
-    fresh = gen_traffic(cluster.pod_ips, 128, n_flows=128, seed=7,
-                        one_per_flow=True)
-    dp = TpuflowDatapath(cluster.ps, flow_slots=1 << 10, aff_slots=1 << 8,
-                         miss_chunk=16, prune_budget=2, canary_probes=0,
-                         flightrec_slots=0, realization_slots=0)
-    short = (("prune_fast_path", 0),
-             ("prune_summary_gather",
-              pl.PH_SLOW | pl.PH_LB | pl.PH_CLS_SUM),
-             ("prune_candidate_gather", pl.PH_ALL))
-    prof = prof_mod.profile_churn_prune(
-        dp._meta, dp._state, dp._drs, dp._dsvc, prof_mod._dev_cols(hot),
-        prof_mod._dev_cols(fresh), n_new=8, k_small=1, k_big=2, repeats=1,
-        chain=short,
-    )
-    assert prof["mode"] == "prune" and prof["prune_budget"] == 2
-    assert list(prof["phases_s"]) == [n for n, _m in short]
-    assert abs(sum(prof["phases_s"].values()) - prof["total_s"]) < 1e-9
-    # Unpruned metas refuse the mode (nothing to attribute) — at both
-    # the profile_churn_prune layer and the Datapath.profile surface.
-    with pytest.raises(ValueError):
-        prof_mod.profile_churn_prune(
-            dp._meta._replace(match=dp._meta.match._replace(prune_budget=0)),
-            dp._state, dp._drs, dp._dsvc, prof_mod._dev_cols(hot),
-            prof_mod._dev_cols(fresh), n_new=8)
-    dp0 = TpuflowDatapath(cluster.ps, flow_slots=1 << 10, aff_slots=1 << 8,
-                          miss_chunk=16, canary_probes=0,
-                          flightrec_slots=0, realization_slots=0)
-    with pytest.raises(ValueError):
-        dp0.profile(hot, fresh, n_new=8, mode="prune")
-    od = OracleDatapath(cluster.ps, prune_budget=2, flow_slots=1 << 10,
-                        canary_probes=0, flightrec_slots=0,
-                        realization_slots=0)
-    po = od.profile(hot, fresh, mode="prune")
-    assert po["mode"] == "prune" and po["prune_budget"] == 2
-    assert "prune_candidate_gather" in po["phases_s"]
-    # Twin parity: the scalar engine refuses the mode unpruned too.
-    od0 = OracleDatapath(cluster.ps, flow_slots=1 << 10, canary_probes=0,
-                         flightrec_slots=0, realization_slots=0)
-    with pytest.raises(ValueError):
-        od0.profile(hot, fresh, mode="prune")
-
-
-@pytest.mark.slow
-def test_profile_prune_full_chain():
-    from antrea_tpu.models.profile import PRUNE_PHASE_CHAIN
-
-    cluster = gen_cluster(60, n_nodes=2, pods_per_node=4, seed=5)
-    hot = gen_traffic(cluster.pod_ips, 32, n_flows=16, seed=6)
-    fresh = gen_traffic(cluster.pod_ips, 128, n_flows=128, seed=7,
-                        one_per_flow=True)
-    dp = TpuflowDatapath(cluster.ps, flow_slots=1 << 10, aff_slots=1 << 8,
-                         miss_chunk=16, prune_budget=2, canary_probes=0,
-                         flightrec_slots=0, realization_slots=0)
-    prof = dp.profile(hot, fresh, n_new=8, k_small=1, k_big=2, repeats=1,
-                      mode="prune")
-    assert prof["mode"] == "prune" and prof["prune_budget"] == 2
-    assert list(prof["phases_s"]) == [n for n, _m in PRUNE_PHASE_CHAIN]
-    assert abs(sum(prof["phases_s"].values()) - prof["total_s"]) < 1e-9
 
 
 # ---------------------------------------------------------------------------

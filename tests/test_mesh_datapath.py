@@ -428,8 +428,6 @@ def test_mesh_config_rejections(world, mesh):
     mdp = _mesh_dp(world, mesh)
     with pytest.raises(ValueError, match="not divisible"):
         mdp.step(gen_traffic(cluster.pod_ips, 7, n_flows=7, seed=2), 100)
-    with pytest.raises(NotImplementedError):
-        mdp.profile(None)
 
 
 def _fwd_topo(n_pods=3):

@@ -747,3 +747,30 @@ def test_hold_admission_leaves_punt_and_arp_lanes_alone():
     assert list(rt.code) == [0, 0, 1]     # punt/ARP allow; only the real
     assert list(rt.pending) == [0, 0, 1]  # miss is held + queued
     assert t.slowpath_stats()["depth"] == o.slowpath_stats()["depth"] == 1
+
+
+@pytest.mark.parametrize("admission,code", [("forward", 0), ("hold", 1)])
+def test_fast_step_defers_every_miss_and_commits_nothing(admission, code):
+    """Catches a fast step that runs any of the slow path: with
+    `PipelineMeta.defer_misses` (what the async engine sets for that one
+    program) a miss keeps the admission policy's image, the flow cache
+    stays bit for bit as it was however often the batch repeats, and only
+    the drain step — the same meta without the flag — commits."""
+    ps, svcs = _world()
+    dp, _od = _pair(ps, svcs, admission=admission)
+    assert dp._meta_step.defer_misses and not dp._meta.defer_misses
+    assert dp._meta_step._replace(defer_misses=False,
+                                  miss_code=dp._meta.miss_code) == dp._meta
+    batch = PacketBatch.from_packets(
+        [_fresh_pkt(CLIENT, SRV), _fresh_pkt(BLOCKED, SRV)])
+    # Row N is the dump row, the masked scatters' junk target no lookup reads.
+    before = [np.asarray(x)[:-1].copy() for x in dp._state.flow]
+    for _ in range(2):
+        r = dp.step(batch, next(_NOW))
+        assert r.n_miss == batch.size and list(r.pending) == [1, 1]
+        assert list(r.code) == [code, code] and not any(r.committed)
+    for was, now in zip(before, dp._state.flow):
+        np.testing.assert_array_equal(was, np.asarray(now)[:-1])
+    dp.drain_slowpath(next(_NOW))
+    r = dp.step(batch, next(_NOW))
+    assert r.n_miss == 0 and list(r.code) == [0, 1]

@@ -29,7 +29,7 @@ sys.path.insert(0, str(REPO))
 from antrea_tpu.analysis import PASSES, run  # noqa: E402
 
 ALL_PASSES = (
-    "mesh", "metrics", "phases", "events", "commit-plane", "audit-plane",
+    "mesh", "metrics", "events", "commit-plane", "audit-plane",
     "maintenance", "reshard", "tenant",
     "thread-safety", "bounded-cache", "jit-purity", "donation-safety",
     "bounded-buffer", "telemetry-registry", "canonical-shape",
@@ -88,11 +88,6 @@ def _mutate_metrics(t: Path):
                  + '\n_SEEDED = "antrea_tpu_bogus_unregistered_total"\n')
 
 
-def _mutate_phases(t: Path):
-    p = t / "antrea_tpu" / "models" / "pipeline.py"
-    p.write_text(p.read_text() + "\nPH_BOGUS_SEEDED = 1 << 29\n")
-
-
 def _mutate_events(t: Path):
     p = t / "antrea_tpu" / "observability" / "flightrec.py"
     p.write_text(p.read_text()
@@ -139,7 +134,6 @@ def _mutate_tenant(t: Path):
 LEGACY = [
     ("check_mesh", "mesh", _mutate_mesh),
     ("check_metrics", "metrics", _mutate_metrics),
-    ("check_phases", "phases", _mutate_phases),
     ("check_events", "events", _mutate_events),
     ("check_commit_plane", "commit-plane", _mutate_commit),
     ("check_audit_plane", "audit-plane", _mutate_audit),
@@ -152,7 +146,7 @@ LEGACY = [
 @pytest.fixture(scope="module")
 def tree_template(tmp_path_factory):
     """A copy of everything the passes read: the package sources plus
-    the repo-root surfaces (README, bench_profile, baseline)."""
+    the repo-root surfaces (README, baseline)."""
     base = tmp_path_factory.mktemp("analysis") / "template"
     (base / "antrea_tpu").mkdir(parents=True)
     for src in (REPO / "antrea_tpu").rglob("*.py"):
@@ -160,7 +154,7 @@ def tree_template(tmp_path_factory):
         dst = base / rel
         dst.parent.mkdir(parents=True, exist_ok=True)
         shutil.copy(src, dst)
-    for name in ("README.md", "bench_profile.py", "BASELINE.analysis.json"):
+    for name in ("README.md", "BASELINE.analysis.json"):
         shutil.copy(REPO / name, base / name)
     return base
 

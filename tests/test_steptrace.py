@@ -40,6 +40,7 @@ from antrea_tpu.compiler.topology import FWD_TUNNEL, NodeRoute, Topology
 from antrea_tpu.datapath import TpuflowDatapath
 from antrea_tpu.datapath.tpuflow import _rid
 from antrea_tpu.models import forwarding as fwd
+from antrea_tpu.models import pipeline as pl
 from antrea_tpu.observability import tracing
 from antrea_tpu.observability.tracing import (STEP_PHASES, STEP_RECORD,
                                               STEP_SCOPES, STEP_SUBSPANS,
@@ -437,22 +438,83 @@ def _lowered_text(dp):
         meta=dp._meta_step).as_text(debug_info=True)
 
 
-def test_the_lowered_step_names_every_scope(world):
+def _mesh_lowered_text(dp):
+    """The sharded step as `MeshDatapath._step` calls it, lowered only."""
+    from antrea_tpu.parallel import meshpath
+
+    i32, yes = np.zeros(B, np.int32), np.ones(B, bool)
+    stepf = meshpath._mesh_step_full_fn(dp._mesh, dp._meta_step, False)
+    dsvc, dft = dp._shared_tables()
+    return stepf.lower(
+        dp._state, dp._drs, dsvc, dft, i32, i32, i32, i32, i32, i32,
+        jnp.int32(1), jnp.int32(1), i32, i32, yes, ~yes, i32,
+        ~yes).as_text(debug_info=True)
+
+
+def _drain_lowered_text(dp):
+    """The async engine's coalesced drain as `_drain_classify` calls it."""
+    D = dp._slowpath.drain_batch
+    i32, yes = jnp.zeros(D, jnp.int32), jnp.ones(D, bool)
+    return pl.pipeline_step.lower(
+        dp._state, dp._drs, dp._dsvc, i32, i32, i32, i32, i32, jnp.int32(1),
+        jnp.int32(1), meta=dp._drain_meta(D), valid=yes, no_commit=~yes,
+        flags=i32, lens=None).as_text(debug_info=True)
+
+
+SLOW_PATH = ("miss_detect", "service_lb", "classify", "classify.candidate",
+             "classify.scan", "cache_commit", "eviction_scan")
+FULL_STEP = ("forwarding", "egress")  # models/forwarding's, not the drain's
+# selection -> (engine kind, knobs, lowering, scopes its program leaves out)
+SELECTIONS = {
+    "default": ("tpuflow", {}, _lowered_text, ("classify.summary",)),
+    "fused": ("tpuflow", {"fused": True}, _lowered_text,
+              ("classify.summary",)),
+    "prune_budget": ("tpuflow", {"prune_budget": 2}, _lowered_text, ()),
+    "fused+prune_budget": ("tpuflow", {"fused": True, "prune_budget": 2},
+                           _lowered_text, ()),
+    "async_fast_step": ("tpuflow", {"async_slowpath": True,
+                                    "drain_batch": 64}, _lowered_text,
+                        SLOW_PATH + ("classify.summary",)),
+    "async_drain": ("tpuflow", {"async_slowpath": True, "drain_batch": 64},
+                    _drain_lowered_text, FULL_STEP + ("classify.summary",)),
+    "mesh_step": ("mesh", {}, _mesh_lowered_text, ("classify.summary",)),
+}
+KERNEL_OF = {"fused": "classify_consumer",
+             "fused+prune_budget": "classify_pruned_consumer"}
+
+
+@pytest.mark.parametrize("selection", list(SELECTIONS))
+def test_the_lowered_step_names_every_scope(world, selection):
+    """Catches a selection whose program gains or loses a layer: exactly
+    the STEP_SCOPES listed lower, nested as tracing.py says; the async fast
+    step holds no slow path at all, and no selection holds a kernel but
+    its own (`classify_onepass` is gone for good)."""
     cluster, services, _ = world
-    plain = _lowered_text(TpuflowDatapath(cluster.ps, services, **KW))
-    # `classify.summary` is the aggregate stage of the pruned classifier;
-    # the unpruned walk has no such stage.
-    assert [s for s in STEP_SCOPES if s not in plain] == ["classify.summary"]
-    pruned = _lowered_text(TpuflowDatapath(cluster.ps, services,
-                                           prune_budget=2, **KW))
-    for scope in STEP_SCOPES:
-        assert re.search(rf'[/"]{re.escape(scope)}[/"]', pruned), scope
-    # Nested as tracing.py says: the round loop's scopes inside
-    # miss_detect, the eviction scan inside the commit.
-    assert "fast_path/probe" in plain and "fast_path/refresh" in plain
-    assert re.search(r"miss_detect/.*while/body/service_lb", plain)
-    assert re.search(r"miss_detect/.*classify/classify\.scan", plain)
-    assert re.search(r"miss_detect/.*cache_commit/eviction_scan", plain)
+    kind, knobs, lower, absent = SELECTIONS[selection]
+    if kind == "tpuflow":
+        dp = TpuflowDatapath(cluster.ps, services, **KW, **knobs)
+    else:
+        dp = _make("mesh", world)
+    text = lower(dp)
+    held = [s for s in STEP_SCOPES
+            if re.search(rf'[/"]{re.escape(s)}[/"]', text)]
+    assert held == [s for s in STEP_SCOPES if s not in absent]
+    kernels = set(re.findall(
+        r"classify_(?:pruned_)?consumer|classify_onepass", text))
+    assert kernels == ({KERNEL_OF[selection]} if selection in KERNEL_OF
+                       else set())
+    assert "fast_path/probe" in text and "fast_path/refresh" in text
+    if selection == "async_fast_step":
+        assert not re.search(r"while/body/(service_lb|classify)", text)
+        return
+    # The round loop's scopes inside miss_detect, the eviction scan
+    # inside the commit.
+    assert re.search(r"miss_detect/.*while/body/service_lb", text)
+    assert re.search(r"miss_detect/.*classify/classify\.scan", text)
+    assert re.search(r"miss_detect/.*cache_commit/eviction_scan", text)
+
+
+def test_an_unknown_scope_is_refused():
     with pytest.raises(ValueError):
         tracing.device_scope("fastpath")
 
@@ -485,10 +547,10 @@ def test_the_names_are_spelled_in_the_schema_only():
     assert len(set(STEP_SCOPES)) == len(STEP_SCOPES)
     names = [n for n in STEP_RECORD.names if n.startswith("t_")]
     assert names == STAMPS
-    # The three Pallas consumers carry stable kernel names.
+    # The two Pallas consumers carry stable kernel names.
     match = (PKG / "ops" / "match.py").read_text()
     assert match.count("pl.pallas_call(") == len(
-        re.findall(r'name="classify_\w+"', match)) == 3
+        re.findall(r'name="classify_\w+"', match)) == 2
 
 
 def test_the_spans_land_in_a_profiler_trace(world, tmp_path):

@@ -4,7 +4,7 @@
     python tools/analyze.py [--pass ID [--pass ID ...]] [--json]
                             [--list] [--root PATH]
 
-Runs the registered passes of antrea_tpu/analysis (the nine migrated
+Runs the registered passes of antrea_tpu/analysis (the eight migrated
 tools/check_* gates + the semantic passes: thread-safety,
 bounded-cache, jit-purity, donation-safety) over the repo, applies the
 BASELINE.analysis.json suppressions, and exits 0 only when every pass
