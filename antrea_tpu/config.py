@@ -32,6 +32,10 @@ class AgentConfig:
     flow_slots: int = 1 << 20
     aff_slots: int = 1 << 18
     ct_timeout_s: int = 3600
+    # missChunk: the WIDEST slow-path round, in lanes.  A step's misses are
+    # served in rounds of this width; the last round narrows itself to what
+    # is left (models/pipeline.round_ladder), so it is a ceiling on a
+    # round's working set, not a price every step with a miss pays.
     miss_chunk: int = 4096
     delta_slots: int = 128
     # Unified maintenance scheduler (datapath/maintenance.py): total

@@ -656,6 +656,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         # ---- account: counters, admission, telemetry, per-rule stats -------
         tr.phase(SP_ACCOUNT)
         tr.n_miss = int(o["n_miss"])
+        tr.round_lanes = int(o["round_lanes"])
         self._evictions += int(o["n_evict"])
         self._prune_account(o)
         pending = None

@@ -518,7 +518,7 @@ EGRESS_NARROW = (
     "code", "est", "reply", "reject_kind", "committed", "miss", "snat", "dsr",
     "spoofed", "l7_redirect", "punt", "fwd_kind", "dec_ttl", "tc_act",
 )
-EGRESS_SCALARS = ("n_miss", "n_evict", "n_reclaim")
+EGRESS_SCALARS = ("n_miss", "n_evict", "n_reclaim", "round_lanes")
 EGRESS_RECORD = tuple(
     (field, block, row, bits, True)
     for block, fields, bits in (("words", EGRESS_WORDS, 32),
@@ -570,7 +570,7 @@ def unpack_egress(words, narrow, scalars=()) -> dict:
     """The fetched blocks (numpy) -> the step's output dict, field by
     field a ROW VIEW of its block: no copy, and a narrow field keeps its
     block's i8 — readers compare by value.  `scalars` is the one-chip
-    step's (3,); the mesh folds its (3, D) block itself and leaves it out."""
+    step's (4,); the mesh folds its (4, D) block itself and leaves it out."""
     o = dict(zip(EGRESS_WORDS, words))
     o.update(zip(EGRESS_NARROW, narrow))
     o.update(zip(EGRESS_SCALARS, scalars))

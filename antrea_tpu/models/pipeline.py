@@ -63,8 +63,8 @@ from ..apis.controlplane import PROTO_TCP
 from ..compiler.compile import ACT_ALLOW, ACT_REJECT, CompiledPolicySet
 from ..compiler.services import ServiceTables
 from ..ops import hashing
-from ..ops.match import (PRUNE_HIST_BOUNDS, DeviceRuleSet, StaticMeta,
-                         classify_batch, to_device, to_host)
+from ..ops.match import (_FUSE_TB, PRUNE_HIST_BOUNDS, DeviceRuleSet,
+                         StaticMeta, classify_batch, to_device, to_host)
 from ..ops.scopes import device_scope
 
 # Python ints, never eager jnp scalars: see the BIG comment in ops/match.py.
@@ -266,7 +266,7 @@ class PipelineMeta(NamedTuple):
     # other_* cover non-TCP (kernel UDP unreplied/stream).  None = inherit
     # ct_timeout_s (per-state handling compiles out entirely).
     ct_timeout_s: int
-    miss_chunk: int  # slow-path round size
+    miss_chunk: int  # the WIDEST slow-path round (round_ladder)
     ct_syn_timeout_s: Optional[int] = None
     ct_other_new_s: Optional[int] = None
     ct_other_est_s: Optional[int] = None
@@ -435,6 +435,36 @@ def _wide_words(col_f: jax.Array, w6, is6) -> jax.Array:
     if w6 is None:
         return m
     return jnp.where((is6 != 0)[:, None], w6, m)
+
+
+# The narrow rung of the round ladder is miss_chunk over this.  Two rungs
+# and no more: every rung is one more body of the round in every step
+# program a process builds, which it traces, lowers and loads anew at each
+# start (measured on the v5e, PERF.md s6 PRs 31-32: ~0.8 s a body on one
+# chip; ~8 s on the four-chip mesh when its step and retry rungs built it).
+_LADDER_NARROW = 8
+
+
+def round_ladder(miss_chunk: int, batch: int) -> tuple:
+    """The widths a slow-path round may run at, widest first.
+
+    The misses are served in rounds of `miss_chunk` lanes; the LAST round
+    carries what is left and runs at the narrowest rung that holds it (the
+    lanes dropped are padding: `valid` false, every scatter aimed at the
+    dump row), so the partition of the misses — and with it every output
+    and every slot of the state; the dump row holds junk either way — is
+    what the single rung `(miss_chunk,)` gives.  The narrow rung is an
+    eighth of miss_chunk (4096 -> 512: a node's steady state misses a few
+    hundred lanes a step) where that is a multiple of the fused consumer's
+    tile (ops/match._FUSE_TB: `fused` takes a rung without padding it),
+    and no rung is wider than the batch can fill: a step of `batch` lanes
+    has at most that many misses, so it keeps the narrowest rung that
+    holds them and those below it."""
+    rungs = (miss_chunk,)
+    if miss_chunk % (_LADDER_NARROW * _FUSE_TB) == 0:
+        rungs += (miss_chunk // _LADDER_NARROW,)
+    top = min(w for w in rungs if w >= min(miss_chunk, batch))
+    return tuple(w for w in rungs if w <= top)
 
 
 def _winner_mask(n_slots, slots, mask, dump):
@@ -1161,14 +1191,25 @@ def _pipeline_step(
     # so every existing position is unchanged when the knob is off.
     n_extra = ((1 if A == 8 else 0) + (3 if prune_on else 0)
                + (2 if tel_on else 0))
+    # The fixed head of the slow path's output tuple (nine images, n_evict,
+    # n_reclaim, round_lanes) and of the rounds' carry (r, lanes, the two
+    # counters, flow, aff, the nine images); the optional outputs follow.
+    N_OUT, N_CARRY = 12, 15
+    # A step that is one shard of a sharded program (a `hit_combine` seam
+    # is given: parallel/meshpath's step, its spill-retry rungs and its
+    # drains) keeps the single rung.  Its host waits for the slowest
+    # replica and its foreign walks run full rounds, so the narrow rung
+    # buys ~2 % there; and on the four-chip v5e host the two-body program's
+    # top-rung spill retry cost the HOST +22 ms a step (PERF.md s6, PR 32).
+    ladder = round_ladder(M, B)[:2 if hit_combine is None else 1]
 
     # ---- slow path: ServiceLB + classify + commit, misses only -------------
     def slow(args):
         flow, aff, outs = args
         (out_code, out_svc, out_dnat_ip, out_dnat_port, out_rule_in,
          out_rule_out, out_committed, out_snat, out_dsr, n_evict0,
-         n_reclaim0) = outs[:11]
-        pos = 11
+         n_reclaim0, lanes0) = outs[:N_OUT]
+        pos = N_OUT
         out_dnat_w = None
         if A == 8:
             out_dnat_w = outs[pos]
@@ -1183,11 +1224,16 @@ def _pipeline_step(
         aff_snap = aff
         midx = jnp.nonzero(miss, size=B, fill_value=B)[0].astype(jnp.int32)
 
-        def round_body(carry):
-            (r, n_evict, n_reclaim, flow, aff, out_code, out_svc,
+        # One round at width W: the lanes midx[r*M : r*M + W].  W is M in
+        # every round but the last, which takes the narrowest rung of
+        # round_ladder that holds what is left; the lanes the narrow rung
+        # drops are padding (`valid` false below), so the result is the
+        # single rung's, bit for bit.
+        def round_body(W, carry):
+            (r, lanes, n_evict, n_reclaim, flow, aff, out_code, out_svc,
              out_dnat_ip, out_dnat_port, out_rule_in, out_rule_out,
-             out_committed, out_snat, out_dsr) = carry[:14]
-            pos = 14
+             out_committed, out_snat, out_dsr) = carry[:N_CARRY]
+            pos = N_CARRY
             out_dnat_w = None
             if A == 8:
                 out_dnat_w = carry[pos]
@@ -1198,9 +1244,9 @@ def _pipeline_step(
             if tel_on:
                 tel_hb, tel_sc = carry[pos:pos + 2]
             idx = jax.lax.dynamic_slice(
-                jnp.concatenate([midx, jnp.full((M,), B, jnp.int32)]),
+                jnp.concatenate([midx, jnp.full((W,), B, jnp.int32)]),
                 (r * M,),
-                (M,),
+                (W,),
             )
             valid = idx < B
             safe = jnp.clip(idx, 0, B - 1)
@@ -1213,7 +1259,7 @@ def _pipeline_step(
             slot_m = slot[safe]
             pp_m = pp[safe]
             if meta.count_flow_stats:
-                lv_m = (jnp.zeros(M, jnp.int32) if lens is None
+                lv_m = (jnp.zeros(W, jnp.int32) if lens is None
                         else jnp.maximum(lens[safe], 0))
             if A == 8:
                 saddr_m = saddr[safe]
@@ -1307,7 +1353,7 @@ def _pipeline_step(
                 # freshens both directions; the frontend SNAT mark and the
                 # DSR delivery mark are pinned here for the connection's
                 # lifetime).
-                pref_col = jnp.full((M,), now & pmask, jnp.int32)
+                pref_col = jnp.full((W,), now & pmask, jnp.int32)
                 zcol = (pref_col
                         | jnp.where(snat_m > 0, REPLY_BIT, 0)
                         | jnp.where(dsr_m > 0, DSR_BIT, 0))
@@ -1322,7 +1368,7 @@ def _pipeline_step(
                     meta_rows = jnp.concatenate(
                         [dnat_w,
                          jnp.stack([m1, rules_p, zcol,
-                                    jnp.zeros((M,), jnp.int32)], axis=1)],
+                                    jnp.zeros((W,), jnp.int32)], axis=1)],
                         axis=1,
                     )
                 key_rows = jnp.concatenate(
@@ -1366,7 +1412,7 @@ def _pipeline_step(
                         [daddr_m,
                          jnp.stack([_pack_meta1(code, svc_idx, dp_m),
                                     rules_p, pref_col,
-                                    jnp.zeros((M,), jnp.int32)],
+                                    jnp.zeros((W,), jnp.int32)],
                                    axis=1)],
                         axis=1,
                     )
@@ -1382,12 +1428,12 @@ def _pipeline_step(
                 # oracle's per-packet insert sequence (parity on eviction
                 # races).
                 MC = 4 if A == 2 else 8
-                slot2 = jnp.stack([slot_m, rev_slot], axis=1).reshape(2 * M)
+                slot2 = jnp.stack([slot_m, rev_slot], axis=1).reshape(2 * W)
                 keys2 = jnp.stack([key_rows, rev_keys], axis=1).reshape(
-                    2 * M, A + 2)
+                    2 * W, A + 2)
                 meta2 = jnp.stack([meta_rows, rev_meta], axis=1).reshape(
-                    2 * M, MC)
-                ins2 = jnp.stack([ins, rev_ins], axis=1).reshape(2 * M)
+                    2 * W, MC)
+                ins2 = jnp.stack([ins, rev_ins], axis=1).reshape(2 * W)
 
                 if meta.second_chance:
                     flow, ins2, sc_n = _second_chance_guard(
@@ -1439,12 +1485,12 @@ def _pipeline_step(
                     # reset to zero — a reused slot must not inherit the
                     # evicted entry's carry.
                     pk2 = jnp.stack(
-                        [jnp.ones(M, jnp.int32), jnp.zeros(M, jnp.int32)],
-                        axis=1).reshape(2 * M)
+                        [jnp.ones(W, jnp.int32), jnp.zeros(W, jnp.int32)],
+                        axis=1).reshape(2 * W)
                     oc2 = jnp.stack(
-                        [lv_m, jnp.zeros(M, jnp.int32)],
-                        axis=1).reshape(2 * M)
-                    z2 = jnp.zeros(2 * M, jnp.int32)
+                        [lv_m, jnp.zeros(W, jnp.int32)],
+                        axis=1).reshape(2 * W)
+                    z2 = jnp.zeros(2 * W, jnp.int32)
                     new_pkts = _scatter_last(flow.pkts, slot2, pk2, ins2,
                                              dump)
                     new_octets = _scatter_last(flow.octets, slot2, oc2,
@@ -1459,7 +1505,7 @@ def _pipeline_step(
                 flow = FlowCache(
                     keys=_scatter_last_rows(flow.keys, slot2, keys2, ins2, dump),
                     meta=_scatter_last_rows(flow.meta, slot2, meta2, ins2, dump),
-                    ts=_scatter_last(flow.ts, slot2, jnp.full((2 * M,), now, jnp.int32), ins2, dump),
+                    ts=_scatter_last(flow.ts, slot2, jnp.full((2 * W,), now, jnp.int32), ins2, dump),
                     pkts=new_pkts,
                     octets=new_octets,
                     pkts_hi=new_pkts_hi,
@@ -1479,37 +1525,55 @@ def _pipeline_step(
                     key_client=new_client,
                     key_svc=_scatter_last(aff.key_svc, learn["aslot"], learn["svc"], lm, adump),
                     ep=_scatter_last(aff.ep, learn["aslot"], learn["ep"], lm, adump),
-                    ts=_scatter_last(aff.ts, learn["aslot"], jnp.full((M,), now, jnp.int32), lm, adump),
+                    ts=_scatter_last(aff.ts, learn["aslot"], jnp.full((W,), now, jnp.int32), lm, adump),
                 )
                 return flow, aff, n_evict, n_reclaim, tel_sc
 
             flow, aff, n_evict, n_reclaim, tel_sc = do_commit(
                 flow, aff, n_evict, n_reclaim, tel_sc if tel_on else None)
-            return (r + 1, n_evict, n_reclaim, flow, aff, out_code, out_svc,
-                    out_dnat_ip, out_dnat_port, out_rule_in, out_rule_out,
-                    out_committed, out_snat, out_dsr) + (
+            return (r + 1, lanes + W, n_evict, n_reclaim, flow, aff,
+                    out_code, out_svc, out_dnat_ip, out_dnat_port,
+                    out_rule_in, out_rule_out, out_committed, out_snat,
+                    out_dsr) + (
                     (out_dnat_w,) if A == 8 else ()) + (
                     (pr_sk, pr_fb, pr_hist) if prune_on else ()) + (
                     (tel_hb, tel_sc) if tel_on else ())
 
-        def round_cond(carry):
-            r = carry[0]
-            return r * M < n_miss
-
-        carry = (jnp.int32(0), n_evict0, n_reclaim0, flow, aff, out_code,
-                 out_svc, out_dnat_ip, out_dnat_port, out_rule_in,
+        carry = (jnp.int32(0), lanes0, n_evict0, n_reclaim0, flow, aff,
+                 out_code, out_svc, out_dnat_ip, out_dnat_port, out_rule_in,
                  out_rule_out, out_committed, out_snat, out_dsr) + (
                  (out_dnat_w,) if A == 8 else ()) + (
                  (pr_sk0, pr_fb0, pr_hist0) if prune_on else ()) + (
                  (tel_hb0, tel_sc0) if tel_on else ())
-        carry = jax.lax.while_loop(round_cond, round_body, carry)
-        (_, n_evict, n_reclaim, flow, aff, out_code, out_svc, out_dnat_ip,
-         out_dnat_port, out_rule_in, out_rule_out, out_committed,
-         out_snat, out_dsr) = carry[:14]
+        # Two loops, each a `while` of its own (a device trace tells slow
+        # path from fast path by nesting under one).  The wide one runs
+        # every round that has more lanes left than the narrow rung holds:
+        # the full rounds, and a last round too wide for the narrow rung.
+        # The narrow one runs once, where it holds what is left, else not
+        # at all; n_miss <= M is one round, as with a single rung.  The
+        # narrow loop sits inside a conditional of its own: two `while`s
+        # side by side in one computation cost the wide one its
+        # loop-invariant operands' place in fast memory on the v5e (the
+        # lane columns: +0.3 ms a full round, PERF.md s6 PRs 31-32); the
+        # conditional passes the tables through and copies none.
+        def rounds(W, more_than):
+            return lambda c: jax.lax.while_loop(
+                lambda c: n_miss - c[0] * M > more_than,
+                partial(round_body, W), c)
+
+        narrow = ladder[1] if len(ladder) > 1 else 0
+        carry = rounds(ladder[0], narrow)(carry)
+        if narrow:
+            carry = jax.lax.cond(n_miss - carry[0] * M > 0,
+                                 rounds(narrow, 0), lambda c: c, carry)
+        (_, lanes, n_evict, n_reclaim, flow, aff, out_code, out_svc,
+         out_dnat_ip, out_dnat_port, out_rule_in, out_rule_out,
+         out_committed, out_snat, out_dsr) = carry[:N_CARRY]
         return flow, aff, (out_code, out_svc, out_dnat_ip, out_dnat_port,
                            out_rule_in, out_rule_out, out_committed,
-                           out_snat, out_dsr, n_evict, n_reclaim) + tuple(
-                           carry[14:14 + n_extra])
+                           out_snat, out_dsr, n_evict, n_reclaim,
+                           lanes) + tuple(
+                           carry[N_CARRY:N_CARRY + n_extra])
 
     def noop(args):
         return args
@@ -1518,7 +1582,7 @@ def _pipeline_step(
         slow_init = (flow, aff, (out_code, out_svc, out_dnat_ip, out_dnat_port,
                                  out_rule_in, out_rule_out, out_committed,
                                  out_snat, out_dsr, jnp.int32(0),
-                                 jnp.int32(0)) + (
+                                 jnp.int32(0), jnp.int32(0)) + (
                                  (out_dnat_w,) if A == 8 else ()) + ((
                                  jnp.int32(0), jnp.int32(0),
                                  jnp.zeros(len(PRUNE_HIST_BOUNDS) + 2,
@@ -1533,9 +1597,9 @@ def _pipeline_step(
             flow, aff, outs = jax.lax.cond(n_miss > 0, slow, noop, slow_init)
     (out_code, out_svc, out_dnat_ip, out_dnat_port,
      out_rule_in, out_rule_out, out_committed, out_snat, out_dsr,
-     n_evict, n_reclaim) = outs[:11]
+     n_evict, n_reclaim, round_lanes) = outs[:N_OUT]
     if A == 8:
-        out_dnat_w = outs[11]
+        out_dnat_w = outs[N_OUT]
 
     with device_scope("fast_path"), device_scope("assemble"):
         final_code = out_code[:B]
@@ -1576,9 +1640,13 @@ def _pipeline_step(
             # split out of n_evict only under meta.drain_reclaim (the
             # overlapped drain's fused maintenance); always 0 otherwise.
             "n_reclaim": n_reclaim,
+            # Lanes the slow-path rounds were run at, padding included: the
+            # sum of their widths (round_ladder).  n_miss over it is how
+            # full the rounds were.
+            "round_lanes": round_lanes,
         }
         if prune_on:
-            pos = 11 + (1 if A == 8 else 0)
+            pos = N_OUT + (1 if A == 8 else 0)
             # Round-7 prune observability, aggregated over the slow-path
             # rounds (valid lanes only): aggregate-AND-zero short circuits,
             # full-width fallback redispatches, and the candidate-superblock
@@ -1599,7 +1667,7 @@ def _pipeline_step(
             # path's output pytree — and its compiled HLO — is unchanged.
             # The prune trio above doubles as the telemetry candidate-hist /
             # skip / fallback source when prune_budget > 0.
-            pos_t = 11 + (1 if A == 8 else 0) + (3 if prune_on else 0)
+            pos_t = N_OUT + (1 if A == 8 else 0) + (3 if prune_on else 0)
             out["tel_probe_hit"] = tel_probe_hit
             out["tel_probe_stale"] = tel_probe_stale
             out["tel_probe_miss"] = tel_probe_miss

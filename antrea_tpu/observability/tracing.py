@@ -109,9 +109,13 @@ STEP_RING_SLOTS = 4096
 # runs <s>_t0..<s>_t1 on the same clock, inside its phase (both 0 where
 # it never opened); `spill_lanes` are the lanes the mesh placed off their
 # home replica, `retry_lanes` those of them re-served from home inside
-# the same call (0 on one chip).
+# the same call (0 on one chip).  `round_lanes` is the step program's own
+# count of the lanes its slow-path rounds were run at, padding included
+# (models/pipeline.round_ladder; the mesh sums its replicas and its spill
+# retry): n_miss over it is how full the rounds were.
 STEP_RECORD = np.dtype(
-    [("seq", "<i8"), ("lanes", "<i8"), ("n_miss", "<i8"), ("t_start", "<i8")]
+    [("seq", "<i8"), ("lanes", "<i8"), ("n_miss", "<i8"),
+     ("round_lanes", "<i8"), ("t_start", "<i8")]
     + [(f"t_{p}", "<i8") for p in STEP_PHASES]
     + [("t_done", "<i8"), ("t_end", "<i8"), ("h2d_transfers", "<i8"),
        ("h2d_bytes", "<i8"), ("d2h_transfers", "<i8"), ("d2h_bytes", "<i8")]
@@ -157,14 +161,14 @@ class StepTracer:
         self._sub_at = 0
         self._subs = [0] * (2 * len(STEP_SUBSPANS))
         self._lanes = 0
-        self.n_miss = 0
+        self.n_miss = self.round_lanes = 0
         self.h2d_transfers = self.h2d_bytes = 0
         self.d2h_transfers = self.d2h_bytes = 0
         self.spill_lanes = self.retry_lanes = 0
 
     def begin(self, lanes: int) -> None:
         self._lanes = int(lanes)
-        self.n_miss = 0
+        self.n_miss = self.round_lanes = 0
         self.h2d_transfers = self.h2d_bytes = 0
         self.d2h_transfers = self.d2h_bytes = 0
         self.spill_lanes = self.retry_lanes = 0
@@ -226,9 +230,9 @@ class StepTracer:
         if seq > self.slots:
             self.dropped += 1  # the oldest row, overwritten here
         self._rows[(seq - 1) % self.slots] = (
-            seq, self._lanes, self.n_miss, *ts, self.h2d_transfers,
-            self.h2d_bytes, self.d2h_transfers, self.d2h_bytes, *self._subs,
-            self.spill_lanes, self.retry_lanes)
+            seq, self._lanes, self.n_miss, self.round_lanes, *ts,
+            self.h2d_transfers, self.h2d_bytes, self.d2h_transfers,
+            self.d2h_bytes, *self._subs, self.spill_lanes, self.retry_lanes)
         return (t - ts[0]) * 1e-9
 
     def records(self) -> np.ndarray:

@@ -421,8 +421,8 @@ def _build_sharded_step(cps, svc, mesh, ft, flow_slots, aff_slots,
         # scalar per shard -> (D,) vector of per-data-shard counts (the
         # prune keys exist iff prune_budget > 0; the hist vector gains
         # the same leading axis and is summed host-side)
-        for k in ("n_miss", "n_evict", "n_reclaim", "n_prune_skips",
-                  "n_prune_fb", "prune_cand_hist"):
+        for k in ("n_miss", "n_evict", "n_reclaim", "round_lanes",
+                  "n_prune_skips", "n_prune_fb", "prune_cand_hist"):
             if k in out:
                 out[k] = out[k][None]
         return jax.tree.map(lambda x: x[None], local), out

@@ -173,8 +173,8 @@ def _mesh_step_fn(mesh, meta: pl.PipelineMeta):
         )
         # scalar per shard -> (D,) vector of per-data-shard counts (the
         # prune keys exist iff the meta carries a prune budget)
-        for k in ("n_miss", "n_evict", "n_reclaim", "n_prune_skips",
-                  "n_prune_fb", "prune_cand_hist",
+        for k in ("n_miss", "n_evict", "n_reclaim", "round_lanes",
+                  "n_prune_skips", "n_prune_fb", "prune_cand_hist",
                   "tel_probe_hit", "tel_probe_stale", "tel_probe_miss",
                   "tel_dma_hb", "tel_chance_bumps"):
             if k in out:
@@ -1040,11 +1040,13 @@ class MeshDatapath(TpuflowDatapath):
         self._spill_retried_total += tr.retry_lanes
 
     def _account_counts(self, counts: np.ndarray) -> None:
-        """Fold one sharded call's (3, D) scalar block.  `n_miss` is not
-        read: the step recomputes it from the MERGED per-lane mask."""
+        """Fold one sharded call's (4, D) scalar block, the step's or its
+        spill retry's.  `n_miss` is not read: the step recomputes it from
+        the MERGED per-lane mask."""
         per = dict(zip(fw.EGRESS_SCALARS, counts.sum(axis=1).tolist()))
         self._evictions += per["n_evict"]
         self._reclaims += per["n_reclaim"]
+        self._steptrace.round_lanes += per["round_lanes"]
 
     # -- sharded slow-path callbacks -----------------------------------------
 

@@ -122,7 +122,7 @@ def test_round_trip_at_the_extremes(round_trip, field):
 # -- the schema ----------------------------------------------------------------
 
 def test_schema_rows_are_contiguous_and_every_field_has_one_place():
-    assert len(set(FIELDS)) == len(FIELDS) == 26
+    assert len(set(FIELDS)) == len(FIELDS) == 27
     for block, fields, bits in (("words", fwd.EGRESS_WORDS, 32),
                                 ("narrow", fwd.EGRESS_NARROW, 8),
                                 ("scalars", fwd.EGRESS_SCALARS, 32)):
@@ -148,7 +148,7 @@ def test_every_enum_constant_fits_its_field(field):
     assert fwd.unpack_egress(
         np.zeros((9, 1), np.int32),
         np.asarray(jnp.full((14, 1), max(consts.values())).astype(jnp.int8)),
-        np.zeros(3, np.int32))[field][0] == max(consts.values())
+        np.zeros(4, np.int32))[field][0] == max(consts.values())
 
 
 def test_the_flags_are_flags():
@@ -345,7 +345,7 @@ def test_mesh_result_is_the_dict_paths_and_the_twins(mesh4, field):
 def test_mesh_transfer_counters_by_hand(mesh4):
     """A retried mesh step fetches two records: the step's, B lanes wide,
     and the retry's, four replicas at the power-of-two rung of the
-    fullest overflow; three copies each, 50 B a lane and three i32
+    fullest overflow; three copies each, 50 B a lane and four i32
     scalars a replica."""
     _steps, spilled, shard, rec = mesh4
     m = np.bincount(shard[spilled], minlength=4).max()
@@ -379,4 +379,4 @@ def test_only_optional_outputs_ride_beside_the_record(world, option):
             i32, jnp.int32(1), jnp.int32(1), i32, meta=dp._meta_step, v6=v6))
     assert set(rest) == beside
     assert [(a.shape, a.dtype) for a in rec] == [
-        ((9, B), np.int32), ((14, B), np.int8), ((3,), np.int32)]
+        ((9, B), np.int32), ((14, B), np.int8), ((4,), np.int32)]

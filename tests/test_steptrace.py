@@ -136,22 +136,22 @@ def test_transfer_counters_by_hand(engine):
         assert rec["h2d_transfers"] >= 14
         assert rec["h2d_bytes"] >= 9 * B * 4 + 3 * B + 2 * 4
     # The egress record, by hand from its schema: one copy a block — nine
-    # i32 rows and fourteen i8 rows of B lanes, three i32 scalars (one
+    # i32 rows and fourteen i8 rows of B lanes, four i32 scalars (one
     # column a replica on the mesh) — and nothing beside it, since no
     # option with an output of its own is on (tests/test_egress_record.py
     # holds the program's shapes).
     rows = {b: sum(1 for _, blk, *_ in fwd.EGRESS_RECORD if blk == b)
             for b in ("words", "narrow", "scalars")}
-    assert rows == {"words": 9, "narrow": 14, "scalars": 3}
+    assert rows == {"words": 9, "narrow": 14, "scalars": 4}
     replicas = 1 if kind == "tpuflow" else 2
     lane_bytes, retried = 9 * 4 + 14 * 1, int(rec["retry_lanes"])
-    once = lane_bytes * B + 3 * replicas * 4
+    once = lane_bytes * B + 4 * replicas * 4
     if not retried:
         assert (rec["d2h_transfers"], rec["d2h_bytes"]) == (3, once)
     else:  # the mesh's spill retry fetches a second record: a power-of-
         # two rung of lanes a replica, wide enough for what spilled
         assert kind == "mesh" and rec["d2h_transfers"] == 6
-        rung, rest = divmod(int(rec["d2h_bytes"]) - once - 3 * replicas * 4,
+        rung, rest = divmod(int(rec["d2h_bytes"]) - once - 4 * replicas * 4,
                             lane_bytes * replicas)
         assert rest == 0 and rung & (rung - 1) == 0
         assert retried <= rung * replicas <= B
@@ -215,6 +215,55 @@ def test_mesh_sub_spans_lie_inside_their_phases(world):
             == rec["t_done"] - rec["t_stage"]).all()
     # the retry's transfers are the step's: two dispatches' worth
     assert (rec["d2h_transfers"] % 2 == 0).all()
+
+
+@pytest.mark.parametrize("kind", ["tpuflow", "mesh"])
+def test_round_lanes_are_the_widths_of_the_rounds_that_ran(kind, world):
+    """`round_lanes` of a step record is the program's own count: for every
+    sharded call (one chip: the one call) and every replica, full rounds
+    of `miss_chunk` lanes and then the narrowest rung of the ladder that
+    holds what is left — summed over the replicas, and over the step and
+    its spill retry on the mesh."""
+    from antrea_tpu.parallel import mesh as pm
+
+    dp = _make(kind, world)
+    b = world[2]
+    blocks = []  # the (4, replicas) scalar block of every sharded call
+    if kind == "mesh":
+        fold = dp._account_counts
+        dp._account_counts = lambda c: (blocks.append(np.array(c)), fold(c))[1]
+        shard = pm.shard_of_tuples(b.src_ip, b.dst_ip, b.proto, b.src_port,
+                                   b.dst_port, 2)
+        idx = np.nonzero(shard == 0)[0][:64]  # all home to one replica
+        skew = PacketBatch(**{f: getattr(b, f)[idx] for f in (
+            "src_ip", "dst_ip", "proto", "src_port", "dst_port")})
+        batches = [b, skew, b]
+    else:
+        batches = [b, b]
+    results = [dp.step(x, now=50 + t) for t, x in enumerate(batches)]
+    rec = dp.step_trace()["records"]
+    assert "round_lanes" in rec.dtype.names
+    assert rec["n_miss"].tolist() == [r.n_miss for r in results]
+    M = KW["miss_chunk"]
+
+    def plan(n_miss, lanes):
+        ladder = pl.round_ladder(M, lanes)
+        full, rest = divmod(n_miss, M)
+        return full * M + (min(w for w in ladder if w >= rest) if rest else 0)
+
+    if kind == "tpuflow":
+        assert rec["round_lanes"].tolist() == [plan(n, B) for n in
+                                               rec["n_miss"].tolist()]
+        assert rec["round_lanes"][0] >= M  # a cold batch: whole rounds
+    else:
+        calls = len(rec) + int((rec["retry_lanes"] > 0).sum())
+        assert len(blocks) == calls > len(rec)  # the skewed step retried
+        scalars = dict(zip(fwd.EGRESS_SCALARS, np.stack(blocks, axis=1)))
+        assert scalars["round_lanes"].shape == (calls, 2)
+        for n, got in zip(scalars["n_miss"].ravel().tolist(),
+                          scalars["round_lanes"].ravel().tolist()):
+            assert n <= got == -(-n // M) * M  # one rung: narrower than 128
+        assert rec["round_lanes"].sum() == scalars["round_lanes"].sum() > 0
 
 
 def test_a_raise_inside_a_sub_span_closes_it():
