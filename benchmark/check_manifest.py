@@ -10,6 +10,7 @@ Exit 0 and "manifest ok", or exit 1 with every fault on a line of its own.
 
 from __future__ import annotations
 
+import ast
 import json
 import os
 import re
@@ -18,6 +19,10 @@ import sys
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+# A configuration's file may name a world builder and a reference of its
+# own (manifest.py): key -> (directory under paths[0], names the file exposes).
+NAMED = {"world_builder": ("worlds", ("build_world", "to_program")),
+         "reference": ("references", ("Reference",))}
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TRAFFIC_SUFFIXES = (".json", ".jsonl", ".toml", ".txt", ".csv")
 # Keys of a configuration that are widths (shapes): `reduced` may never
@@ -39,6 +44,54 @@ def _line(s, what, faults, limit=200):
             and "\n" not in s and "\t" not in s):
         faults.append(f"{what}: must be 1 to {limit} characters on one "
                       f"line with no tab, not {s!r}")
+
+
+def _exposes(path: str, names: tuple) -> list:
+    """The names of `names` that the file does not bind at its top level
+    (read, not imported: a world builder may import the program)."""
+    with open(path) as f:
+        tree = ast.parse(f.read(), path)
+    bound = set()
+    for node in tree.body:
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            bound.add(node.name)
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            bound.update((a.asname or a.name).split(".")[0]
+                         for a in node.names)
+        elif isinstance(node, ast.Assign):
+            bound.update(t.id for t in node.targets
+                         if isinstance(t, ast.Name))
+    return [n for n in names if n not in bound]
+
+
+def check_named(config: dict, what: str, home: str, under: str) -> list:
+    """The faults of a configuration's `world_builder` / `reference` keys."""
+    faults = []
+    for key, (folder, names) in NAMED.items():
+        if key not in config:
+            continue
+        name = config[key]
+        if not (isinstance(name, str) and NAME.match(name)):
+            faults.append(f"{what}: {key} {name!r} is not a name")
+            continue
+        path = os.path.join(home, folder, f"{name}.py")
+        if not os.path.isfile(path):
+            faults.append(f"{what}: {key} names {under}/{folder}/{name}.py, "
+                          f"which is not there")
+            continue
+        try:
+            missing = _exposes(path, names)
+        except SyntaxError as e:
+            faults.append(f"{what}: {under}/{folder}/{name}.py does not "
+                          f"parse: {e}")
+            continue
+        if missing:
+            faults.append(f"{what}: {under}/{folder}/{name}.py does not "
+                          f"expose {missing}")
+    if "world_builder" in config and "reference" not in config:
+        faults.append(f"{what}: names a world builder and no reference: the "
+                      f"default reference reads the default world only")
+    return faults
 
 
 def check(doc: dict, root: str) -> list:
@@ -120,9 +173,13 @@ def check(doc: dict, root: str) -> list:
             seen_files.add(f)
             try:
                 with open(os.path.join(root, f)) as fh:
-                    json.load(fh)
+                    body = json.load(fh)
             except ValueError as e:
                 faults.append(f"{what}: {f} is not JSON: {e}")
+            else:
+                faults += check_named(body if isinstance(body, dict) else {},
+                                      what,
+                                      os.path.join(root, paths[0]), paths[0])
         red = c.get("reduced")
         if not (isinstance(red, list) and len(red) <= 16):
             faults.append(f"{what}: reduced is a list of at most 16 keys")

@@ -7,11 +7,18 @@ has closed.  Numbers, each with a limit of its own (the traffic file's
 `limits`; PERF.md gives the readings they were set from):
 
   wrong_lanes         sampled lanes on which an exact statement fails
-                      (limit 0).  Per lane: the Service resolved; the DNAT
-                      target is one of its endpoints (or untouched); `code`
-                      equals the reference's verdict on the post-DNAT packet
-                      (REJECT where the Service has no endpoint); the rule
-                      named for a denial is the reference's; reject_kind
+                      (limit 0).  The statements come with the reference: a
+                      reference that defines `failed_statements(sample)`
+                      (manifest.py: `references/<name>.py`) states its
+                      deployment's own (reply legs, wide keys); one that
+                      does not gets `failed_statements` below, the first
+                      deployments' nine (either fills the sample's
+                      `ref_code` and `ref_rule`, which the lines for
+                      standard error print).  Per lane: the Service resolved;
+                      the DNAT target is one of its endpoints (or untouched);
+                      `code` equals the reference's verdict on the post-DNAT
+                      packet (REJECT where the Service has no endpoint); the
+                      rule named for a denial is the reference's; reject_kind
                       follows code and protocol; no reply or SNAT mark on
                       this one-directional ClusterIP traffic; `est` only on
                       an allowed flow that was sent in an earlier step;
@@ -30,6 +37,9 @@ has closed.  Numbers, each with a limit of its own (the traffic file's
                       stepped once more after the close; of its fresh lanes
                       that were committed, the share not established then.
                       A step that returns its state unchanged reads 1.
+
+The three step-level numbers and the limits' handling are the same for every
+cell: a reference may state more about a lane, it cannot drop one of them.
 """
 
 from __future__ import annotations
@@ -126,7 +136,8 @@ def decide(ref, sample: dict, steps: dict, replay, limits: dict):
     n = len(sample["code"])
     if n == 0:
         raise ValueError("the window closed with no lane to compare")
-    bad = failed_statements(ref, sample)
+    own = getattr(ref, "failed_statements", None)
+    bad = own(sample) if own is not None else failed_statements(ref, sample)
     wrong = int(np.logical_or.reduce(list(bad.values())).sum())
     hold("wrong_lanes", wrong)
     hold("short_miss_steps", int(np.sum(

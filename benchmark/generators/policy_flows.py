@@ -38,6 +38,13 @@ denials), so the generator reads the policy:
              seed offers the same mix of work: what the seed draws is the
              templates, which template a rank carries, the order of the
              lanes, the arrivals and the lanes the comparison samples.
+             A mix that names a `flow_seed` draws the templates, the
+             template of each rank and the hot source ports from it, and
+             only the ring's lanes and the sampled lanes from the run's
+             seed: the hot set is then the mix's, as the rule world is the
+             configuration's.  A sharded engine needs that: which replica the
+             Zipf head's elephants hash to decides how many lanes overflow it,
+             so a hot set per seed is a different load per seed (PERF.md s2).
   fresh      `fresh_lanes` lanes of every batch (0 = none; every
              batch/fresh_lanes-th lane, so arrivals lie among the other
              packets and not in one half) are connections
@@ -260,7 +267,9 @@ class Traffic:
         self.seed = seed
         self.batch = int(p["batch"])
         self.fresh_lanes = int(p.get("fresh_lanes", 0))
-        rng = np.random.default_rng([seed, 0])
+        flow_seed = p.get("flow_seed")
+        rng = np.random.default_rng(
+            [seed if flow_seed is None else int(flow_seed), 0])
         shares, pools = {}, {}
         found = _classes(rng, world, reference, p)
         for (kind, allowed), share in class_shares(p).items():
@@ -300,6 +309,8 @@ class Traffic:
             hot[ranks] = rows[rng.integers(0, len(rows), size=len(ranks))]
         hot_sport = rng.integers(1024, _FRESH_PORT0, size=n_hot)
         cdf = np.cumsum(weights) / weights.sum()
+        if flow_seed is not None:  # the hot set was the mix's; the lanes are
+            rng = np.random.default_rng([seed, 1])  # the run's
         self.ring = []
         for _ in range(int(p["ring"])):
             idx = np.minimum(np.searchsorted(cdf, rng.random(self.batch)),

@@ -26,20 +26,22 @@ import numpy as np
 import correct
 import reduce_trace
 from manifest import HERE, Manifest, load_json, load_module
-from reference import Reference
-from world import build_world, to_program
 
-# What is taken from every sampled lane of a StepResult.
+# What is taken from every sampled lane of a StepResult; of the batch, every
+# column the generator sent (Window.take).
 _ANSWER_FIELDS = ("code", "est", "committed", "svc_idx", "dnat_ip",
                   "dnat_port", "reply", "reject_kind", "snat",
                   "ingress_rule", "egress_rule")
-_PACKET_FIELDS = ("src_ip", "dst_ip", "proto", "src_port", "dst_port")
 # `now` handed to step: whole seconds of the run's wall clock from a fixed
 # base, so that conntrack aging runs as on a node.
 _NOW_BASE = 1000
 # A traced run keeps the profiler on for this long, from a quarter of the
 # window on; traces are large and tracing slows the host.
 _TRACE_SECONDS = 3.0
+# A traced run puts the program's step records aside every so many steps:
+# the program's ring keeps its last 4,096, and a window of short steps has
+# more (the per-layer readers are held to the WHOLE window, step_spans.py).
+_KEEP_RECORDS_EVERY = 1024
 
 
 class NoDevice(SystemExit):
@@ -122,13 +124,24 @@ class Window:
         self.gc = []  # (generation, seconds) of every collection inside it
         self.failed_lanes = 0
         self.error = None
-        self.sample = {f: [] for f in _ANSWER_FIELDS + _PACKET_FIELDS
-                       + ("fresh",)}
+        self.sample = {f: [] for f in _ANSWER_FIELDS + ("fresh",)}
         self.last = None  # (columns, StepResult) of the last step
+        self.records = []  # the program's step records, put aside (traced)
+
+    def keep_records(self, engine) -> None:
+        read = getattr(engine, "step_trace", None)
+        trace = read() if read is not None else None
+        if trace:
+            self.records.append(trace["records"])
 
     def take(self, cols: dict, res, lanes, fresh) -> None:
-        for f in _PACKET_FIELDS:
-            self.sample[f].append(np.asarray(cols[f])[lanes])
+        """The sample carries what the batch carried: every column the
+        generator sent that is not None, so that a reference which states
+        something about `src_ip6` or `tcp_flags` finds it there."""
+        for f, column in cols.items():
+            if column is not None:
+                self.sample.setdefault(f, []).append(
+                    np.asarray(column)[lanes])
         for f in _ANSWER_FIELDS:
             v = getattr(res, f)
             self.sample[f].append(
@@ -202,6 +215,8 @@ def run_window(engine, traffic, seconds: float, t_process: float,
         w.n_established += int(np.count_nonzero(res.est))
         w.take(cols, res, lanes, fresh)
         w.last = (cols, res)
+        if trace_dir and len(w.t_verdict) % _KEEP_RECORDS_EVERY == 0:
+            w.keep_records(engine)
         w.t_done.append(clock())
     gc.callbacks.remove(on_gc)
     if trace_until is not None:
@@ -264,15 +279,16 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     # -- set-up ------------------------------------------------------------
     t0 = time.perf_counter()
-    world = build_world(config["world"], config["world_seed"])
-    ps, services = to_program(world)
+    worlds = load_module(manifest.world_path(config))
+    world = worlds.build_world(config["world"], config["world_seed"])
+    ps, services = worlds.to_program(world)
     t_world = time.perf_counter()
     engine = build_engine(config, devices)
     engine.install_bundle(ps, services)
     install_s = time.perf_counter() - t_world
     # The traffic reads the policy (which flows it allows), so the reference
     # is built in set-up; the comparison uses the same one after the close.
-    reference = Reference(world)
+    reference = load_module(manifest.reference_path(config)).Reference(world)
     generator = load_module(manifest.generator_path(mix["generator"]))
     traffic = generator.Traffic(mix, world, seed, reference)
     say(traffic.summary)

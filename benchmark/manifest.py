@@ -7,6 +7,14 @@ per-layer metric is a file of its own, found by the name in the manifest:
   traffic mix    <paths[0]>/traffic/<traffic>.json, naming its generator
   generator      <paths[0]>/generators/<generator>.py
   layer metric   <paths[0]>/layers/<metric name>.py
+  world builder  <paths[0]>/worlds/<configuration's "world_builder">.py:
+                 build_world(params, seed) and to_program(world)
+  reference      <paths[0]>/references/<configuration's "reference">.py:
+                 Reference(world, keep_policy=None); it may bring its own
+                 failed_statements(sample) (correct.py)
+
+A configuration that names neither reads <paths[0]>/world.py and
+<paths[0]>/reference.py, the first deployments' own.
 """
 
 from __future__ import annotations
@@ -14,6 +22,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -47,6 +56,17 @@ class Manifest:
     def layer_path(self, metric: str) -> str:
         return os.path.join(self.home, "layers", f"{metric}.py")
 
+    def world_path(self, config: dict) -> str:
+        return self._named(config, "world_builder", "worlds", "world.py")
+
+    def reference_path(self, config: dict) -> str:
+        return self._named(config, "reference", "references", "reference.py")
+
+    def _named(self, config: dict, key: str, folder: str, default: str):
+        if key not in config:
+            return os.path.join(self.home, default)
+        return os.path.join(self.home, folder, f"{config[key]}.py")
+
     def metrics_of(self, cell: str, group: str) -> list:
         """The metrics of `group` (end_to_end | per_layer) this cell is
         asked for: those without a `workloads` key, or naming the cell."""
@@ -64,5 +84,6 @@ def load_module(path: str):
         "-", "_")
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # a module that defines dataclasses is looked up
     spec.loader.exec_module(mod)
     return mod

@@ -21,8 +21,12 @@ the loop, stay outside; four loops of ~20 us in the fast path fall inside.
 Reduced form (times in seconds):
   window_s   first bench.* span's start to the last one's end
   busy_s     union of device-op intervals inside the window, mean over chips
+  chips      how many chips ran an op inside the window
   modules    {module: {"busy_s": union of its ops,
-                       "while_s": union of its ops inside a while}}
+                       "while_s": union of its ops inside a while}}, each
+             the mean over chips of the PER-CHIP unions: a sharded module
+             runs on every chip at the same time, and its time is a
+             replica's, not the overlap of all of them
   steps      [[start_s, dur_s, device busy inside it], ...] per bench.step
   top_ops    the ten op names with most device time, [[name, seconds], ...]
   top_gaps   the ten longest intervals with no op on any chip, labelled by
@@ -130,10 +134,10 @@ def reduce(loaded: dict) -> dict:
         if b <= a:
             continue
         per_chip[chip].append([a, b])
-        m = modules.setdefault(module, {"all": [], "while": []})
-        m["all"].append([a, b])
+        m = modules.setdefault(module, {"all": {}, "while": {}})
+        m["all"].setdefault(chip, []).append([a, b])
         if in_while:
-            m["while"].append([a, b])
+            m["while"].setdefault(chip, []).append([a, b])
         by_name.setdefault(name, []).append([a, b])
     busy = {c: _union(v) for c, v in per_chip.items()}
     busy_s = (sum(_length(v) for v in busy.values()) / len(chips) / 1e9
@@ -160,16 +164,19 @@ def reduce(loaded: dict) -> dict:
         if a > edge:
             gaps.append([client_in((edge + a) / 2), (a - edge) / 1e9])
         edge = max(edge, b)
-    out_modules = {}
-    for module, m in modules.items():
-        whole = _union(m["all"])
-        out_modules[module] = {
-            "busy_s": _length(whole) / len(chips) / 1e9,
-            "while_s": _length(_union(m["while"])) / len(chips) / 1e9}
+
+    def mean_s(by_chip: dict) -> float:
+        return sum(_length(_union(v)) for v in by_chip.values()) / len(
+            chips) / 1e9
+
+    out_modules = {module: {"busy_s": mean_s(m["all"]),
+                            "while_s": mean_s(m["while"])}
+                   for module, m in modules.items()}
     top_ops = sorted(([n, _length(v) / 1e9] for n, v in by_name.items()),
                      key=lambda t: -t[1])[:10]
     return {"window_s": (hi - lo) / 1e9, "busy_s": busy_s,
-            "modules": out_modules, "steps": steps, "top_ops": top_ops,
+            "chips": len(chips), "modules": out_modules, "steps": steps,
+            "top_ops": top_ops,
             "top_gaps": sorted(gaps, key=lambda t: -t[1])[:10]}
 
 

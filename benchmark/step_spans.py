@@ -13,19 +13,27 @@ import numpy as np
 
 def window_records(ctx):
     """The records whose `step` span began inside the timed window; None
-    where the ring no longer covers the window (it keeps the last 4,096
-    steps: a median over the window's tail alone would say nothing of it)."""
+    where they no longer cover the window (the ring keeps the last 4,096
+    steps: a median over the window's tail alone would say nothing of it).
+    A traced window of more steps than that put its records aside as it went
+    (`harness.Window.keep_records`); they are joined here by `seq`."""
     read = getattr(ctx["engine"], "step_trace", None)
     w = ctx["window"]
     trace = read() if read is not None and w.t_verdict else None
     if not trace:
         return None
     rec = trace["records"]
+    aside = getattr(w, "records", None)
+    if aside:
+        rec = np.concatenate(aside + [rec])
+        rec = rec[np.unique(rec["seq"], return_index=True)[1]]
     began = rec["t_start"]
     opened = w.t_handoff[0] * 1e9
     if trace["dropped"] and len(rec) and began[0] > opened:
         return None
     rec = rec[(began >= opened) & (began <= w.t_verdict[-1] * 1e9)]
+    if aside and len(rec) and np.any(np.diff(rec["seq"]) != 1):
+        return None  # a stretch of the window fell between two readings
     return rec if len(rec) else None
 
 

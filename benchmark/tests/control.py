@@ -33,12 +33,14 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [os.path.dirname(HERE), os.path.dirname(os.path.dirname(HERE))]
 
 import correct  # noqa: E402
-from reference import Reference  # noqa: E402
 
 
 def read(ctx: dict) -> dict:
     s = dict(ctx["sample"])
-    half = Reference(ctx["world"], keep_policy=lambda i: i % 2 == 0)
+    # The control is built from the class of the reference it was handed:
+    # a configuration that brings its own reference brings its control.
+    half = type(ctx["reference"])(ctx["world"],
+                                  keep_policy=lambda i: i % 2 == 0)
     proto = np.asarray(s["proto"], np.int64)
     _, no_ep = half.resolve(correct._u32(s["dst_ip"]), proto,
                             np.asarray(s["dst_port"], np.int64))

@@ -93,12 +93,18 @@ def test_the_committed_manifest_asks_the_one_chip_cells_for_it():
     `round_lanes` sums the replicas' foreign walks and the spill retry, so
     their quotient is no share of the rounds."""
     m = Manifest()
-    (entry,) = [x for x in load_json(os.path.join(ROOT, "BENCHMARK.json"))[
-        "per_layer"] if x["name"] == NAME]
-    one_chip = ["np100k.churn", "np100k.steady", "acnp10k.churn"]
+    doc = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    (entry,) = [x for x in doc["per_layer"] if x["name"] == NAME]
+    one_chip = ["np100k.churn", "np100k.steady", "acnp10k.churn",
+                "np100k.steady_b4k"]
+    listed = entry.pop("workloads")
     assert entry == {"name": NAME, "unit": "%", "better": "higher",
                      "source": "program_counter", "layer": "slowpath",
-                     "moves": "served_pps", "workloads": one_chip}
+                     "moves": "served_pps"}
+    # a later PR's cell may join the list (the test does not pin it), as
+    # long as it is a one-chip cell
+    assert set(one_chip) <= set(listed)
+    assert all(m.cell(c)["chips"] == 1 for c in listed)
     for cell in one_chip:
         assert NAME in {x["name"] for x in m.metrics_of(cell, "per_layer")}
     assert NAME not in {x["name"] for x in m.metrics_of(

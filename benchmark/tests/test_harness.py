@@ -43,6 +43,11 @@ TINY_CELLS = [
     ("tiny_flip_code.churn", "tiny_flip_code", "tiny_churn", 1),
     ("tiny_half_batch.churn", "tiny_half_batch", "tiny_churn", 1),
     ("tiny_state_unchanged.churn", "tiny_state_unchanged", "tiny_churn", 1),
+    # the same faults under the mix without arrivals (np100k.steady,
+    # np100k.steady_b4k): no replay and no fresh lane to lean on
+    ("tiny_flip_code.steady", "tiny_flip_code", "tiny_steady", 1),
+    ("tiny_half_batch.steady", "tiny_half_batch", "tiny_steady", 1),
+    ("tiny_state_unchanged.steady", "tiny_state_unchanged", "tiny_steady", 1),
 ]
 
 
@@ -181,6 +186,43 @@ def test_reduce_trace_on_the_recorded_trace():
     assert r["top_gaps"][0][0] == want["longest_gap_in"]
 
 
+def test_reduce_reads_a_sharded_module_per_chip():
+    """Four replicas run the step module at the same time.  Chip 0 runs it
+    0-10 ms (its loop 2-6), chip 1 5-15 (5-9), chip 2 0-20 (no loop), chip 3
+    10-12 (10-12): 10 + 10 + 20 + 2 over four chips is 10.5 ms a step, 2.5
+    of it in a loop.  The union ACROSS chips (what `reduce` took until PR 33)
+    is 20 ms, over four chips 5 (and 2.25): under every replica's own time
+    but one."""
+    ms = 1_000_000
+    ops = []
+    for chip, (a, b), loop in ((0, (0, 10), (2, 6)), (1, (5, 15), (5, 9)),
+                               (2, (0, 20), None), (3, (10, 12), (10, 12))):
+        ops.append([chip, "fusion.1", a * ms, (b - a) * ms, "jit_step", False])
+        if loop:
+            ops.append([chip, "while.2", loop[0] * ms,
+                        (loop[1] - loop[0]) * ms, "jit_step", True])
+    ops.append([1, "copy.9", 21 * ms, 2 * ms, "jit_other", False])
+    r = reduce_trace.reduce({"ops": ops,
+                             "spans": [["bench.step", 0, 25 * ms]]})
+    assert r["chips"] == 4
+    got = reduce_trace.step_device_ms(r, {"trace": {"step_modules": "step"}})
+    assert got["all"] == pytest.approx((10 + 10 + 20 + 2) / 4)
+    assert got["while"] == pytest.approx((4 + 4 + 0 + 2) / 4)
+    assert r["modules"]["jit_other"]["busy_s"] == pytest.approx(2e-3 / 4)
+    # the device's busy time was per chip before, and stays: 10, 12, 20, 2
+    assert r["busy_s"] == pytest.approx((10 + 12 + 20 + 2) / 4 * 1e-3)
+    # a replica's lanes over a replica's time: the roofline reader's count
+    reader = harness.load_module(os.path.join(BENCH, "layers",
+                                              "step_roofline.py"))
+    w = harness.Window()
+    w.lanes, w.n_miss = [4000], [40]
+    share = reader.read({"reduced": r, "window": w,
+                         "config": {"trace": {"step_modules": "step"}},
+                         "peak": {"hbm_bytes_per_s": 1e9}})
+    assert share == pytest.approx(
+        100 * work.least_seconds(1000, 10, 1e9) * 1e3 / 10.5)
+
+
 # -- the manifest check -------------------------------------------------------
 
 def _bad(doc, edit):
@@ -265,14 +307,17 @@ def test_mesh_cell_on_four_virtual_devices(tree):
     assert r["check"]["wrong_lanes"]["value"] == 0
 
 
-@pytest.mark.parametrize("fault, number", [
-    ("flip_code", "wrong_lanes"),
-    ("half_batch", "wrong_lanes"),
-    ("half_batch", "short_miss_steps"),
-    ("state_unchanged", "replay_unhit_share"),
+@pytest.mark.parametrize("fault, mix, number", [
+    ("flip_code", "churn", "wrong_lanes"),
+    ("half_batch", "churn", "wrong_lanes"),
+    ("half_batch", "churn", "short_miss_steps"),
+    ("state_unchanged", "churn", "replay_unhit_share"),
+    ("flip_code", "steady", "wrong_lanes"),
+    ("half_batch", "steady", "wrong_lanes"),
+    ("state_unchanged", "steady", "remiss_share"),
 ])
-def test_a_broken_timed_path_is_not_correct(tree, fault, number):
-    r = run(tree, f"tiny_{fault}.churn")
+def test_a_broken_timed_path_is_not_correct(tree, fault, mix, number):
+    r = run(tree, f"tiny_{fault}.{mix}")
     assert r["correct"] is False
     n = r["check"][number]
     assert n["value"] > n["limit"]

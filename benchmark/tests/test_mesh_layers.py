@@ -125,6 +125,14 @@ def test_the_committed_manifest_holds_the_mesh_cell():
     assert (mix["batch"], mix["universe_flows"]) == (524288, 131072)
     differ = {k for k in one if mix[k] != one[k]}
     assert differ == {"batch", "universe_flows", "assumed"}
+    # the hot set is the mix's own (PR 33): the seed no longer decides on
+    # which replica the Zipf head lands
+    assert set(mix) - set(one) == {"flow_seed"}
+    small = load_json(m.traffic_path("steady_b4k"))
+    assert {k for k in one if small[k] != one[k]} == {
+        "batch", "ring", "sample_lanes_per_step", "assumed"}
+    assert set(small) == set(one)
+    assert small["batch"] * small["ring"] == one["batch"] * one["ring"]
     asked = {x["name"]: x for x in m.metrics_of("np100k.steady_mesh4",
                                                 "per_layer")}
     for n in MESH:
