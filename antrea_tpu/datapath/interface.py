@@ -32,6 +32,7 @@ from __future__ import annotations
 import enum
 from abc import ABC, abstractmethod
 from dataclasses import dataclass
+from functools import cached_property
 from typing import Optional
 
 import numpy as np
@@ -39,6 +40,7 @@ import numpy as np
 from ..apis.service import ServiceEntry
 from ..compiler.ir import PolicySet
 from ..packet import PacketBatch
+from ..utils import ip as iputil
 
 
 class DatapathType(str, enum.Enum):
@@ -131,6 +133,54 @@ class StepResult:
     # lists because v6 addresses exceed any numpy integer lane width.
     dnat_key: list = None  # post-DNAT dst (reply lanes: un-DNAT rewrite)
     peer_key: list = None  # tunnel peer (FWD_TUNNEL lanes; else 0)
+
+
+class WideStepResult(StepResult):
+    """The StepResult of a dual-stack tpuflow engine: the same fields, with
+    the two wide views built when they are first read and not in `step`.
+
+    The step hands over what the device wrote — the (B, 4) sign-flipped
+    word rows of the post-DNAT destination and of the tunnel peer, and the
+    mask of deliverable tunnel lanes — and `step` does nothing else with
+    them, so its `attribute` phase costs the same whatever share of the
+    lanes is v6.  `dnat_words` / `peer_words` are the columns for a reader
+    that stays in numpy ((B, 4) u32, RFC 4291 v4-mapped rows for v4 lanes;
+    a peer row is all zero off the tunnel lanes); `dnat_key` / `peer_key`
+    are the per-lane lists of combined-keyspace Python ints that
+    `StepResult` documents (a v6 key passes any numpy lane, so building
+    them costs Python work for every v6 lane: utils/ip.words_to_keys).
+    Each is computed once and kept.
+    """
+
+    def __init__(self, dnat_w_f=None, peer_w_f=None, tunnel=None, **fields):
+        super().__init__(**fields)
+        self._wide = (dnat_w_f, peer_w_f, tunnel)
+        if dnat_w_f is not None:
+            # The dataclass wrote its None defaults into the instance; take
+            # them out so that the first read reaches the properties below.
+            # (A copy by `dataclasses.replace` comes without the word rows
+            # and keeps the lists it was handed.)
+            del self.__dict__["dnat_key"], self.__dict__["peer_key"]
+
+    @cached_property
+    def dnat_words(self) -> np.ndarray:
+        return iputil.unflip_u32_array(self._wide[0])
+
+    @cached_property
+    def peer_words(self) -> np.ndarray:
+        # The kernel zeroes peer_w on non-deliverable lanes; un-flipping
+        # that 0 would read 0x80000000 a word.
+        _, peer_w_f, tunnel = self._wide
+        return np.where(tunnel[:, None], iputil.unflip_u32_array(peer_w_f),
+                        0).astype(np.uint32)
+
+    @cached_property
+    def dnat_key(self) -> list:
+        return iputil.words_to_keys(self.dnat_words)
+
+    @cached_property
+    def peer_key(self) -> list:
+        return iputil.words_to_keys(self.peer_words, keep=self._wide[2])
 
 
 class Datapath(ABC):

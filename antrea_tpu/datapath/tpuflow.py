@@ -28,6 +28,7 @@ its rule is gone; ref network_policy.go ct_label persistence).
 
 from __future__ import annotations
 
+import functools
 import time
 from collections import Counter
 from typing import Optional
@@ -59,7 +60,8 @@ from ..config import ConfigError
 from . import persist
 from .audit import AuditableDatapath
 from .commit import TransactionalDatapath, pad_probes
-from .interface import Datapath, DatapathStats, DatapathType, StepResult
+from .interface import (Datapath, DatapathStats, DatapathType, StepResult,
+                        WideStepResult)
 from .maintenance import MaintainableDatapath
 from .slowpath import ADMIT_HOLD
 from .tenancy import TenantedDatapath, TenantSpec
@@ -618,6 +620,8 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         # pure-IP batches keep the round-3 compiled program.
         arp = batch.arp_ops() if batch.arp_op is not None else None
         v6 = self._v6_host(batch)
+        if batch.is6 is not None:  # a narrow engine was handed none set
+            tr.v6_lanes = int(np.count_nonzero(batch.is6))
         # Serving-batcher padding mask: padded lanes ride the spoof
         # discipline (no state commit / miss admission / counters); None
         # traces the identical program, so the unbatched path stays
@@ -690,34 +694,19 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         tr.phase(SP_ATTRIBUTE)
         unflip = iputil.unflip_u32_array
 
-        def keys_of(wide_col, keep=True):
-            """(B, 4) flipped word rows -> per-lane combined keys, 0 on
-            the lanes a `keep` mask leaves out.
-            Vectorized for the common case: v4-mapped rows (word 3 IS the
-            key) take one numpy pass; Python big-int math runs only for
-            lanes carrying a real v6 address."""
-            words = unflip(wide_col).astype(np.int64)
-            mapped = ((words[:, 0] == 0) & (words[:, 1] == 0)
-                      & (words[:, 2] == 0xFFFF))
-            keys = np.where(keep, words[:, 3], 0).tolist()
-            for i in np.nonzero(keep & ~mapped)[0]:
-                w = words[i]
-                keys[i] = iputil.V6_OFF + (
-                    (int(w[0]) << 96) | (int(w[1]) << 64)
-                    | (int(w[2]) << 32) | int(w[3])
-                )
-            return keys
-
         # peer_f / peer_w are zeroed for non-deliverable lanes in the
         # kernel; the (kind==TUNNEL & deliverable) gate avoids un-flipping
         # that 0 (and reports 0, not the mapped-zero key).
         tunnel = (o["fwd_kind"] == FWD_TUNNEL) & (o["out_port"] != -1)
-        dnat_key = peer_key = None
+        # A dual-stack engine hands the wide word rows on as they landed:
+        # the per-lane key lists are built when first read
+        # (interface.WideStepResult), never here.
+        result = StepResult
         if self._dual_stack:
-            dnat_key = keys_of(o["dnat_w_f"])
-            peer_key = keys_of(o["peer_w"], keep=tunnel)
+            result = functools.partial(WideStepResult, o["dnat_w_f"],
+                                       o["peer_w"], tunnel)
 
-        res = StepResult(
+        res = result(
             code=o["code"],
             est=o["est"],
             pending=pending,
@@ -742,8 +731,6 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             dec_ttl=o["dec_ttl"],
             tc_act=o["tc_act"],
             tc_port=o["tc_port"],
-            dnat_key=dnat_key,
-            peer_key=peer_key,
         )
         tr.phase(SP_DONE)
         return res

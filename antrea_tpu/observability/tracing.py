@@ -112,7 +112,9 @@ STEP_RING_SLOTS = 4096
 # the same call (0 on one chip).  `round_lanes` is the step program's own
 # count of the lanes its slow-path rounds were run at, padding included
 # (models/pipeline.round_ladder; the mesh sums its replicas and its spill
-# retry): n_miss over it is how full the rounds were.
+# retry): n_miss over it is how full the rounds were.  `v6_lanes` are the
+# lanes of the batch whose family mask `is6` is set, counted from the host
+# batch in `stage` (0 on a narrow engine, which refuses such a batch).
 STEP_RECORD = np.dtype(
     [("seq", "<i8"), ("lanes", "<i8"), ("n_miss", "<i8"),
      ("round_lanes", "<i8"), ("t_start", "<i8")]
@@ -120,7 +122,7 @@ STEP_RECORD = np.dtype(
     + [("t_done", "<i8"), ("t_end", "<i8"), ("h2d_transfers", "<i8"),
        ("h2d_bytes", "<i8"), ("d2h_transfers", "<i8"), ("d2h_bytes", "<i8")]
     + [(f"{s}_{e}", "<i8") for s, _ in STEP_SUBSPANS for e in ("t0", "t1")]
-    + [("spill_lanes", "<i8"), ("retry_lanes", "<i8")]
+    + [("spill_lanes", "<i8"), ("retry_lanes", "<i8"), ("v6_lanes", "<i8")]
 )
 _N_STAMPS = len(STEP_PHASES) + 3  # start, one per phase, done, end
 
@@ -164,14 +166,14 @@ class StepTracer:
         self.n_miss = self.round_lanes = 0
         self.h2d_transfers = self.h2d_bytes = 0
         self.d2h_transfers = self.d2h_bytes = 0
-        self.spill_lanes = self.retry_lanes = 0
+        self.spill_lanes = self.retry_lanes = self.v6_lanes = 0
 
     def begin(self, lanes: int) -> None:
         self._lanes = int(lanes)
         self.n_miss = self.round_lanes = 0
         self.h2d_transfers = self.h2d_bytes = 0
         self.d2h_transfers = self.d2h_bytes = 0
-        self.spill_lanes = self.retry_lanes = 0
+        self.spill_lanes = self.retry_lanes = self.v6_lanes = 0
         self._subs = [0] * (2 * len(STEP_SUBSPANS))
         self._span = self._annotate("tpuflow.step",
                                     seq=self.steps_total + 1)
@@ -232,7 +234,8 @@ class StepTracer:
         self._rows[(seq - 1) % self.slots] = (
             seq, self._lanes, self.n_miss, self.round_lanes, *ts,
             self.h2d_transfers, self.h2d_bytes, self.d2h_transfers,
-            self.d2h_bytes, *self._subs, self.spill_lanes, self.retry_lanes)
+            self.d2h_bytes, *self._subs, self.spill_lanes, self.retry_lanes,
+            self.v6_lanes)
         return (t - ts[0]) * 1e-9
 
     def records(self) -> np.ndarray:

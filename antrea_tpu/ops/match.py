@@ -1144,6 +1144,7 @@ def _searchsorted_right(bounds: jax.Array, x: jax.Array) -> jax.Array:
     return blk_c * K + inblock
 
 
+@device_scope("classify.index6")
 def _searchsorted6(bounds6: jax.Array, xw: jax.Array) -> jax.Array:
     """Lexicographic searchsorted(side='right') over 4-word v6 boundaries.
 

@@ -6,7 +6,9 @@ layer names its sections without depending on the observability plane
 models/forwarding, ops/match) — how the device's layers are told apart
 in a profiler trace: every op of the step lowers under a path of these,
 nested as listed (`probe`/`refresh`/`assemble` inside `fast_path`; the round-loop scopes and the `classify.*` stages inside
-`miss_detect`; `eviction_scan` inside `cache_commit`; `egress`, the
+`miss_detect`, `classify.index6` — the v6 interval search of a dual-stack
+engine, ops/match._searchsorted6 — inside `classify.candidate` or
+`classify.summary`; `eviction_scan` inside `cache_commit`; `egress`, the
 packing of the served step's outputs into one record, after them all).
 The ONE place the scope names are declared: call sites go through
 `device_scope`, which refuses any other name.
@@ -17,7 +19,8 @@ import jax
 STEP_SCOPES = (
     "fast_path", "probe", "refresh", "assemble", "forwarding", "miss_detect",
     "service_lb", "classify", "classify.summary", "classify.candidate",
-    "classify.scan", "cache_commit", "eviction_scan", "egress",
+    "classify.index6", "classify.scan", "cache_commit", "eviction_scan",
+    "egress",
 )
 
 

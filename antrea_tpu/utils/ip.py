@@ -187,6 +187,27 @@ def unflip_u32_array(col):
             ^ np.int32(-(2 ** 31))).astype(np.uint32)
 
 
+def words_to_keys(words, keep=True) -> list:
+    """(B, 4) u32 word rows -> per-lane combined keys (Python ints: a v6
+    key exceeds any numpy lane), 0 on the lanes a `keep` mask leaves out —
+    the column inverse of key_to_words.  A v4-mapped row IS its word 3;
+    any other row is V6_OFF + its 128-bit value, put together from two
+    64-bit halves so that only the lanes carrying a real v6 address cost a
+    Python operation, and those one shift and one add."""
+    import numpy as np
+
+    w = np.asarray(words).astype(np.uint64)
+    keep = np.broadcast_to(np.asarray(keep, bool), w.shape[:1])
+    mapped = (w[:, 0] == 0) & (w[:, 1] == 0) & (w[:, 2] == 0xFFFF)
+    keys = np.where(keep, w[:, 3], 0).tolist()
+    wide = np.nonzero(keep & ~mapped)[0]
+    hi = ((w[wide, 0] << np.uint64(32)) | w[wide, 1]).tolist()
+    lo = ((w[wide, 2] << np.uint64(32)) | w[wide, 3]).tolist()
+    for i, h, l in zip(wide.tolist(), hi, lo):
+        keys[i] = V6_OFF + ((h << 64) | l)
+    return keys
+
+
 def key_to_flipped_words(key: int) -> tuple[int, int, int, int]:
     """key_to_words with each word sign-flipped — the exact i32 lane values
     the device stores, for host/oracle twins that must hash or compare the

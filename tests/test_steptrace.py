@@ -163,10 +163,11 @@ def test_sub_spans_are_zero_on_one_chip(engine):
     kind, dp, _ = engine
     rec = dp.step_trace()["records"]
     assert [f"{s}_{e}" for s, _ in STEP_SUBSPANS for e in ("t0", "t1")] + [
-        "spill_lanes", "retry_lanes"] == list(STEP_RECORD.names[-6:])
+        "spill_lanes", "retry_lanes", "v6_lanes"] == list(
+            STEP_RECORD.names[-7:])
     assert {p for _, p in STEP_SUBSPANS} <= set(STEP_PHASES)
     if kind == "tpuflow":
-        for f in STEP_RECORD.names[-6:]:
+        for f in STEP_RECORD.names[-7:]:  # a narrow engine: no v6 lane
             assert (rec[f] == 0).all(), f
     else:  # the mesh routes every step; it retries only what spilled
         assert (rec["route_t1"] > rec["route_t0"]).all()
@@ -481,10 +482,12 @@ def test_last_commit_after_a_direct_install(world):
 
 def _lowered_text(dp):
     i32 = jnp.zeros(B, jnp.int32)
+    wide = jnp.zeros((B, 4), jnp.int32)
     return fwd.pipeline_step_full_packed.lower(
         dp._state, dp._drs, dp._dsvc, dp._dft, i32, i32, i32, i32, i32, i32,
-        jnp.int32(1), jnp.int32(1), i32, None, None,
-        meta=dp._meta_step).as_text(debug_info=True)
+        jnp.int32(1), jnp.int32(1), i32, None, None, meta=dp._meta_step,
+        v6=(wide, wide, i32) if dp._dual_stack else None,
+    ).as_text(debug_info=True)
 
 
 def _mesh_lowered_text(dp):
@@ -514,19 +517,24 @@ SLOW_PATH = ("miss_detect", "service_lb", "classify", "classify.candidate",
              "classify.scan", "cache_commit", "eviction_scan")
 FULL_STEP = ("forwarding", "egress")  # models/forwarding's, not the drain's
 # selection -> (engine kind, knobs, lowering, scopes its program leaves out)
+V4 = ("classify.index6",)  # the v6 interval search: dual-stack engines only
 SELECTIONS = {
-    "default": ("tpuflow", {}, _lowered_text, ("classify.summary",)),
+    "default": ("tpuflow", {}, _lowered_text, V4 + ("classify.summary",)),
+    "dual_stack": ("tpuflow", {"dual_stack": True}, _lowered_text,
+                   ("classify.summary",)),
     "fused": ("tpuflow", {"fused": True}, _lowered_text,
-              ("classify.summary",)),
-    "prune_budget": ("tpuflow", {"prune_budget": 2}, _lowered_text, ()),
+              V4 + ("classify.summary",)),
+    "prune_budget": ("tpuflow", {"prune_budget": 2}, _lowered_text, V4),
     "fused+prune_budget": ("tpuflow", {"fused": True, "prune_budget": 2},
-                           _lowered_text, ()),
+                           _lowered_text, V4),
     "async_fast_step": ("tpuflow", {"async_slowpath": True,
                                     "drain_batch": 64}, _lowered_text,
-                        SLOW_PATH + ("classify.summary",)),
+                        V4 + SLOW_PATH + ("classify.summary",)),
     "async_drain": ("tpuflow", {"async_slowpath": True, "drain_batch": 64},
-                    _drain_lowered_text, FULL_STEP + ("classify.summary",)),
-    "mesh_step": ("mesh", {}, _mesh_lowered_text, ("classify.summary",)),
+                    _drain_lowered_text,
+                    V4 + FULL_STEP + ("classify.summary",)),
+    "mesh_step": ("mesh", {}, _mesh_lowered_text,
+                  V4 + ("classify.summary",)),
 }
 KERNEL_OF = {"fused": "classify_consumer",
              "fused+prune_budget": "classify_pruned_consumer"}
