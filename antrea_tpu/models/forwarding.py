@@ -105,7 +105,8 @@ def _lp_row(dft: DeviceForwardingTables, ip_f: jax.Array):
 
 def _row_eq_wide(table: jax.Array, n: jax.Array, xw: jax.Array):
     """-> (row, known) exact 4-word row match (all-pairs — per-node v6
-    tables are small; same shape rationale as ops/match._searchsorted6)."""
+    tables are small: the shape ops/match._searchsorted6 keeps up to its
+    flat size)."""
     cap = table.shape[0]
     eq = (table[None, :, :] == xw[:, None, :]).all(axis=2)  # (B, cap)
     eq = eq & (jnp.arange(cap, dtype=jnp.int32) < n[0])[None, :]

@@ -715,8 +715,8 @@ def _service_lb(
 
     if is6 is not None and dsvc.uip6_w.shape[0] > 0:
         # v6 frontend probe: exact 4-word match (all-pairs — the v6
-        # frontend table is small; same shape rationale as
-        # ops/match._searchsorted6).
+        # frontend table is small: the shape ops/match._searchsorted6
+        # keeps up to its flat size).
         eq6 = (dsvc.uip6_w[None, :, :] == daddr[:, None, :]).all(axis=2)
         ip6_hit = eq6.any(axis=1)
         row6 = jnp.argmax(eq6, axis=1)

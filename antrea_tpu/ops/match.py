@@ -631,13 +631,20 @@ def pad_ruleset_entries(
 # ---------------------------------------------------------------------------
 
 
+def _lex_le_words(a, b) -> jax.Array:
+    """Lexicographic a <= b over four words, the most significant first,
+    indexed on the LEADING axis (per-word flipped i32)."""
+    a0, a1, a2, a3 = a
+    b0, b1, b2, b3 = b
+    return (a0 < b0) | ((a0 == b0) & ((a1 < b1) | ((a1 == b1) & (
+        (a2 < b2) | ((a2 == b2) & (a3 <= b3))))))
+
+
 def _lex_le4(a: jax.Array, b: jax.Array) -> jax.Array:
     """Lexicographic a <= b over a trailing 4-word axis (per-word flipped
     i32 — the same compare _searchsorted6 builds from)."""
-    lt = a < b
-    eq = a == b
-    return lt[..., 0] | (eq[..., 0] & (lt[..., 1] | (eq[..., 1] & (
-        lt[..., 2] | (eq[..., 2] & (lt[..., 3] | eq[..., 3]))))))
+    return _lex_le_words([a[..., w] for w in range(4)],
+                         [b[..., w] for w in range(4)])
 
 
 def _delta_lane_match(ip_f, dt: DeltaTable, i, wide):
@@ -1109,6 +1116,7 @@ def _resolve(action: jax.Array, hits, pod_iso: jax.Array):
     return code.astype(jnp.int32), rule.astype(jnp.int32)
 
 
+_SS_FLAT = 4096  # boundaries up to which all pairs beat the two levels
 _SS_BLOCK = 256  # ~sqrt(NB) at the 100k-rule scale; compares/pkt = NB/256+256
 
 
@@ -1125,7 +1133,7 @@ def _searchsorted_right(bounds: jax.Array, x: jax.Array) -> jax.Array:
     VPU work with static shapes (vmap/shard_map friendly).
     """
     nb = bounds.shape[0]
-    if nb <= 4096:
+    if nb <= _SS_FLAT:
         return jnp.searchsorted(bounds, x, side="right", method="compare_all")
     K = _SS_BLOCK
     nblk = -(-nb // K)
@@ -1144,20 +1152,47 @@ def _searchsorted_right(bounds: jax.Array, x: jax.Array) -> jax.Array:
     return blk_c * K + inblock
 
 
+# v6 rows a block.  On the chip 128 and 256 read alike, 512 worse (PERF.md s6).
+_SS6_BLOCK = 128
+
+
 @device_scope("classify.index6")
 def _searchsorted6(bounds6: jax.Array, xw: jax.Array) -> jax.Array:
     """Lexicographic searchsorted(side='right') over 4-word v6 boundaries.
 
     bounds6 (N, 4) and xw (B, 4) are per-word sign-flipped i32, so word-wise
-    signed compares give unsigned lexicographic order.  v6 boundary tables
-    are small (group CIDR endpoints), so all-pairs compare-count is the
-    right TPU shape (see _searchsorted_right's rationale).
+    signed compares give unsigned lexicographic order.  The same two sizes
+    as _searchsorted_right, and the same indices from both: up to _SS_FLAT
+    boundaries an all-pairs compare-count; beyond (a peer dimension of a
+    100k-rule dual-stack node holds ~15k) the all-pairs compare over the
+    ~N/K block-last rows picks the block, one gather of that block a lane
+    and the compare-count inside it finish.  A block is a row of 4*K words,
+    word plane after word plane, so the gathered window has K as its minor
+    axis and never the 4 words.
     """
     n = bounds6.shape[0]
     if n == 0:
         return jnp.zeros(xw.shape[0], dtype=jnp.int32)
-    leq = _lex_le4(bounds6[None, :, :], xw[:, None, :])  # (B, N)
-    return leq.sum(axis=1, dtype=jnp.int32)
+    if n <= _SS_FLAT:
+        leq = _lex_le4(bounds6[None, :, :], xw[:, None, :])  # (B, N)
+        return leq.sum(axis=1, dtype=jnp.int32)
+    K = _SS6_BLOCK
+    nblk = -(-n // K)
+    # This function's own pads are all-ones rows, as pad_ruleset_entries'
+    # rows and a genuine ffff:...:ffff boundary are: position alone tells
+    # them apart (valid, below).
+    planes = jnp.concatenate(
+        [bounds6, jnp.full((nblk * K - n, 4), _PAD_BOUND, bounds6.dtype)]
+    ).T.reshape(4, nblk, K)
+    x = xw.T[:, :, None]  # (4, B, 1)
+    blk = _lex_le_words(planes[:, None, :, -1], x).sum(axis=1, dtype=jnp.int32)
+    blk_c = jnp.minimum(blk, nblk - 1)
+    blocks = planes.transpose(1, 0, 2).reshape(nblk, 4 * K)
+    window = blocks[blk_c].reshape(-1, 4, K).transpose(1, 0, 2)  # (4, B, K)
+    off = jnp.arange(K, dtype=jnp.int32)
+    valid = (blk_c[:, None] * K + off[None, :]) < n
+    inblock = (_lex_le_words(window, x) & valid).sum(axis=1, dtype=jnp.int32)
+    return blk_c * K + inblock
 
 
 @device_scope("classify")

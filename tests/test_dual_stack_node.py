@@ -36,6 +36,7 @@ from antrea_tpu.datapath import OracleDatapath, TpuflowDatapath
 from antrea_tpu.datapath.interface import StepResult, WideStepResult
 from antrea_tpu.models import forwarding as fwd
 from antrea_tpu.observability.tracing import STEP_PHASES, STEP_RECORD
+from antrea_tpu.ops import match
 from antrea_tpu.packet import PacketBatch
 from antrea_tpu.simulator import gen_cluster, gen_services, gen_traffic
 from antrea_tpu.utils import ip as iputil
@@ -189,6 +190,67 @@ def test_three_mixed_steps_answer_like_the_scalar_twin(served, world, field):
     if field in ("ingress_rule", "egress_rule"):
         named = np.array([r is not None for r in getattr(first, field)])
         assert named[is6].any() and named[~is6].any()
+
+
+N_BLOCKS = 2400  # distinct v6 CIDRs of one peer: two boundaries each
+
+
+def _block_policy(pods) -> tuple:
+    """An ACNP over every pod, ahead of every other policy, whose one
+    ingress rule drops N_BLOCKS v6 /64s (every second /64 under
+    2001:db8:ff00::/40, so no two merge) -> (group, policy)."""
+    both = [cp.GroupMember(ip=ip) for p in pods for ip in (p, _twin(p))]
+    blocks = [cp.IPBlock(cidr=f"2001:db8:ff{i >> 7:02x}:{(i & 127) * 2:x}::/64")
+              for i in range(N_BLOCKS)]
+    return cp.AppliedToGroup("every-pod", both), cp.NetworkPolicy(
+        uid="drop-v6-blocks", name="drop-v6-blocks",
+        type=cp.NetworkPolicyType.ACNP, applied_to_groups=["every-pod"],
+        tier_priority=50, priority=1.0, rules=[cp.NetworkPolicyRule(
+            direction=cp.Direction.IN, action=cp.RuleAction.DROP, priority=0,
+            from_peer=cp.NetworkPolicyPeer(ip_blocks=blocks))])
+
+
+def _from_the_blocks(batch: PacketBatch, seed: int) -> PacketBatch:
+    """The batch with the source of every second v6 lane moved into one of
+    the policy's /64s or into the /64 after it, which no block holds."""
+    rng = np.random.default_rng(seed)
+    src6 = batch.src_ip6.copy()
+    lanes = np.nonzero(batch.is6)[0][::2]
+    i = rng.integers(0, N_BLOCKS, lanes.size)
+    src6[lanes, 0] = 0x2001_0DB8
+    src6[lanes, 1] = ((0xFF00 | (i >> 7)) << 16) | (
+        (i & 127) * 2 + rng.integers(0, 2, lanes.size))
+    src6[lanes, 2:] = rng.integers(0, 2**32, (lanes.size, 2))
+    return dataclasses.replace(batch, src_ip6=src6.astype(np.uint32))
+
+
+def test_a_peer_dimension_over_the_flat_size_serves_like_the_twin(world):
+    """The v6 interval search in blocks, inside the step: a world whose
+    ingress peer dimension holds more v6 boundaries than `_SS_FLAT`."""
+    ps, services, batches = world
+    ps = copy.deepcopy(ps)
+    pods = sorted({m.ip for g in ps.applied_to_groups.values()
+                   for m in g.members if not iputil.is_v6(m.ip)})
+    group, policy = _block_policy(pods)
+    ps.applied_to_groups[group.name] = group
+    ps.policies.append(policy)
+    dp = TpuflowDatapath(ps, services, dual_stack=True, **KW)
+    n6 = dp._drs.ingress.peer.bounds6.shape[0]
+    assert n6 > match._SS_FLAT >= dp._drs.egress.peer.bounds6.shape[0]
+    twin = OracleDatapath(ps, services, dual_stack=True, **{
+        k: v for k, v in KW.items() if k != "miss_chunk"})
+    dropped = 0
+    for i, b in enumerate(batches):
+        b = _from_the_blocks(b, seed=i)
+        res, want = dp.step(b, now=10 + i), twin.step(b, now=10 + i)
+        assert res.n_miss == want.n_miss
+        assert res.ingress_rule == want.ingress_rule
+        assert res.egress_rule == want.egress_rule
+        np.testing.assert_array_equal(np.asarray(res.code, np.int64),
+                                      np.asarray(want.code, np.int64))
+        dropped += sum(r is not None and r.startswith(policy.uid)
+                       for r in res.ingress_rule)
+    assert dropped > B // 8  # lanes inside a block, denied by its rule
 
 
 def _per_lane_keys(wide_col, keep):
