@@ -345,8 +345,26 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         bit-for-bit)."""
         host, match_meta = to_host(cps, delta_slots=self._delta_slots,
                                    prune_budget=self._prune_budget)
-        drs = jax.tree_util.tree_map(jnp.asarray, self._pad_tables(host))
+        drs = self._upload_tables(
+            lambda t: jax.tree_util.tree_map(jnp.asarray, t),
+            self._pad_tables(host))
         return drs, placed_meta(match_meta, drs)
+
+    def _upload_tables(self, place, host_tables):
+        """`place(host_tables)` -> the tables on the device, waited for: the
+        commit transaction's `upload` sub-span and its `table_bytes`
+        (observability/tracing.COMMIT_SUBSPANS), on the tracer's clock.
+        The bytes are those the devices hold (a replicated table counts
+        once a replica).  The constructor's boot tables come through here
+        too, outside any transaction: the tracer records nothing then."""
+        tr = getattr(self, "_realization", None)  # unset while booting
+        t0 = tr.now() if tr is not None else 0.0
+        placed = jax.block_until_ready(place(host_tables))
+        if tr is not None:
+            tr.commit_upload(tr.now() - t0, sum(
+                shard.data.nbytes for x in jax.tree_util.tree_leaves(placed)
+                for shard in x.addressable_shards))
+        return placed
 
     def _place_services(self, dsvc: pl.DeviceServiceTables):
         """Device service-table placement hook (mesh engine: replicated
@@ -384,12 +402,14 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         staged = list(services) if services is not None else None
         staged_dsvc = None
         if staged is not None:
-            staged_dsvc = self._place_services(pl.svc_to_device(
+            staged_dsvc = self._upload_tables(
+                lambda st: self._place_services(pl.svc_to_device(st)),
                 compile_services(staged, node_ips=self._node_ips,
-                                 node_name=self._node_name)))
+                                 node_name=self._node_name))
         if ps is not None:
             old_in = self._cps.ingress.rule_ids
             old_out = self._cps.egress.rule_ids
+            old_bits = self._meta.rule_bits_in
             self._ps = ps
             self._compile_rules(services=staged)
             # Cached flow-entry attribution follows rule IDENTITY across the
@@ -397,7 +417,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             # rule id; vanished rules lose attribution (the oracle twin
             # applies the same identity rule in PipelineOracle.update, so
             # stats/l7 attribution of established hits cannot drift).
-            self._remap_cached_attribution(old_in, old_out)
+            self._remap_cached_attribution(old_in, old_out, old_bits)
         elif staged is not None and self._cps.has_svcref:
             # Service-only bundle under toServices rules: reference
             # indices shift with the service list — recompile rules (ids
@@ -415,7 +435,10 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             self._slowpath.mark_stale(self._gen)
         return self._gen
 
-    def _remap_cached_attribution(self, old_in: list, old_out: list) -> None:
+    def _remap_cached_attribution(self, old_in: list, old_out: list,
+                                  old_bits: int) -> None:
+        """`old_bits`: the packed column's split under the OLD rule set
+        (pl.rule_split); the rewrite packs under the new one."""
         if (list(old_in) == list(self._cps.ingress.rule_ids)
                 and list(old_out) == list(self._cps.egress.rule_ids)):
             return  # same ids in the same order: nothing to rewrite
@@ -437,11 +460,12 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         # Ellipsis indexing: the rules column is the trailing axis both on
         # the single-chip (slots+1, 4) layout and the mesh engine's
         # (D, slots+1, 4) sharded layout.
-        rp = meta[..., RC]
-        vi = jnp.clip(rp & 0xFFFF, 0, r_in.shape[0] - 1)
-        vo = jnp.clip((rp >> 16) & 0xFFFF, 0, r_out.shape[0] - 1)
+        # Stored indices are the +1 encoding on both sides of the remap.
+        vi, vo = (jnp.clip(v + 1, 0, r.shape[0] - 1) for v, r in zip(
+            pl._unpack_rules(meta[..., RC], old_bits), (r_in, r_out)))
         self._state = self._state._replace(flow=self._state.flow._replace(
-            meta=meta.at[..., RC].set(r_in[vi] | (r_out[vo] << 16))
+            meta=meta.at[..., RC].set(pl._pack_rules(
+                r_in[vi] - 1, r_out[vo] - 1, self._meta.rule_bits_in))
         ))
         self._state_mutations += 1
 
@@ -789,7 +813,8 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             # Shared bit-layout decoders (single source of truth with the
             # kernel's row packing); wide worlds decode word quadruples.
             code, svc_idx, dnat_port = pl._unpack_meta1(int(meta[i, M1C]))
-            rule_in, rule_out = pl._unpack_rules(int(meta[i, RC]))
+            rule_in, rule_out = pl._unpack_rules(
+                int(meta[i, RC]), self._meta.rule_bits_in)
             if A == 2:
                 src, dst = unflip_ip(keys[i, 0]), unflip_ip(keys[i, 1])
                 dnat = unflip_ip(meta[i, DC])
@@ -1285,7 +1310,8 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         for i in np.nonzero(live)[0]:
             pg = int(kpg[i])
             code, svc_idx, dnat_port = pl._unpack_meta1(int(meta[i, M1C]))
-            rule_in, rule_out = pl._unpack_rules(int(meta[i, RC]))
+            rule_in, rule_out = pl._unpack_rules(
+                int(meta[i, RC]), self._meta.rule_bits_in)
             if A == 2:
                 src = iputil.unflip_u32(int(keys[i, 0]))
                 dst = iputil.unflip_u32(int(keys[i, 1]))
@@ -1569,11 +1595,19 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
                 bb = (np.bincount(vals, weights=lens[ok],
                                   minlength=len(ids))
                       if lens is not None else None)
-                for r in np.nonzero(bc)[0]:
-                    if ids[r]:
-                        ctr[ids[r]] += int(bc[r])
-                        if bb is not None and bb[r]:
-                            bctr[ids[r]] += int(bb[r])
+                # One fold a DISTINCT rule the step named (tens of
+                # thousands where every namespace has rules of its own):
+                # plain Python ints from one tolist() a column, never a
+                # numpy scalar an iteration.
+                hit = np.nonzero(bc)[0]
+                counts = bc[hit].tolist()
+                volumes = bb[hit].tolist() if bb is not None else None
+                for k, r in enumerate(hit.tolist()):
+                    rid = ids[r]
+                    if rid:
+                        ctr[rid] += counts[k]
+                        if volumes is not None and volumes[k]:
+                            bctr[rid] += int(volumes[k])
         none_mask = (o["ingress_rule"] < 0) & (o["egress_rule"] < 0)
         if not_spoofed is not None:
             none_mask = none_mask & not_spoofed
@@ -1600,7 +1634,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         # capacity check and placement (datapath/tenancy — no-op on the
         # default world).
         cps = self._pad_cps(cps)
-        pl.check_rule_capacity(cps)
+        bits_in = pl.rule_split(cps)
         drs, match_meta = self._place_rules(cps)
         self._cps = cps
         self._drs = drs
@@ -1618,6 +1652,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
             count_flow_stats=self._flow_stats,
             second_chance=bool(self._pipe_kw["second_chance"]),
             telemetry=bool(self._pipe_kw["telemetry"]),
+            rule_bits_in=bits_in,
         )
         # Async-mode step/drain variants of the meta: the FAST step
         # compiles the whole slow path out (defer_misses — misses keep the

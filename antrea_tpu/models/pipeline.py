@@ -321,6 +321,10 @@ class PipelineMeta(NamedTuple):
     # outputs, HLO bit-identical — the same discipline as every knob
     # above.
     telemetry: bool = False
+    # Bits of the packed rule-attribution column that hold the ingress
+    # index (rule_split(cps): 16 unless one direction has 65,534 rules or
+    # more).  Not an option: the engine derives it from the rule set.
+    rule_bits_in: int = 16
 
     @property
     def pref_mask(self) -> int:
@@ -558,16 +562,19 @@ def _unpack_meta1(m1):
     return code, svc_idx, dnat_port
 
 
-def _pack_rules(rule_in, rule_out):
-    # Rule indices fit 16 bits each (check_rule_capacity, invoked by every
-    # pipeline constructor, guards n_rules < 0xFFFE per direction; callers
-    # composing to_device + _pack_rules directly must call it themselves).
+def _pack_rules(rule_in, rule_out, bits_in: int = 16):
+    # The two rule indices share one 32-bit column: the ingress one in the
+    # low `bits_in` bits, the egress one above (rule_split, invoked by
+    # every pipeline constructor, finds the split that fits both
+    # directions or refuses the rule set; callers composing to_device +
+    # _pack_rules directly must call it themselves).
     # Stored +1 so the zero row means "no rule" (MISS).
-    return (rule_in + 1) | ((rule_out + 1) << 16)
+    return (rule_in + 1) | ((rule_out + 1) << bits_in)
 
 
-def _unpack_rules(rp):
-    return (rp & 0xFFFF) - 1, ((rp >> 16) & 0xFFFF) - 1
+def _unpack_rules(rp, bits_in: int = 16):
+    return ((rp & ((1 << bits_in) - 1)) - 1,
+            ((rp >> bits_in) & ((1 << (32 - bits_in)) - 1)) - 1)
 
 
 class PolicyCapacityError(ValueError):
@@ -580,18 +587,31 @@ class PolicyCapacityError(ValueError):
     predate the typed error."""
 
 
-def check_rule_capacity(cps: CompiledPolicySet) -> None:
-    """Rule attribution is cached in one packed 16/16 column (_pack_rules);
-    guard both the single-chip and sharded pipelines against overflow."""
-    for dt in (cps.ingress, cps.egress):
-        if dt.n_rules >= 0xFFFE:
-            raise PolicyCapacityError(
-                f"flow-cache rule packing supports < 65534 rules per "
-                f"direction, got {dt.n_rules}; split the policy set across "
-                f"datapath instances (per-Node span dissemination keeps "
-                f"per-instance rule counts bounded in the reference, "
-                f"architecture.md:57-60)"
-            )
+def rule_split(cps: CompiledPolicySet) -> int:
+    """-> the bits of the packed rule-attribution column (_pack_rules) that
+    hold the ingress index; the egress index has the other 32 - bits.
+
+    16/16 wherever both directions fit 16 bits (n_rules < 0xFFFE, stored
+    +1 with the all-ones value kept free), so every such world keeps the
+    one layout and the one compiled program it always had.  A rule set
+    lopsided past that (upstream's xLargeScale shape: 75,000 ingress rules
+    and no egress rule) gives the wide direction the bits the narrow one
+    leaves: the split is a function of the two counts alone.  Raises
+    PolicyCapacityError where no split holds both."""
+    need_in, need_out = ((dt.n_rules + 2).bit_length()
+                         for dt in (cps.ingress, cps.egress))
+    if need_in <= 16 and need_out <= 16:
+        return 16
+    if need_in + need_out > 32:
+        raise PolicyCapacityError(
+            f"flow-cache rule packing holds both directions' rule indices "
+            f"in 32 bits; {cps.ingress.n_rules} ingress and "
+            f"{cps.egress.n_rules} egress rules need {need_in} + {need_out}"
+            f"; split the policy set across datapath instances (per-Node "
+            f"span dissemination keeps per-instance rule counts bounded in "
+            f"the reference, architecture.md:57-60)"
+        )
+    return need_in if need_in > 16 else 32 - need_out
 
 
 def make_pipeline(
@@ -626,7 +646,7 @@ def make_pipeline(
     compile checks on hosts whose accelerator runtime may be broken; jit
     places numpy leaves itself at call time.
     """
-    check_rule_capacity(cps)
+    bits_in = rule_split(cps)
     if host:
         drs, match_meta = to_host(cps, prune_budget=prune_budget)
         dsvc = svc_to_host(svc)
@@ -647,6 +667,7 @@ def make_pipeline(
         count_flow_stats=count_flow_stats,
         second_chance=second_chance,
         telemetry=telemetry,
+        rule_bits_in=bits_in,
     )
     state = init_state(flow_slots, aff_slots, xp=np if host else jnp,
                        key_words=meta.key_words)
@@ -962,7 +983,8 @@ def _pipeline_step(
         # column — a don't-care for v6 lanes, whose consumers read c_dnat_w).
         c_dnat_ip = mr[:, DC]
         c_dnat_w = mr[:, 0:4] if A == 8 else None
-        c_rule_in, c_rule_out = _unpack_rules(mr[:, RC])
+        c_rule_in, c_rule_out = _unpack_rules(mr[:, RC],
+                                                 meta.rule_bits_in)
 
     with device_scope("fast_path"), device_scope("refresh"):
         # Idle-timeout refresh for hits.
@@ -1348,7 +1370,8 @@ def _pipeline_step(
                 egen = jnp.where(committed_m, GEN_ETERNAL, gen_w)
                 pg_ins = p_m | 0x100 | (egen << 9)
                 m1 = _pack_meta1(code, svc_idx, dnat_port)
-                rules_p = _pack_rules(rule_in, rule_out)
+                rules_p = _pack_rules(rule_in, rule_out,
+                                      meta.rule_bits_in)
                 # Column 3 = snat(31) | dsr(30) | pref (the commit
                 # freshens both directions; the frontend SNAT mark and the
                 # DSR delivery mark are pinned here for the connection's

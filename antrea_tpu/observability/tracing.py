@@ -75,6 +75,16 @@ _HIST_STAGES = REALIZATION_STAGES + ("total",)
 # grafted onto every span the commit realized).
 _COMMIT_STAMPS = ("start", "compile", "canary", "swap", "settle")
 
+# Sub-spans of a commit: (name, the stage it lies inside).  As with a
+# step's STEP_SUBSPANS below, a sub-span is NOT a stage — it is not added
+# to the telescoping sum.  `upload` is the time the engine spent placing
+# host tables on the device and waiting for them (rule, isolation and
+# Service tables; the host build before it is the rest of `compile`);
+# beside it the commit counts `table_bytes`, the bytes it placed.  A
+# commit that uploads nothing (the oracle engine, a no-op delta) records
+# zeros.
+COMMIT_SUBSPANS = (("upload", "compile"),)
+
 # Host phases of ONE `Datapath.step` call, in order; contiguous children
 # of the parent span `step` (phase k ends where phase k+1 begins).  The
 # ONE place the phase names are spelled: engines stamp by index
@@ -280,6 +290,9 @@ class RealizationTracer:
         # The in-flight and last-completed commit transactions.
         self._open_commit: Optional[dict] = None
         self._last_commit: Optional[tuple[int, dict]] = None  # (gen, stamps)
+        # The open / last commit's sub-span: (upload seconds, bytes placed).
+        self._open_upload = [0.0, 0]
+        self._last_upload = (0.0, 0)
         # First-hit latch: highest bundle generation live traffic has
         # stepped under, and when.  One int compare on the hot step.
         self._hit_gen = -1
@@ -381,6 +394,19 @@ class RealizationTracer:
         ends here for every span this commit realizes."""
         self._stamps_total += 1
         self._open_commit = {"start": self.now()}
+        self._open_upload = [0.0, 0]  # seconds, bytes placed
+
+    def commit_upload(self, seconds: float, nbytes: int) -> None:
+        """The engine placed `nbytes` of host tables on the device in
+        `seconds` of this clock, inside the open commit's compile stage
+        (COMMIT_SUBSPANS; a commit may upload more than once: rules, then
+        Services).  Outside a transaction (the constructor's boot tables)
+        nothing is recorded."""
+        if self._open_commit is None:
+            return
+        self._stamps_total += 1
+        self._open_upload[0] += max(0.0, float(seconds))
+        self._open_upload[1] += int(nbytes)
 
     def commit_stage(self, stage: str) -> None:
         """Stamp a completed commit stage (compile/canary/swap/settle),
@@ -404,6 +430,10 @@ class RealizationTracer:
         t = oc["start"]
         for s in _COMMIT_STAMPS:
             t = oc[s] = max(oc.get(s, t), t)
+        # The sub-span lies inside its stage whatever the clock did.
+        self._last_upload = (min(self._open_upload[0],
+                                 oc["compile"] - oc["start"]),
+                             self._open_upload[1])
         self._last_commit = (int(gen), oc)
 
     def commit_abort(self) -> None:
@@ -415,16 +445,19 @@ class RealizationTracer:
         """Stage seconds of the last settled commit transaction, readable
         without a realization span (a direct `install_bundle` opens
         none): {"generation", "compile_s", "canary_s", "swap_s",
-        "settle_s"}, telescoping to settle - start.  `compile` runs from
-        commit_begin to the stamp after the engine built and uploaded the
-        candidate (snapshot + host rule compile + upload); `canary` is
-        the fresh-probe gate.  None before the first commit."""
+        "settle_s"}, telescoping to settle - start, and beside them the
+        compile stage's sub-span and counter {"upload_s", "table_bytes"}
+        (COMMIT_SUBSPANS).  `compile` runs from commit_begin to the stamp
+        after the engine built and uploaded the candidate (snapshot +
+        host rule compile + upload); `canary` is the fresh-probe gate.
+        None before the first commit."""
         if self._last_commit is None:
             return None
         gen, stamps = self._last_commit
         out = {"generation": gen}
         for prev, stage in zip(_COMMIT_STAMPS, _COMMIT_STAMPS[1:]):
             out[f"{stage}_s"] = stamps[stage] - stamps[prev]
+        out["upload_s"], out["table_bytes"] = self._last_upload
         return out
 
     # -- the first-hit latch (engines' step()) -------------------------------

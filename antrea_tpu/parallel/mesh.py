@@ -37,7 +37,7 @@ HBM capacity math (measured on the 100k-rule bench world, v5e = 16 GB):
     budget, not the rule state.
   Single-chip ceiling: ~14 GB of incidence -> ~1.6M rules; an 8-way rule
   axis lifts that to ~4.5M rules per direction pair (capped earlier by the
-  16-bit attribution packing, models/pipeline.check_rule_capacity) — rule
+  32-bit attribution packing, models/pipeline.rule_split) — rule
   state beyond one chip's HBM is exactly what the axis buys, the way the
   reference relies on OVS's shared tables + megaflow cache.
 
@@ -393,7 +393,7 @@ def _build_sharded_step(cps, svc, mesh, ft, flow_slots, aff_slots,
     """Shared builder behind make_sharded_pipeline[_full] — one place for
     the capacity check, placement, meta/state construction and shard_map
     scaffolding so the two public variants can never drift."""
-    pl.check_rule_capacity(cps)
+    bits_in = pl.rule_split(cps)
     drs, match_meta = shard_rule_set(cps, mesh, prune_budget=prune_budget)
     dspec = _drs_specs(agg=prune_budget > 0)
     repl = NamedSharding(mesh, P())
@@ -414,6 +414,7 @@ def _build_sharded_step(cps, svc, mesh, ft, flow_slots, aff_slots,
         # The fused consumer is shard-aware (global word offsets ride
         # word_idx), so the sharded walk keeps the cold-path win.
         fused=fused,
+        rule_bits_in=bits_in,
     )
     state = shard_state(pl.init_state(flow_slots, aff_slots), mesh)
 

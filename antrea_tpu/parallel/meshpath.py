@@ -683,10 +683,11 @@ class MeshDatapath(TpuflowDatapath):
         # sharded placement (datapath/tenancy._pad_tables — no-op on the
         # default world), composing with the word_multiple padding above
         # so tenant shapes stay rung-determined ON the mesh too.
-        drs = self._pad_tables(host)
-        drs = jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-            drs, _drs_specs(agg=self._prune_budget > 0))
+        drs = self._upload_tables(
+            lambda t: jax.tree.map(
+                lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+                t, _drs_specs(agg=self._prune_budget > 0)),
+            self._pad_tables(host))
         return drs, placed_meta(meta, drs)
 
     def _place_rules(self, cps):
@@ -1481,13 +1482,14 @@ class MeshDatapath(TpuflowDatapath):
                            (rh & np.uint32(N - 1)).astype(np.int64),
                            tenant=tid)
 
-    def _remap_cached_attribution(self, old_in: list, old_out: list) -> None:
+    def _remap_cached_attribution(self, old_in: list, old_out: list,
+                                  old_bits: int) -> None:
         # Same-ids-in-same-order is the base method's no-op fast path
         # (services-only bundles, degraded-recovery recompiles): zero
         # cache rows rewritten, so the bounded dirty set must survive.
         changed = (list(old_in) != list(self._cps.ingress.rule_ids)
                    or list(old_out) != list(self._cps.egress.rule_ids))
-        super()._remap_cached_attribution(old_in, old_out)
+        super()._remap_cached_attribution(old_in, old_out, old_bits)
         # A mid-resize bundle that REALLY remapped attribution touched
         # the WHOLE cache: no bounded dirty set covers that — fall back
         # to the full catch-up sweep (metered; the pre-tracking shape).

@@ -585,16 +585,17 @@ def test_audit_scan_leaves_counters_and_census_intact():
 
 
 def test_policy_capacity_error_is_typed():
-    """check_rule_capacity raises the typed PolicyCapacityError (still a
+    """rule_split raises the typed PolicyCapacityError (still a
     ValueError for pre-existing callers)."""
     from types import SimpleNamespace
 
+    # the two indices share 32 bits (pl.rule_split): 17 + 17 do not fit
     cps = SimpleNamespace(ingress=SimpleNamespace(n_rules=0xFFFE),
-                          egress=SimpleNamespace(n_rules=3))
+                          egress=SimpleNamespace(n_rules=0xFFFE))
     with pytest.raises(pl.PolicyCapacityError):
-        pl.check_rule_capacity(cps)
+        pl.rule_split(cps)
     with pytest.raises(ValueError):
-        pl.check_rule_capacity(cps)
+        pl.rule_split(cps)
 
 
 def test_poison_bundle_reports_failed_and_stops_hot_retrying():

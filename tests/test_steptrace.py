@@ -464,6 +464,10 @@ def test_last_commit_after_a_direct_install(world):
     gen = dp.install_bundle(cluster.ps, services)
     last = tracer.last_commit()
     assert last.pop("generation") == gen == dp.generation
+    # the compile stage's sub-span and counter (COMMIT_SUBSPANS, PR 36) are
+    # in no telescoping sum
+    upload_s, table_bytes = last.pop("upload_s"), last.pop("table_bytes")
+    assert 0 < upload_s <= last["compile_s"] and table_bytes > 0
     assert set(last) == {"compile_s", "canary_s", "swap_s", "settle_s"}
     assert all(v >= 0 for v in last.values())
     assert last["compile_s"] > 0 and last["canary_s"] > 0
