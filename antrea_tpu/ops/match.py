@@ -71,6 +71,18 @@ _ALL1 = 0xFFFFFFFF
 # finds an all-zero AND and the lane takes the default verdict).
 AGG_BLOCK = 32
 
+# The minor size of the TPU's (8, 128) tile of 32-bit words, and the unit of
+# every rule-word axis (`_width`).  With default layouts the TPU places a
+# 2-D array in the dimension order that pads least under that tile: a
+# (rows, W) table whose W is no multiple of 128 (1,866 words pad by 2.9 %,
+# 33,433 rows by 0.3 %) is laid out COLUMN-major, and a step that gathers
+# whole rows (`classify.candidate`) transposes all of it first, every
+# step.  A tiled W pads nothing as the minor dimension, so the table is
+# placed row-major and the gather reads it where it lies.  Any table
+# gathered by row is born with such a width.
+TILE_WORDS = 128
+assert TILE_WORDS % AGG_BLOCK == 0
+
 # The K-budget autotuner's closed rung ladder (one jit-cached classify
 # variant per rung, like the drain CHUNK_LADDER) and its hysteresis.
 PRUNE_LADDER = (1, 2, 4, 8, 16)
@@ -114,9 +126,9 @@ class DimTable(NamedTuple):
     # unpruned pytree — and every jit signature over it — is unchanged):
     # (rows, W/AGG_BLOCK) u32, bit j of word s set iff inc word
     # s*AGG_BLOCK+j is nonzero (build_agg is the ONE builder, shared with
-    # the consistency property tests).  W is padded to an AGG_BLOCK
-    # multiple whenever agg is built, so superblocks never straddle the
-    # row end (or a rule-axis shard boundary — see _width).
+    # the consistency property tests).  W is a TILE_WORDS multiple (of
+    # which AGG_BLOCK is a divisor), so superblocks never straddle the row
+    # end (or a rule-axis shard boundary — see _width).
     agg: Optional[jax.Array] = None
 
 
@@ -437,17 +449,17 @@ def _direction_host(
     )
 
 
-def _width(n_rules: int, word_multiple: int, agg: bool = False) -> int:
-    # Dual-level alignment under pruning: W must divide by word_multiple
-    # (the rule-axis shard count) AND each shard's W/word_multiple slice
-    # must itself be an AGG_BLOCK multiple, so aggregate words never
-    # straddle a shard boundary and the agg axis shards evenly — hence
-    # word_multiple * AGG_BLOCK, not lcm (lcm alone leaves per-SHARD
-    # widths misaligned whenever gcd(word_multiple, 32) > 1).
-    if agg:
-        word_multiple *= AGG_BLOCK
-    w = max(1, -(-n_rules // 32))
-    return -(-w // word_multiple) * word_multiple
+def _width(n_rules: int, word_multiple: int) -> int:
+    """Rule words a direction's tables are built with: the rule count's
+    words rounded up to TILE_WORDS for each of `word_multiple` rule-axis
+    shards.  That one unit carries every alignment the consumers need: the
+    row-major placement of the row-gathered `inc` tables (TILE_WORDS), an
+    even split over the rule axis whose per-shard slice is itself tiled,
+    and, because AGG_BLOCK divides TILE_WORDS, aggregate words that never
+    straddle a row end or a shard boundary under pruning."""
+    words = max(1, -(-n_rules // 32))
+    unit = word_multiple * TILE_WORDS
+    return -(-words // unit) * unit
 
 
 def to_host(
@@ -470,8 +482,8 @@ def to_host(
     per lane and direction; 0 builds the exact pre-aggregate pytree.
     """
     agg = prune_budget > 0
-    w_in = _width(cps.ingress.n_rules, word_multiple, agg=agg)
-    w_out = _width(cps.egress.n_rules, word_multiple, agg=agg)
+    w_in = _width(cps.ingress.n_rules, word_multiple)
+    w_out = _width(cps.egress.n_rules, word_multiple)
     drs = DeviceRuleSet(
         ingress=_direction_host(cps.ingress, cps, w_in, agg=agg),
         egress=_direction_host(cps.egress, cps, w_out, agg=agg),

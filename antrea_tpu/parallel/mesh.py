@@ -230,10 +230,10 @@ def _drs_specs(agg: bool = False) -> m.DeviceRuleSet:
         # words sharded — bounds are the small side in both families.
         # The aggregate level (round-7 pruning) shards on ITS word axis
         # exactly like the incidence it summarizes: to_device pads W to a
-        # word_multiple*AGG_BLOCK multiple under pruning (dual-level
-        # alignment, ops/match._width), so each rule shard's agg slice
-        # covers precisely its own inc words and no aggregate word
-        # straddles a shard boundary.  agg=False worlds carry agg=None
+        # word_multiple*TILE_WORDS multiple (ops/match._width; AGG_BLOCK
+        # divides the tile), so each rule shard's agg slice covers
+        # precisely its own inc words and no aggregate word straddles a
+        # shard boundary.  agg=False worlds carry agg=None
         # (an EMPTY pytree node), matching the unpruned table pytree.
         return m.DimTable(bounds=P(), bounds6=P(), inc=P(None, RULE),
                           agg=P(None, RULE) if agg else None)

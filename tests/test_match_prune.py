@@ -191,7 +191,7 @@ def _assert_agg_consistent(drs):
     for dd in (drs.ingress, drs.egress):
         for tab in (dd.at, dd.peer, dd.svc):
             inc = np.asarray(tab.inc)
-            assert inc.shape[1] % m.AGG_BLOCK == 0
+            assert inc.shape[1] % m.TILE_WORDS == 0  # AGG_BLOCK divides it
             assert np.array_equal(np.asarray(tab.agg), m.build_agg(inc))
 
 
@@ -212,8 +212,9 @@ def test_agg_rebuilds_from_incidence_after_deltas_and_sharding():
     _assert_agg_consistent(dp._drs)
 
     # Mesh word-sharding: the global tables stay consistent AND each
-    # rule shard's slice is superblock-aligned (W/n_rule % 32 == 0), so
-    # per-shard aggregates cover exactly their own incidence words.
+    # rule shard's slice is tiled (W/n_rule % TILE_WORDS == 0: whole
+    # superblocks), so per-shard aggregates cover exactly their own
+    # incidence words.
     from antrea_tpu.parallel.meshpath import MeshDatapath
 
     md = MeshDatapath(cluster.ps, n_data=2, n_rule=2, flow_slots=1 << 8,
@@ -224,7 +225,7 @@ def test_agg_rebuilds_from_incidence_after_deltas_and_sharding():
     for dd in (md._drs.ingress, md._drs.egress):
         w = dd.at.inc.shape[1]
         s = dd.at.agg.shape[1]
-        assert w % (2 * m.AGG_BLOCK) == 0  # n_rule=2, dual-level multiple
+        assert w % (2 * m.TILE_WORDS) == 0  # n_rule=2: a tile a shard
         assert s % 2 == 0 and s * m.AGG_BLOCK == w
         # Shard d's aggregate slice == build_agg of shard d's inc slice.
         inc = np.asarray(dd.at.inc)

@@ -143,7 +143,9 @@ def test_the_larger_world_is_past_the_simulators_caps():
     d = dp._drs.ingress
     for tab in (d.at, d.peer, dp._drs.iso_in):
         assert tab.bounds.shape[0] > match._SS_FLAT  # the blocked search
-    assert d.at.inc.shape == (d.at.bounds.shape[0] + 2, dp._meta.match.w_in)
+    w = dp._meta.match.w_in  # 6,300 rules: 197 words, tiled to 256
+    assert w == 256 and w % match.TILE_WORDS == 0
+    assert d.at.inc.shape == (d.at.bounds.shape[0] + 2, w)
     assert dp._meta.match.in_phases == (0, 3 * SIZES[1], 0)
     assert dp._meta.match.out_phases == (0, 0, 0)  # no egress rule at all
 
