@@ -54,6 +54,7 @@ from typing import Optional
 import numpy as np
 
 from ..compiler.ir import canary_probe_tuples
+from ..observability.tracing import CommitSpan
 from ..oracle.interpreter import Oracle
 from ..packet import Packet, PacketBatch
 from ..utils import ip as iputil
@@ -358,12 +359,18 @@ class CommitPlane:
         tr = self._tracer()
         if tr is not None:
             tr.commit_stage(STAGE_SETTLE)
-            tr.commit_done(gen)
         self._emit("commit", stage=STAGE_SETTLE, outcome="ok",
                    gen=int(gen), delta=delta)
         if was_degraded:
             self._emit("recover", gen=int(gen))
-        self._refresh_audit_golden()
+        # The digests after the settle stamp: a span of the transaction's
+        # own (COMMIT_SUBSPANS `digest`), which no stage stamp counts.
+        try:
+            with CommitSpan(tr, "digest"):
+                self._refresh_audit_golden()
+        finally:
+            if tr is not None:
+                tr.commit_done(gen)
 
     # -- canary ---------------------------------------------------------------
 
@@ -412,14 +419,18 @@ class CommitPlane:
             # canary included) shares per-table-shape kernels.  Only the
             # real lanes are diffed.
             pkts = pad_probes(pkts, self.probes)
-            got = np.asarray(o._canary_classify(
-                PacketBatch.from_packets(pkts),
-                # Fresh probe clock, disjoint from any plausible packet
-                # clock a test or simulator drives (probes never touch
-                # state, but the fresh walk still takes a timestamp).
-                now=(1 << 20) + self.seq,
-            ))
-            oracle = Oracle(o._ps)
+            tr = self._tracer()
+            with CommitSpan(tr, "walk"):
+                got = np.asarray(o._canary_classify(
+                    PacketBatch.from_packets(pkts),
+                    # Fresh probe clock, disjoint from any plausible packet
+                    # clock a test or simulator drives (probes never touch
+                    # state, but the fresh walk still takes a timestamp).
+                    now=(1 << 20) + self.seq,
+                ))
+            with CommitSpan(tr, "oracle"):
+                oracle = Oracle(o._ps)
+                wants = [int(oracle.classify(p).code) for p in pkts[:n_real]]
             self.canary_probes_total += n_real
             # Replica-resolved canaries (the mesh engine) return a
             # (replicas, probes) verdict MATRIX — every data replica
@@ -430,7 +441,6 @@ class CommitPlane:
             # Single-chip engines return the classic (probes,) vector.
             replicated = got.ndim == 2
             views = got if replicated else got[None, :]
-            wants = [int(oracle.classify(p).code) for p in pkts[:n_real]]
             for r in range(views.shape[0]):
                 for i, want in enumerate(wants):
                     if int(views[r, i]) == want:

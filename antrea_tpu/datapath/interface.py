@@ -354,6 +354,35 @@ class Datapath(ABC):
             return None
         return {"records": tr.records(), "dropped": tr.dropped}
 
+    # -- the build ledger (observability/tracing.BuildLedger, one per
+    # process): an engine whose constructor carries `construct_span` sets
+    # where its rows begin ------------------------------------------------
+
+    _builds_from = None
+
+    def build_trace(self) -> Optional[dict]:
+        """The XLA builds since this engine's construction began, oldest
+        first: {"records": structured array (tracing.BUILD_RECORD — the
+        process's build number, the perf_counter_ns it ended at, trace /
+        lower / backend ns, persistent-cache hit or miss, the span that
+        caused it and the open step's `seq`, the executable's name),
+        "dropped": rows of them aged out of the ring}.  The ledger is the
+        process's: another engine's builds after this one's construction
+        are rows here too, filed under their own spans.  None on a
+        datapath whose constructor opened no `construct` span."""
+        if self._builds_from is None:
+            return None
+        from ..observability.tracing import build_ledger
+
+        return build_ledger().trace(since=self._builds_from)
+
+    def _commit_span(self, name: str):
+        """COMMIT_SUBSPANS' `name` of the commit transaction open on this
+        engine (a no-op outside one, and while booting)."""
+        from ..observability.tracing import CommitSpan
+
+        return CommitSpan(getattr(self, "_realization", None), name)
+
     # -- hot-path telemetry (observability/telemetry.py) --------------------
     # Engines with telemetry=True build a TelemetryPlane at construction
     # and call _telemetry_account from _step + observe_step from the

@@ -43,6 +43,7 @@ from ..compiler.topology import (
 from ..compiler.compile import ACT_ALLOW, ACT_DROP
 from ..observability.metrics import Histogram
 from ..observability.telemetry import TelemetryPlane
+from ..observability.tracing import construct_span
 from ..oracle.interpreter import Oracle
 from ..oracle.pipeline import PipelineOracle, _reject_kind
 from ..utils import ip as iputil
@@ -82,6 +83,7 @@ class OracleDatapath(TenantedDatapath, MaintainableDatapath,
         "_persist_dirty",
     )
 
+    @construct_span
     def __init__(
         self,
         ps: Optional[PolicySet] = None,

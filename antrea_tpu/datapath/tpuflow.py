@@ -51,7 +51,7 @@ from ..observability.metrics import Histogram
 from ..observability.telemetry import TelemetryPlane
 from ..observability.tracing import (SP_ACCOUNT, SP_ATTRIBUTE, SP_DISPATCH,
                                      SP_DONE, SP_FETCH, SP_STAGE, SP_UPLOAD,
-                                     SP_WAIT, StepTracer)
+                                     SP_WAIT, StepTracer, construct_span)
 from ..ops.match import (PRUNE_HIST_BOUNDS, PRUNE_LADDER, DeltaTable,
                          PruneAutotuner, placed_meta, to_host)
 from ..packet import Packet, PacketBatch
@@ -102,6 +102,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         "_state_mutations", "_pipe_kw", "_persist_dirty",
     )
 
+    @construct_span
     def __init__(
         self,
         ps: Optional[PolicySet] = None,
@@ -343,27 +344,26 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         build and device placement (datapath/tenancy._pad_tables — a
         no-op on the default world, preserving the untenanted pytree
         bit-for-bit)."""
-        host, match_meta = to_host(cps, delta_slots=self._delta_slots,
-                                   prune_budget=self._prune_budget)
+        with self._commit_span("tables"):
+            host, match_meta = to_host(cps, delta_slots=self._delta_slots,
+                                       prune_budget=self._prune_budget)
+            host = self._pad_tables(host)
         drs = self._upload_tables(
-            lambda t: jax.tree_util.tree_map(jnp.asarray, t),
-            self._pad_tables(host))
+            lambda t: jax.tree_util.tree_map(jnp.asarray, t), host)
         return drs, placed_meta(match_meta, drs)
 
     def _upload_tables(self, place, host_tables):
         """`place(host_tables)` -> the tables on the device, waited for: the
         commit transaction's `upload` sub-span and its `table_bytes`
-        (observability/tracing.COMMIT_SUBSPANS), on the tracer's clock.
-        The bytes are those the devices hold (a replicated table counts
-        once a replica).  The constructor's boot tables come through here
-        too, outside any transaction: the tracer records nothing then."""
-        tr = getattr(self, "_realization", None)  # unset while booting
-        t0 = tr.now() if tr is not None else 0.0
-        placed = jax.block_until_ready(place(host_tables))
-        if tr is not None:
-            tr.commit_upload(tr.now() - t0, sum(
+        (observability/tracing.COMMIT_SUBSPANS).  The bytes are those the
+        devices hold (a replicated table counts once a replica).  The
+        constructor's boot tables come through here too, outside any
+        transaction: nothing is recorded then."""
+        with self._commit_span("upload") as span:
+            placed = jax.block_until_ready(place(host_tables))
+            span.nbytes = sum(
                 shard.data.nbytes for x in jax.tree_util.tree_leaves(placed)
-                for shard in x.addressable_shards))
+                for shard in x.addressable_shards)
         return placed
 
     def _place_services(self, dsvc: pl.DeviceServiceTables):
@@ -1622,19 +1622,21 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         """services: the service view toServices lowering resolves against
         — None means the currently-committed list; install_bundle passes
         its STAGED list so a mixed bundle compiles consistently."""
-        self._has_named_ports = any(
-            s.port_name
-            for p in self._ps.policies for r in p.rules for s in r.services
-        )
-        cps = compile_policy_set(
-            self._ps,
-            services=self._services if services is None else services,
-        )
-        # Tenant worlds: pad phase capacities onto pow2 rungs BEFORE the
-        # capacity check and placement (datapath/tenancy — no-op on the
-        # default world).
-        cps = self._pad_cps(cps)
-        bits_in = pl.rule_split(cps)
+        with self._commit_span("rules"):
+            self._has_named_ports = any(
+                s.port_name
+                for p in self._ps.policies for r in p.rules
+                for s in r.services
+            )
+            cps = compile_policy_set(
+                self._ps,
+                services=self._services if services is None else services,
+            )
+            # Tenant worlds: pad phase capacities onto pow2 rungs BEFORE
+            # the capacity check and placement (datapath/tenancy — no-op
+            # on the default world).
+            cps = self._pad_cps(cps)
+            bits_in = pl.rule_split(cps)
         drs, match_meta = self._place_rules(cps)
         self._cps = cps
         self._drs = drs

@@ -46,12 +46,23 @@ ring and mirrored into the JAX profiler's trace as `tpuflow.step[.phase]`
 annotations; STEP_SCOPES (declared in the leaf module ops/scopes.py,
 re-exported here) names the scopes the device program's ops
 carry, so a device trace files every op under the same cut.
+
+What a step or an install COMPILED is the build ledger's (`BuildLedger`,
+one per process): every executable the process traces, lowers and
+compiles (or loads from the persistent cache) is a row, filed under the
+span that was open — a step by its `seq`, a commit stage or sub-span
+(COMMIT_SUBSPANS), the engine constructor's `construct` span, or
+`other`.  Spans read its two running totals at their edges, so a step
+record and a commit sub-span carry the builds they caused.  All stamps
+are time.perf_counter_ns (the step ring's clock); every span is also a
+`jax.profiler.TraceAnnotation`.
 """
 
 from __future__ import annotations
 
+import functools
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Optional
 
 import numpy as np
@@ -77,13 +88,25 @@ _COMMIT_STAMPS = ("start", "compile", "canary", "swap", "settle")
 
 # Sub-spans of a commit: (name, the stage it lies inside).  As with a
 # step's STEP_SUBSPANS below, a sub-span is NOT a stage — it is not added
-# to the telescoping sum.  `upload` is the time the engine spent placing
-# host tables on the device and waiting for them (rule, isolation and
-# Service tables; the host build before it is the rest of `compile`);
-# beside it the commit counts `table_bytes`, the bytes it placed.  A
-# commit that uploads nothing (the oracle engine, a no-op delta) records
-# zeros.
-COMMIT_SUBSPANS = (("upload", "compile"),)
+# to the telescoping sum — and those of one stage are disjoint, so they
+# sum to at most the stage.  `rules`: the host rule compile
+# (`compile_policy_set`, the tenant rung padding, `rule_split`); `tables`:
+# the host table build (`ops/match.to_host`, `_pad_tables`); `upload`:
+# the engine placing host tables on the device and waiting for them
+# (rule, isolation and Service tables), beside it the counter
+# `table_bytes`, the bytes placed; `oracle`: the scalar Oracle built over
+# the bundle and the probes' wanted verdicts; `walk`: the candidate's
+# fresh walk of the probes (`_canary_classify`).  `digest` (stage None)
+# is the audit plane's golden digests after the settle stamp: a span of
+# the transaction's own, in no stage.  A commit that does none of it
+# (the oracle engine places nothing, a delta that appends compiles no
+# rule) records zeros.  Outside a transaction (the constructor's boot
+# tables, the watchdog's canary) nothing is recorded.
+COMMIT_SUBSPANS = (
+    ("rules", "compile"), ("tables", "compile"), ("upload", "compile"),
+    ("oracle", "canary"), ("walk", "canary"), ("digest", None),
+)
+_SUB_STAGE = dict(COMMIT_SUBSPANS)
 
 # Host phases of ONE `Datapath.step` call, in order; contiguous children
 # of the parent span `step` (phase k ends where phase k+1 begins).  The
@@ -125,6 +148,10 @@ STEP_RING_SLOTS = 4096
 # retry): n_miss over it is how full the rounds were.  `v6_lanes` are the
 # lanes of the batch whose family mask `is6` is set, counted from the host
 # batch in `stage` (0 on a narrow engine, which refuses such a batch).
+# `xla_builds` / `xla_build_ns` are the executables the build ledger saw
+# built inside the call and their trace + lower + backend ns: 0 on a step
+# that ran programs already in memory, so a slow step that built one is
+# told from a step that was stopped.
 STEP_RECORD = np.dtype(
     [("seq", "<i8"), ("lanes", "<i8"), ("n_miss", "<i8"),
      ("round_lanes", "<i8"), ("t_start", "<i8")]
@@ -132,9 +159,206 @@ STEP_RECORD = np.dtype(
     + [("t_done", "<i8"), ("t_end", "<i8"), ("h2d_transfers", "<i8"),
        ("h2d_bytes", "<i8"), ("d2h_transfers", "<i8"), ("d2h_bytes", "<i8")]
     + [(f"{s}_{e}", "<i8") for s, _ in STEP_SUBSPANS for e in ("t0", "t1")]
-    + [("spill_lanes", "<i8"), ("retry_lanes", "<i8"), ("v6_lanes", "<i8")]
+    + [("spill_lanes", "<i8"), ("retry_lanes", "<i8"), ("v6_lanes", "<i8"),
+       ("xla_builds", "<i8"), ("xla_build_ns", "<i8")]
 )
 _N_STAMPS = len(STEP_PHASES) + 3  # start, one per phase, done, end
+
+# -- the XLA build ledger --------------------------------------------------
+
+# JAX's monitoring events of one build (jax 0.9: `jax/_src/dispatch.py`,
+# `compiler.py`, `compilation_cache.py`).  A jitted function's first call
+# at a shape fires trace (the jaxpr; the functions and primitives it
+# traces inside fire their own, nested in time), lower (the MLIR module)
+# and backend (compile, or load from the persistent cache, with a cache
+# hit or miss event inside it); an in-memory hit fires none.
+_EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_EV_BACKEND = "/jax/core/compile/backend_compile_duration"
+BUILD_CACHE = ("none", "hit", "miss")  # BUILD_RECORD.cache indexes this
+_EV_CACHE = {"/jax/compilation_cache/cache_hits": 1,
+             "/jax/compilation_cache/cache_misses": 2}
+
+# Builds the ring keeps (drop-oldest, drops metered): a cold install with
+# its eager canary builds ~1–2 thousand.
+BUILD_RING_SLOTS = 8192
+
+# One build row: `seq` counts the process's builds from 1, `t_end` is the
+# perf_counter_ns at which the backend compile ended; trace / lower /
+# backend ns; `cache` indexes BUILD_CACHE (a miss is a compile the cache
+# then stored; `none`, a compile it did not); `span` names the span open
+# then (`step`, `construct`, `commit.<stage>`, `commit.<stage>.<sub-span>`,
+# `commit.digest` or `other`) and `step_seq` the open step's `seq` (0
+# outside a step); `fun_name` is the executable's (`jit(f)`).
+BUILD_RECORD = np.dtype(
+    [("seq", "<i8"), ("t_end", "<i8"), ("trace_ns", "<i8"),
+     ("lower_ns", "<i8"), ("backend_ns", "<i8"), ("cache", "i1"),
+     ("step_seq", "<i8"), ("span", "U40"), ("fun_name", "U96")])
+
+
+class BuildLedger:
+    """Every executable the process builds, and the span that caused it.
+
+    One per process (`build_ledger()`): JAX's listeners cannot be
+    unregistered, so they are registered once, behind the module guard.
+    Listeners run on the compiling thread, inside the call that built;
+    `cause` is the process's, so a build on another thread (an API
+    handler's) is filed under the span the engine's thread has open.
+    `builds` and `build_ns` are running totals that a span reads at its
+    start and end; `cause` is the span open now, swapped in and out by the
+    spans themselves.  A build's trace and lower time is the time of the
+    outermost trace(s) and the lowering since the previous build; a
+    lowering that no backend compile followed (`jit(f).lower(x)`) is
+    dropped at the next one, so the totals count executables only.
+    """
+
+    def __init__(self, slots: int = BUILD_RING_SLOTS):
+        self.slots = int(slots)
+        self._clock = time.perf_counter_ns
+        self._rows: deque = deque(maxlen=self.slots)
+        self.builds = 0
+        self.build_ns = 0
+        self.cause = ("other", 0)  # (span name, step seq)
+        self._traces: list = []  # (t_end, ns) of traces not yet lowered
+        self._lowered = None  # (trace ns, lower ns) awaiting the backend
+        self._cache = 0
+
+    def on_duration(self, event: str, duration_secs: float, **kw) -> None:
+        if event == _EV_TRACE:
+            t, ns = self._clock(), int(duration_secs * 1e9)
+            # An enclosing trace ends last: it replaces what it traced.
+            self._traces = [x for x in self._traces if x[0] < t - ns]
+            self._traces.append((t, ns))
+        elif event == _EV_LOWER:
+            self._lowered = (sum(ns for _, ns in self._traces),
+                             int(duration_secs * 1e9))
+            self._traces = []
+        elif event == _EV_BACKEND:
+            trace_ns, lower_ns = self._lowered or (0, 0)
+            backend_ns = int(duration_secs * 1e9)
+            self._lowered = None
+            self.builds += 1
+            self.build_ns += trace_ns + lower_ns + backend_ns
+            span, step_seq = self.cause
+            self._rows.append((self.builds, self._clock(), trace_ns,
+                               lower_ns, backend_ns, self._cache, step_seq,
+                               span, str(kw.get("fun_name", ""))[:96]))
+            self._cache = 0
+
+    def on_event(self, event: str, **kw) -> None:
+        hit = _EV_CACHE.get(event)
+        if hit:
+            self._cache = hit
+
+    @property
+    def dropped(self) -> int:
+        return self.builds - len(self._rows)
+
+    def trace(self, since: int = 0) -> dict:
+        """{"records": the rows of builds after build `since`, oldest
+        first (BUILD_RECORD), "dropped": those of them the ring lost}."""
+        rows = [r for r in list(self._rows) if r[0] > since]
+        return {"records": np.array(rows, BUILD_RECORD),
+                "dropped": max(0, self.dropped - since)}
+
+
+_LEDGER: Optional[BuildLedger] = None
+
+
+def build_ledger() -> BuildLedger:
+    """The process's build ledger; the first call registers its listeners
+    with jax.monitoring (once: they cannot be unregistered)."""
+    global _LEDGER
+    if _LEDGER is None:
+        import jax.monitoring
+
+        led = BuildLedger()
+        jax.monitoring.register_event_duration_secs_listener(led.on_duration)
+        jax.monitoring.register_event_listener(led.on_event)
+        _LEDGER = led
+    return _LEDGER
+
+
+class Span:
+    """A span outside the step ring: a TraceAnnotation, the ledger's
+    `cause` while it is open, and on close `ns`, `builds` and `build_ns`
+    (what the ledger's totals moved by).  `nbytes` is the caller's to set
+    (the `upload` sub-span's `table_bytes`)."""
+
+    __slots__ = ("_ann", "_cause", "_prev", "_t0", "_b0", "_n0", "ns",
+                 "builds", "build_ns", "nbytes")
+
+    def __init__(self, annotation: str, cause: str):
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation(annotation)
+        self._cause = cause
+        self.ns = self.builds = self.build_ns = self.nbytes = 0
+
+    def __enter__(self):
+        led = build_ledger()
+        self._ann.__enter__()
+        self._prev, led.cause = led.cause, (self._cause, 0)
+        self._t0, self._b0, self._n0 = (time.perf_counter_ns(), led.builds,
+                                        led.build_ns)
+        return self
+
+    def __exit__(self, *exc):
+        led = build_ledger()
+        self.ns = time.perf_counter_ns() - self._t0
+        self.builds = led.builds - self._b0
+        self.build_ns = led.build_ns - self._n0
+        led.cause = self._prev
+        self._ann.__exit__(*exc)
+        return False
+
+
+def construct_span(init):
+    """Decorator of an engine's `__init__`: the constructor's own span
+    `construct` (`tpuflow.construct`).  It marks where the engine's
+    `build_trace()` begins and hands the span to the engine's realization
+    tracer (`last_commit()["construct_s"]`).  A subclass's `__init__` and
+    its base's both carry it: the outermost opens the span."""
+
+    @functools.wraps(init)
+    def wrapped(self, *args, **kwargs):
+        if self._builds_from is not None:  # inside a subclass's span
+            return init(self, *args, **kwargs)
+        self._builds_from = build_ledger().builds
+        with Span("tpuflow.construct", "construct") as span:
+            init(self, *args, **kwargs)
+        tr = getattr(self, "_realization", None)
+        if tr is not None:
+            tr.note_construct(span)
+
+    return wrapped
+
+
+class CommitSpan(Span):
+    """COMMIT_SUBSPANS' `name` of the tracer's open commit transaction
+    (`tpuflow.commit.<stage>.<name>`); with no tracer or no open commit
+    it records nothing and costs nothing."""
+
+    __slots__ = ("_tracer", "_name")
+
+    def __init__(self, tracer, name: str):
+        stage = _SUB_STAGE[name]
+        where = f"{stage}.{name}" if stage else name
+        super().__init__(f"tpuflow.commit.{where}", f"commit.{where}")
+        self._tracer = tracer
+        self._name = name
+
+    def __enter__(self):
+        if self._tracer is None or self._tracer._open_commit is None:
+            self._tracer = None
+            return self
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        if self._tracer is not None:
+            super().__exit__(*exc)
+            self._tracer._commit_sub(self._name, self)
+        return False
 
 
 class StepTracer:
@@ -153,12 +377,18 @@ class StepTracer:
     `jax.profiler.TraceAnnotation` (`tpuflow.step`, `tpuflow.step.<phase>`,
     `tpuflow.step.<phase>.<sub-span>`) — free while no profiler session
     runs, and in a traced run an event on the device ops' timeline.
+    While the span is open the build ledger files builds under its `seq`;
+    `begin` and `end` read the ledger's two totals for `xla_builds` and
+    `xla_build_ns`.
     """
 
     def __init__(self):
         from jax.profiler import TraceAnnotation
 
         self._annotate = TraceAnnotation
+        self._ledger = build_ledger()
+        self._built = (0, 0)
+        self._cause = None
         self._names = tuple(f"tpuflow.step.{p}" for p in STEP_PHASES)
         self._sub_names = tuple(f"tpuflow.step.{p}.{s}"
                                 for s, p in STEP_SUBSPANS)
@@ -185,9 +415,12 @@ class StepTracer:
         self.d2h_transfers = self.d2h_bytes = 0
         self.spill_lanes = self.retry_lanes = self.v6_lanes = 0
         self._subs = [0] * (2 * len(STEP_SUBSPANS))
-        self._span = self._annotate("tpuflow.step",
-                                    seq=self.steps_total + 1)
+        seq = self.steps_total + 1
+        self._span = self._annotate("tpuflow.step", seq=seq)
         self._span.__enter__()
+        led = self._ledger
+        self._built = (led.builds, led.build_ns)
+        self._cause, led.cause = led.cause, ("step", seq)
         self._stamps = [self._clock()]
 
     def phase(self, k: int) -> None:
@@ -236,6 +469,9 @@ class StepTracer:
             self._child.__exit__(None, None, None)
             self._child = None
         self._span.__exit__(None, None, None)
+        led = self._ledger
+        led.cause = self._cause
+        builds, build_ns = self._built
         ts = self._stamps
         ts.extend([t] * (_N_STAMPS - len(ts)))
         seq = self.steps_total = self.steps_total + 1
@@ -245,7 +481,7 @@ class StepTracer:
             seq, self._lanes, self.n_miss, self.round_lanes, *ts,
             self.h2d_transfers, self.h2d_bytes, self.d2h_transfers,
             self.d2h_bytes, *self._subs, self.spill_lanes, self.retry_lanes,
-            self.v6_lanes)
+            self.v6_lanes, led.builds - builds, led.build_ns - build_ns)
         return (t - ts[0]) * 1e-9
 
     def records(self) -> np.ndarray:
@@ -290,9 +526,17 @@ class RealizationTracer:
         # The in-flight and last-completed commit transactions.
         self._open_commit: Optional[dict] = None
         self._last_commit: Optional[tuple[int, dict]] = None  # (gen, stamps)
-        # The open / last commit's sub-span: (upload seconds, bytes placed).
-        self._open_upload = [0.0, 0]
-        self._last_upload = (0.0, 0)
+        # The open / last commit's sub-spans (COMMIT_SUBSPANS): name ->
+        # [ns, builds, build ns, bytes]; and the builds of each stage.
+        self._open_subs: dict = {}
+        self._last_subs: dict = {}
+        self._stage_builds: dict = {}
+        self._last_builds: dict = {}
+        self._built = (0, 0)  # the ledger's totals at the last stamp
+        self._cause = None  # the ledger's cause before the commit began
+        # The engine constructor's span (construct_span): (seconds,
+        # builds, build seconds), or None.
+        self._construct = None
         # First-hit latch: highest bundle generation live traffic has
         # stepped under, and when.  One int compare on the hot step.
         self._hit_gen = -1
@@ -394,19 +638,23 @@ class RealizationTracer:
         ends here for every span this commit realizes."""
         self._stamps_total += 1
         self._open_commit = {"start": self.now()}
-        self._open_upload = [0.0, 0]  # seconds, bytes placed
+        self._open_subs = {name: [0, 0, 0, 0] for name, _ in COMMIT_SUBSPANS}
+        self._stage_builds = {}
+        led = build_ledger()
+        self._built = (led.builds, led.build_ns)
+        if self._cause is None:
+            self._cause = led.cause
+        led.cause = (f"commit.{_COMMIT_STAMPS[1]}", 0)
 
-    def commit_upload(self, seconds: float, nbytes: int) -> None:
-        """The engine placed `nbytes` of host tables on the device in
-        `seconds` of this clock, inside the open commit's compile stage
-        (COMMIT_SUBSPANS; a commit may upload more than once: rules, then
-        Services).  Outside a transaction (the constructor's boot tables)
-        nothing is recorded."""
-        if self._open_commit is None:
-            return
+    def _commit_sub(self, name: str, span: "Span") -> None:
+        """A CommitSpan closed inside the open commit (a sub-span may open
+        more than once: rules, then Services, are both uploads)."""
         self._stamps_total += 1
-        self._open_upload[0] += max(0.0, float(seconds))
-        self._open_upload[1] += int(nbytes)
+        acc = self._open_subs[name]
+        acc[0] += span.ns
+        acc[1] += span.builds
+        acc[2] += span.build_ns
+        acc[3] += int(span.nbytes)
 
     def commit_stage(self, stage: str) -> None:
         """Stamp a completed commit stage (compile/canary/swap/settle),
@@ -416,6 +664,18 @@ class RealizationTracer:
         self._stamps_total += 1
         prev = max(self._open_commit.values())
         self._open_commit[stage] = max(self.now(), prev)
+        led = build_ledger()
+        builds, build_ns = self._built
+        self._stage_builds[stage] = (led.builds - builds,
+                                     led.build_ns - build_ns)
+        self._built = (led.builds, led.build_ns)
+        k = min(_COMMIT_STAMPS.index(stage) + 1, len(_COMMIT_STAMPS) - 1)
+        led.cause = (f"commit.{_COMMIT_STAMPS[k]}", 0)  # the next stage
+
+    def note_construct(self, span: "Span") -> None:
+        """The engine constructor's span closed (construct_span)."""
+        self._construct = (span.ns * 1e-9, span.builds,
+                           span.build_ns * 1e-9)
 
     def commit_done(self, gen: int) -> None:
         """The transaction settled at bundle generation `gen`: its stamps
@@ -430,34 +690,66 @@ class RealizationTracer:
         t = oc["start"]
         for s in _COMMIT_STAMPS:
             t = oc[s] = max(oc.get(s, t), t)
-        # The sub-span lies inside its stage whatever the clock did.
-        self._last_upload = (min(self._open_upload[0],
-                                 oc["compile"] - oc["start"]),
-                             self._open_upload[1])
+        # A stage's sub-spans lie inside it whatever the two clocks did:
+        # each is clamped to what the earlier ones left of the stage.
+        left = {s: oc[s] - oc[p]
+                for p, s in zip(_COMMIT_STAMPS, _COMMIT_STAMPS[1:])}
+        subs = {}
+        for name, stage in COMMIT_SUBSPANS:
+            ns, builds, build_ns, nbytes = self._open_subs[name]
+            sec = ns * 1e-9
+            if stage is not None:
+                sec = min(sec, left[stage])
+                left[stage] -= sec
+            subs[name] = (sec, builds, build_ns * 1e-9, nbytes)
+        self._last_subs = subs
+        self._last_builds = self._stage_builds  # commit_begin makes anew
         self._last_commit = (int(gen), oc)
+        self._end_cause()
 
     def commit_abort(self) -> None:
         """The transaction rolled back: nothing realized, drop the
         stamps (the retry's own transaction re-stamps from compile)."""
         self._open_commit = None
+        self._end_cause()
+
+    def _end_cause(self) -> None:
+        if self._cause is not None:
+            build_ledger().cause = self._cause
+            self._cause = None
 
     def last_commit(self) -> Optional[dict]:
         """Stage seconds of the last settled commit transaction, readable
         without a realization span (a direct `install_bundle` opens
         none): {"generation", "compile_s", "canary_s", "swap_s",
-        "settle_s"}, telescoping to settle - start, and beside them the
-        compile stage's sub-span and counter {"upload_s", "table_bytes"}
-        (COMMIT_SUBSPANS).  `compile` runs from commit_begin to the stamp
-        after the engine built and uploaded the candidate (snapshot +
-        host rule compile + upload); `canary` is the fresh-probe gate.
-        None before the first commit."""
+        "settle_s"}, telescoping to settle - start; beside them, in no
+        sum, the sub-spans "<name>_s" of COMMIT_SUBSPANS (`rules`,
+        `tables`, `upload` of compile, `oracle`, `walk` of canary,
+        `digest` after settle), the counter "table_bytes", the engine
+        constructor's "construct_s" (0.0 where no span was handed over),
+        and "builds": span -> (executables built, their trace + lower +
+        backend seconds) for `construct`, each stage and each sub-span.
+        `compile` runs from commit_begin to the stamp after the engine
+        built and uploaded the candidate (snapshot + host rule compile +
+        upload); `canary` is the fresh-probe gate.  None before the first
+        commit."""
         if self._last_commit is None:
             return None
         gen, stamps = self._last_commit
         out = {"generation": gen}
         for prev, stage in zip(_COMMIT_STAMPS, _COMMIT_STAMPS[1:]):
             out[f"{stage}_s"] = stamps[stage] - stamps[prev]
-        out["upload_s"], out["table_bytes"] = self._last_upload
+        construct = self._construct or (0.0, 0, 0.0)
+        builds = {"construct": construct[1:]}
+        for stage in _COMMIT_STAMPS[1:]:
+            n, ns = self._last_builds.get(stage, (0, 0))
+            builds[stage] = (n, ns * 1e-9)
+        for name, (sec, n, build_s, nbytes) in self._last_subs.items():
+            out[f"{name}_s"] = sec
+            builds[name] = (n, build_s)
+        out["table_bytes"] = self._last_subs["upload"][3]
+        out["construct_s"] = construct[0]
+        out["builds"] = builds
         return out
 
     # -- the first-hit latch (engines' step()) -------------------------------

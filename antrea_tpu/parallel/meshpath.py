@@ -115,7 +115,8 @@ from ..datapath.tpuflow import TpuflowDatapath, _rids
 from ..observability.telemetry import classify_regime
 from ..observability.tracing import (SP_ACCOUNT, SP_ATTRIBUTE, SP_DISPATCH,
                                      SP_DONE, SP_FETCH, SP_STAGE, SP_UPLOAD,
-                                     SP_WAIT, SS_RETRY, SS_ROUTE)
+                                     SP_WAIT, SS_RETRY, SS_ROUTE,
+                                     construct_span)
 from ..models import forwarding as fw
 from ..models import pipeline as pl
 from ..ops import hashing
@@ -595,6 +596,7 @@ class MeshDatapath(TpuflowDatapath):
         "_fo_mask",
     )
 
+    @construct_span
     def __init__(self, ps=None, services=None, *, mesh=None, n_data: int = 2,
                  n_rule: int = 1, devices=None, reshard_budget: int = 256,
                  failover: bool = False, failover_knobs=None, **kw):
@@ -676,18 +678,21 @@ class MeshDatapath(TpuflowDatapath):
         rung-packed rule window (parallel/reshard._ensure_world_rules),
         so rung-shared shapes — and their XLA executables — survive a
         resize."""
-        host, meta = to_host(cps, word_multiple=self._n_rule,
-                             delta_slots=self._delta_slots,
-                             prune_budget=self._prune_budget)
-        # Tenant worlds: entry-axis rung padding between host build and
-        # sharded placement (datapath/tenancy._pad_tables — no-op on the
-        # default world), composing with the word_multiple padding above
-        # so tenant shapes stay rung-determined ON the mesh too.
+        with self._commit_span("tables"):
+            host, meta = to_host(cps, word_multiple=self._n_rule,
+                                 delta_slots=self._delta_slots,
+                                 prune_budget=self._prune_budget)
+            # Tenant worlds: entry-axis rung padding between host build
+            # and sharded placement (datapath/tenancy._pad_tables — no-op
+            # on the default world), composing with the word_multiple
+            # padding above so tenant shapes stay rung-determined ON the
+            # mesh too.
+            host = self._pad_tables(host)
         drs = self._upload_tables(
             lambda t: jax.tree.map(
                 lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
                 t, _drs_specs(agg=self._prune_budget > 0)),
-            self._pad_tables(host))
+            host)
         return drs, placed_meta(meta, drs)
 
     def _place_rules(self, cps):

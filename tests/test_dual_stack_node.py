@@ -325,7 +325,9 @@ def test_the_step_record_counts_the_v6_lanes(served, world):
     dp, _ = served
     ps, services, batches = world
     rec = dp.step_trace()["records"]
-    assert rec.dtype == STEP_RECORD and STEP_RECORD.names[-1] == "v6_lanes"
+    # v6_lanes, then the build ledger's two fields (PR 38)
+    assert rec.dtype == STEP_RECORD and STEP_RECORD.names[-3:] == (
+        "v6_lanes", "xla_builds", "xla_build_ns")
     want = [int(b.is6.sum()) for b in batches]
     assert rec["v6_lanes"][:3].tolist() == want and min(want) > 0
     assert (rec["lanes"] == B).all()

@@ -200,7 +200,9 @@ def test_the_upload_lies_inside_compile_and_counts_the_placed_tables():
     dp = TpuflowDatapath(**KW)
     tracer = dp.realization_tracer
     assert tracer.last_commit() is None  # the boot tables are no transaction
-    assert COMMIT_SUBSPANS == (("upload", "compile"),)
+    assert COMMIT_SUBSPANS == (
+        ("rules", "compile"), ("tables", "compile"), ("upload", "compile"),
+        ("oracle", "canary"), ("walk", "canary"), ("digest", None))
 
     def placed(*trees):
         return sum(x.nbytes for x in jax.tree_util.tree_leaves(trees))

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 
 import antrea_tpu
 from antrea_tpu.compiler.topology import FWD_TUNNEL, NodeRoute, Topology
-from antrea_tpu.datapath import TpuflowDatapath
+from antrea_tpu.datapath import OracleDatapath, TpuflowDatapath
 from antrea_tpu.datapath.tpuflow import _rid
 from antrea_tpu.models import forwarding as fwd
 from antrea_tpu.models import pipeline as pl
@@ -164,10 +164,11 @@ def test_sub_spans_are_zero_on_one_chip(engine):
     rec = dp.step_trace()["records"]
     assert [f"{s}_{e}" for s, _ in STEP_SUBSPANS for e in ("t0", "t1")] + [
         "spill_lanes", "retry_lanes", "v6_lanes"] == list(
-            STEP_RECORD.names[-7:])
+            STEP_RECORD.names[-9:-2])
+    assert STEP_RECORD.names[-2:] == ("xla_builds", "xla_build_ns")
     assert {p for _, p in STEP_SUBSPANS} <= set(STEP_PHASES)
     if kind == "tpuflow":
-        for f in STEP_RECORD.names[-7:]:  # a narrow engine: no v6 lane
+        for f in STEP_RECORD.names[-9:-2]:  # a narrow engine: no v6 lane
             assert (rec[f] == 0).all(), f
     else:  # the mesh routes every step; it retries only what spilled
         assert (rec["route_t1"] > rec["route_t0"]).all()
@@ -464,10 +465,14 @@ def test_last_commit_after_a_direct_install(world):
     gen = dp.install_bundle(cluster.ps, services)
     last = tracer.last_commit()
     assert last.pop("generation") == gen == dp.generation
-    # the compile stage's sub-span and counter (COMMIT_SUBSPANS, PR 36) are
-    # in no telescoping sum
+    # the sub-spans and counter (COMMIT_SUBSPANS, PR 36 / 38), the
+    # constructor's span and the builds are in no telescoping sum
     upload_s, table_bytes = last.pop("upload_s"), last.pop("table_bytes")
     assert 0 < upload_s <= last["compile_s"] and table_bytes > 0
+    subs = {f"{name}_s" for name, _ in tracing.COMMIT_SUBSPANS}
+    assert all(last.pop(k) >= 0 for k in subs - {"upload_s"})
+    assert last.pop("construct_s") > 0 and isinstance(last.pop("builds"),
+                                                      dict)
     assert set(last) == {"compile_s", "canary_s", "swap_s", "settle_s"}
     assert all(v >= 0 for v in last.values())
     assert last["compile_s"] > 0 and last["canary_s"] > 0
@@ -480,6 +485,89 @@ def test_last_commit_after_a_direct_install(world):
     assert tracer.last_commit()["generation"] == dp.generation == gen + 1
     assert TpuflowDatapath(realization_slots=0, **KW).realization_tracer \
         is None
+
+
+def _install_engine(kind):
+    if kind == "tpuflow":
+        return TpuflowDatapath(**KW)
+    from antrea_tpu.parallel import MeshDatapath
+
+    return MeshDatapath(n_data=2, n_rule=1, devices=jax.devices("cpu")[:2],
+                        **KW)
+
+
+@pytest.mark.parametrize("kind", ["tpuflow", "mesh"])
+def test_the_install_sub_spans_lie_inside_their_stages(kind, world):
+    """COMMIT_SUBSPANS on a small install, on one chip and on the 2-device
+    CPU mesh: a stage's sub-spans sum to at most the stage, each is > 0,
+    and the builds each caused are builds of its stage."""
+    cluster, services, _ = world
+    dp = _install_engine(kind)
+    dp.install_bundle(cluster.ps, services)
+    last = dp.realization_tracer.last_commit()
+    sub = {name: last[f"{name}_s"] for name, _ in tracing.COMMIT_SUBSPANS}
+    assert min(sub.values()) > 0
+    assert sub["rules"] + sub["tables"] + sub["upload"] <= last["compile_s"]
+    assert sub["oracle"] + sub["walk"] <= last["canary_s"]
+    assert last["construct_s"] > 0 and last["table_bytes"] > 0
+    builds = last["builds"]
+    assert set(builds) == {"construct", "compile", "canary", "swap",
+                           "settle", *sub}
+    assert (builds["rules"][0] + builds["tables"][0] + builds["upload"][0]
+            <= builds["compile"][0])
+    assert builds["oracle"][0] + builds["walk"][0] <= builds["canary"][0]
+    # the walk's builds are rows of the ledger filed under the walk
+    rows = dp.build_trace()["records"]
+    assert (rows["span"] == "commit.canary.walk").sum() == builds["walk"][0]
+
+
+def test_the_digest_is_a_span_after_settle(world):
+    """The audit plane's golden digests run after the settle stamp: a span
+    of the transaction's own, which moves no stage stamp."""
+    cluster, services, _ = world
+    dp = TpuflowDatapath(**KW)
+    tracer = dp.realization_tracer
+    calls = []
+    refresh = dp._audit_refresh_golden
+
+    def spy():
+        calls.append(tracer._open_commit is not None)
+        refresh()
+
+    dp._audit_refresh_golden = spy
+    dp.install_bundle(cluster.ps, services)
+    last = tracer.last_commit()
+    assert calls == [True] and last["digest_s"] > 0
+    _, stamps = tracer._last_commit
+    assert stamps["settle"] - stamps["start"] == pytest.approx(
+        sum(last[f"{s}_s"] for s in ("compile", "canary", "swap", "settle")),
+        rel=1e-12)
+    # the oracle engine has no audit plane: its digest span is empty work
+    twin = OracleDatapath(canary_probes=8)
+    twin.install_bundle(cluster.ps, services)
+    assert 0 <= twin.realization_tracer.last_commit()["digest_s"] < \
+        last["digest_s"]
+
+
+def test_a_delta_that_compiles_no_rule_records_zeros(world):
+    """An appended delta compiles no rule, builds no table and uploads
+    nothing: zeros, while its canary's sub-spans run; a no-op delta settles
+    no commit, so the last one stands."""
+    cluster, services, _ = world
+    dp = TpuflowDatapath(cluster.ps, services, **KW)
+    ag = sorted(cluster.ps.address_groups)[0]
+    victim = cluster.ps.address_groups[ag].members[0].ip
+    gen = dp.apply_group_delta(ag, added_ips=["10.9.9.9"],
+                               removed_ips=[victim])
+    assert dp._n_deltas > 0  # appended, not recompiled
+    last = dp.realization_tracer.last_commit()
+    assert last["generation"] == gen
+    assert (last["rules_s"], last["tables_s"], last["upload_s"],
+            last["table_bytes"]) == (0.0, 0.0, 0.0, 0)
+    assert last["oracle_s"] > 0 and last["walk_s"] > 0
+    assert dp.apply_group_delta(ag, added_ips=["10.9.9.9"],
+                                removed_ips=[]) == gen  # a refcount only
+    assert dp.realization_tracer.last_commit() == last
 
 
 # -- the device scopes and the host annotations --------------------------------
