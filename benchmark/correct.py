@@ -40,6 +40,9 @@ has closed.  Numbers, each with a limit of its own (the traffic file's
 
 The three step-level numbers and the limits' handling are the same for every
 cell: a reference may state more about a lane, it cannot drop one of them.
+What a reference's statements may read: the sample's batch columns, its
+answers, `fresh`, and the lane labels its generator gave (manifest.py), each
+under its own name; `failed_statements` below reads none of the labels.
 """
 
 from __future__ import annotations

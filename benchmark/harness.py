@@ -32,6 +32,9 @@ from manifest import HERE, Manifest, load_json, load_module
 _ANSWER_FIELDS = ("code", "est", "committed", "svc_idx", "dnat_ip",
                   "dnat_port", "reply", "reject_kind", "snat",
                   "ingress_rule", "egress_rule")
+# Names a lane label may not take (beside every PacketBatch field): the
+# sample already holds them, or the comparison writes them (correct.py).
+_NOT_LABELS = _ANSWER_FIELDS + ("fresh", "ref_code", "ref_rule")
 # `now` handed to step: whole seconds of the run's wall clock from a fixed
 # base, so that conntrack aging runs as on a node.
 _NOW_BASE = 1000
@@ -108,6 +111,30 @@ def packet_batch(cols: dict):
     return PacketBatch(**cols)
 
 
+def refuse_label(name: str) -> None:
+    """A lane label named like a batch column, an answer or a name the
+    comparison writes would overwrite it in the sample."""
+    import dataclasses
+
+    from antrea_tpu.packet import PacketBatch
+
+    if name in _NOT_LABELS or name in {
+            f.name for f in dataclasses.fields(PacketBatch)}:
+        raise ValueError(f"the generator labels its lanes {name!r}: that "
+                         f"name is a batch column, an answer or one the "
+                         f"comparison writes; give the label another")
+
+
+def warm_up(engine, traffic, t_process: float) -> None:
+    """Step every batch of the generator's warm-up; a generator that listens
+    (`observe`) hears each answer, as in the window."""
+    listen = getattr(traffic, "observe", None)
+    for cols in traffic.warmup():
+        res = engine.step(packet_batch(cols), now_of(t_process))
+        if listen is not None:
+            listen(cols, res)
+
+
 class Window:
     """What the client's side saw: one row per iteration of the loop."""
 
@@ -125,6 +152,7 @@ class Window:
         self.failed_lanes = 0
         self.error = None
         self.sample = {f: [] for f in _ANSWER_FIELDS + ("fresh",)}
+        self.labels = set()  # the lane labels' names, each checked once
         self.last = None  # (columns, StepResult) of the last step
         self.records = []  # the program's step records, put aside (traced)
 
@@ -134,10 +162,16 @@ class Window:
         if trace:
             self.records.append(trace["records"])
 
-    def take(self, cols: dict, res, lanes, fresh) -> None:
+    def take(self, cols: dict, res, lanes, fresh, labels=None) -> None:
         """The sample carries what the batch carried: every column the
         generator sent that is not None, so that a reference which states
-        something about `src_ip6` or `tcp_flags` finds it there."""
+        something about `src_ip6` or `tcp_flags` finds it there; and each
+        lane label the generator gave, under its own name."""
+        for name, column in (labels or {}).items():
+            if name not in self.labels:
+                refuse_label(name)
+                self.labels.add(name)
+            self.sample.setdefault(name, []).append(np.asarray(column)[lanes])
         for f, column in cols.items():
             if column is not None:
                 self.sample.setdefault(f, []).append(
@@ -158,7 +192,9 @@ def run_window(engine, traffic, seconds: float, t_process: float,
                trace_dir) -> Window:
     """The closed loop: one client, back to back.  With `trace_dir`, the
     profiler is on from a quarter of the window for _TRACE_SECONDS, and the
-    client's two phases are spans of the trace."""
+    client's phases are spans of the trace.  A generator that listens
+    (`observe`) hears each answer after the verdict's clock and before the
+    sample's: its time is the client's, never the step's."""
     import jax
 
     w = Window()
@@ -172,6 +208,7 @@ def run_window(engine, traffic, seconds: float, t_process: float,
             w.gc.append((info["generation"], clock() - gc_began.pop()))
 
     gc.callbacks.append(on_gc)
+    listen = getattr(traffic, "observe", None)
     span = jax.profiler.TraceAnnotation if trace_dir else None
     trace_from = seconds / 4 if trace_dir else float("inf")
     trace_until = None  # set while the profiler is on
@@ -188,7 +225,9 @@ def run_window(engine, traffic, seconds: float, t_process: float,
         tracing = trace_until is not None
         ta = clock()
         with span("bench.assemble") if tracing else contextlib.nullcontext():
-            cols, lanes, fresh = traffic.next_batch()
+            out = traffic.next_batch()  # (cols, lanes, fresh[, labels])
+            cols, lanes, fresh = out[:3]
+            labels = out[3] if len(out) > 3 else None
             batch = packet_batch(cols)
         now = now_of(t_process)
         cpu = time.process_time()
@@ -213,8 +252,12 @@ def run_window(engine, traffic, seconds: float, t_process: float,
             w.failed_lanes += int(np.sum(res.pending))
         w.n_allowed += int(np.count_nonzero(np.asarray(res.code) == 0))
         w.n_established += int(np.count_nonzero(res.est))
-        w.take(cols, res, lanes, fresh)
+        w.take(cols, res, lanes, fresh, labels)
         w.last = (cols, res)
+        if listen is not None:
+            with (span("bench.observe") if tracing
+                  else contextlib.nullcontext()):
+                listen(cols, res)
         if trace_dir and len(w.t_verdict) % _KEEP_RECORDS_EVERY == 0:
             w.keep_records(engine)
         w.t_done.append(clock())
@@ -293,8 +336,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     traffic = generator.Traffic(mix, world, seed, reference)
     say(traffic.summary)
     t_traffic = time.perf_counter()
-    for cols in traffic.warmup():
-        engine.step(packet_batch(cols), now_of(t_process))
+    warm_up(engine, traffic, t_process)
     # The collector stays on in the window, as in an agent; what set-up built
     # (the world's 100k rule objects, the program's tables) is taken out of
     # its reach, so that a full collection in the window walks the window's

@@ -15,6 +15,34 @@ per-layer metric is a file of its own, found by the name in the manifest:
 
 A configuration that names neither reads <paths[0]>/world.py and
 <paths[0]>/reference.py, the first deployments' own.
+
+A generator defines Traffic(params, world, seed, reference) with `summary`
+(one line for standard error), `fresh_lanes` and `fresh_at` (how many lanes
+of a batch, and which, carry connections never sent before), `warmup()`
+(yields the columns of each batch that set-up steps) and `next_batch()` ->
+(columns, sampled lanes, True where a sampled lane is fresh).  The sampled
+lanes are drawn from the seed before any answer exists.  Two parts are
+optional:
+
+  lane labels  `next_batch()` may return a fourth item, {name: array aligned
+               with the batch}: what the generator meant each lane to be (a
+               reply leg, the frontend it answers for).  The sample carries
+               each label's sampled lanes under its own name, beside the
+               columns and the answers, for the reference's statements
+               (correct.py).  A label named like a PacketBatch field, an
+               answer, `fresh`, `ref_code` or `ref_rule` is refused at the
+               first step.  Labels never choose the sampled lanes.
+  observe      `observe(columns, result)` is called once for every StepResult
+               of the warm-up and of the window, in the order the results
+               come back, never for a step that raised nor for the replay
+               after the close.  In the window it runs after the verdict's
+               clock and before the sample's: its time is the client's
+               (`client.gen_share`), never the step's (`verdict_p95_ms`).  A
+               generator may count on having heard batch n only before it
+               assembles batch n + 2 (a loop that keeps a batch in flight
+               while it assembles the next keeps that), and sends a reply
+               only for a flow it has heard was committed.  The loop as it is
+               hands batch n's result back before batch n + 1 is assembled.
 """
 
 from __future__ import annotations

@@ -61,6 +61,30 @@ class _StateUnchanged(_Wrapped):
         return res
 
 
+class _ReplyUnmarked(_Wrapped):
+    """A reply leg answered without its mark: `reply` 0 on every lane that
+    the cache answered from a connection's reply entry."""
+
+    def step(self, batch, now):
+        res = self.dp.step(batch, now)
+        return dataclasses.replace(res, reply=np.zeros_like(res.reply))
+
+
+class _ReplyAtEndpoint(_Wrapped):
+    """The un-DNAT left out: a reply leg keeps the endpoint it came from in
+    `dnat_ip`/`dnat_port` instead of the frontend its client sent to."""
+
+    def step(self, batch, now):
+        res = self.dp.step(batch, now)
+        rpl = np.asarray(res.reply) == 1
+        return dataclasses.replace(
+            res,
+            dnat_ip=np.where(rpl, batch.src_ip, res.dnat_ip).astype(
+                np.asarray(res.dnat_ip).dtype),
+            dnat_port=np.where(rpl, batch.src_port, res.dnat_port).astype(
+                np.asarray(res.dnat_port).dtype))
+
+
 def flip_code(**kw):
     return _FlipCode(**kw)
 
@@ -71,3 +95,11 @@ def half_batch(**kw):
 
 def state_unchanged(**kw):
     return _StateUnchanged(**kw)
+
+
+def reply_unmarked(**kw):
+    return _ReplyUnmarked(**kw)
+
+
+def reply_at_endpoint(**kw):
+    return _ReplyAtEndpoint(**kw)

@@ -15,6 +15,7 @@ import os
 import shutil
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -48,7 +49,18 @@ TINY_CELLS = [
     ("tiny_flip_code.steady", "tiny_flip_code", "tiny_steady", 1),
     ("tiny_half_batch.steady", "tiny_half_batch", "tiny_steady", 1),
     ("tiny_state_unchanged.steady", "tiny_state_unchanged", "tiny_steady", 1),
+    # reply legs (test_replies.py): a generator that listens and labels its
+    # lanes, a reference that reads the labels, and two faults of the reply
+    # path under the same mix
+    ("tiny_replies.churn", "tiny_replies", "tiny_churn_replies", 1),
+    ("tiny_reply_unmarked.churn", "tiny_reply_unmarked", "tiny_churn_replies",
+     1),
+    ("tiny_reply_at_endpoint.churn", "tiny_reply_at_endpoint",
+     "tiny_churn_replies", 1),
 ]
+# Files a tiny cell names beside its configuration and mix: (file, folder).
+PLACED = [("reply_flows.py", "generators"),
+          ("reply_reference.py", "references")]
 
 
 @pytest.fixture(scope="module")
@@ -59,6 +71,8 @@ def tree(tmp_path_factory):
     root = tmp_path_factory.mktemp("checkout")
     shutil.copytree(BENCH, root / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    for name, folder in PLACED:
+        shutil.copy(os.path.join(FIXTURES, name), root / "benchmark" / folder)
     doc = load_json(os.path.join(ROOT, "BENCHMARK.json"))
     for cell, config, traffic, chips in TINY_CELLS:
         shutil.copy(os.path.join(FIXTURES, f"{config}.json"),
@@ -315,6 +329,9 @@ def test_mesh_cell_on_four_virtual_devices(tree):
     ("flip_code", "steady", "wrong_lanes"),
     ("half_batch", "steady", "wrong_lanes"),
     ("state_unchanged", "steady", "remiss_share"),
+    # under tiny_churn_replies (TINY_CELLS)
+    ("reply_unmarked", "churn", "wrong_lanes"),
+    ("reply_at_endpoint", "churn", "wrong_lanes"),
 ])
 def test_a_broken_timed_path_is_not_correct(tree, fault, mix, number):
     r = run(tree, f"tiny_{fault}.{mix}")
@@ -400,3 +417,177 @@ def test_a_denied_class_that_nobody_proposed_goes_without():
     # The other classes keep their sizes: a short class is short alone.
     assert [x for x in full.summary.split(", ") if "pod" in x] == [
         x for x in t.summary.split(", ") if "pod" in x]
+
+
+# -- the generator's optional parts: observe and lane labels ------------------
+
+class _Echo:
+    """A fake engine: answers every lane from its own columns and keeps each
+    result; the `fail_at`-th call raises, the `stop_at`-th sleeps `stop_s`
+    (so that a window ends right after it)."""
+
+    def __init__(self, fail_at=None, stop_at=None, stop_s=0.0, step_s=0.0):
+        self.results, self.batches, self.calls = [], [], 0
+        self.fail_at, self.stop_at, self.stop_s = fail_at, stop_at, stop_s
+        self.step_s = step_s
+
+    def step(self, batch, now):
+        from antrea_tpu.datapath.interface import StepResult
+
+        self.calls += 1
+        if self.calls == self.fail_at:
+            raise RuntimeError("a planted failure of the served path")
+        self.batches.append({k: np.asarray(v) for k, v in vars(batch).items()
+                             if v is not None})
+        n = batch.size
+        d = np.asarray(batch.dst_port, np.int64)
+        res = StepResult(
+            code=(d % 3).astype(np.int8), est=(d % 2).astype(np.int8),
+            svc_idx=np.full(n, -1), dnat_ip=np.asarray(batch.dst_ip),
+            dnat_port=np.asarray(batch.dst_port), ingress_rule=[None] * n,
+            egress_rule=[None] * n, committed=np.zeros(n, np.int8),
+            n_miss=n // 8, reply=np.zeros(n, np.int8),
+            reject_kind=np.zeros(n, np.int8), snat=np.zeros(n, np.int8))
+        self.results.append(res)
+        time.sleep(self.step_s)
+        if self.calls == self.stop_at:
+            time.sleep(self.stop_s)
+        return res
+
+
+class _Listener:
+    """A fake generator with both optional parts: batch k's source address
+    is k on every lane, label `mark` is 1000 k + the lane, and `observe`
+    keeps what it heard (and sleeps `listen_s`)."""
+
+    batch = 16
+    fresh_lanes = 1
+    fresh_at = np.array([0])
+    lanes = np.array([0, 3, 7, 15])
+
+    def __init__(self, label="mark", listen_s=0.0, warm=3):
+        self.label, self.listen_s, self.warm = label, listen_s, warm
+        self.built, self.heard = 0, []
+
+    def _cols(self):
+        k, n = self.built, self.batch
+        self.built += 1
+        return {"src_ip": np.full(n, k, np.uint32),
+                "dst_ip": np.arange(n, dtype=np.uint32) + 100,
+                "proto": np.full(n, 6, np.int32),
+                "src_port": np.full(n, 1024 + k, np.int32),
+                "dst_port": np.arange(n, dtype=np.int32) + 80}
+
+    def warmup(self):
+        for _ in range(self.warm):
+            yield self._cols()
+
+    def next_batch(self):
+        k = self.built
+        return (self._cols(), self.lanes, self.lanes == 0,
+                {self.label: 1000 * k + np.arange(self.batch)})
+
+    def observe(self, cols, res):
+        self.heard.append((cols, res))
+        time.sleep(self.listen_s)
+
+
+def test_observe_hears_every_served_step_in_order_and_no_other():
+    gen, eng = _Listener(), _Echo(fail_at=3 + 5)
+    t = time.perf_counter()
+    harness.warm_up(eng, gen, t)
+    w = harness.run_window(eng, gen, 30.0, t, None)
+    assert w.error and "planted failure" in w.error  # the window's 5th step
+    assert len(w.t_verdict) == 4 and len(eng.results) == 3 + 4
+    # once a served step, its own result, in order, the warm-up's too; not
+    # the step that raised
+    assert [res for _, res in gen.heard] == eng.results
+    assert [int(cols["src_ip"][0]) for cols, _ in gen.heard] == list(range(7))
+    assert all(b["src_ip"][0] == k for k, b in enumerate(eng.batches))
+    assert harness.replay_last(eng, w, gen, t) is not None
+    assert len(eng.results) == 8 and len(gen.heard) == 7  # not the replay
+
+
+def test_observe_is_the_clients_time_and_not_the_steps():
+    """Listening 20 ms a step against 2 ms steps: the verdict's interval
+    never holds it, the client's share of the loop does."""
+    gen_share = harness.load_module(
+        os.path.join(BENCH, "layers", "client.gen_share.py")).read
+    shares = []
+    for listen_s in (0.0, 0.02):
+        gen, eng = _Listener(listen_s=listen_s), _Echo(step_s=0.002)
+        w = harness.run_window(eng, gen, 0.6, time.perf_counter(), None)
+        assert len(gen.heard) == len(w.t_verdict) > 5
+        waits = np.subtract(w.t_verdict, w.t_handoff)
+        assert waits.max() < 0.015
+        listened = np.subtract(w.t_done, w.t_verdict)
+        assert (listened >= listen_s).all()
+        assert harness.end_to_end(w, 1.0)["verdict_p95_ms"] < 15
+        shares.append(gen_share({"window": w}))
+    assert shares[0] < 60 and shares[1] > 80
+
+
+def test_labels_reach_the_sample_at_the_sampled_lanes():
+    gen, eng = _Listener(), _Echo(stop_at=4, stop_s=1.0)
+    w = harness.run_window(eng, gen, 0.8, time.perf_counter(), None)
+    assert len(w.t_verdict) == 4
+    s = w.sampled()
+    want = np.concatenate([1000 * k + gen.lanes for k in range(4)])
+    assert (s["mark"] == want).all()
+    # beside the columns and the answers of the same lanes
+    assert (s["src_ip"] == np.repeat(np.arange(4), len(gen.lanes))).all()
+    assert (s["dst_port"] == np.tile(gen.lanes + 80, 4)).all()
+    assert (s["fresh"] == np.tile(gen.lanes == 0, 4)).all()
+    assert len(s["code"]) == len(want)
+
+
+@pytest.mark.parametrize("name", [
+    "src_ip", "in_port", "pkt_len", "code", "dnat_ip", "fresh", "ref_code",
+    "ref_rule"])
+def test_a_label_that_takes_a_sample_name_is_refused(name):
+    gen, eng = _Listener(label=name), _Echo()
+    with pytest.raises(ValueError, match=repr(name)):
+        harness.run_window(eng, gen, 5.0, time.perf_counter(), None)
+    assert len(eng.results) == 1  # at the first step
+
+
+# Taken on the parent tree (3f00b88, before observe and lane labels): every
+# batch that the warm-up and the first four window steps handed the engine,
+# and the window's sample without the rule lists, through a fake engine.
+GOLDEN_LOOP = {
+    ("tiny", "tiny_churn", 5): ("a69c18e6f2bdfbdb", "9b91df60565504e0"),
+    ("tiny", "tiny_steady", 2**31 + 17): ("62df4ca800b195a0",
+                                          "95de589b9a3ddb67"),
+    ("tiny_dual_stack", "tiny_churn_ds", 5): ("45f01a31ea309ccc",
+                                              "7188ff7198eaf51b"),
+    ("tiny_xlarge", "tiny_churn_xl", 2**31 + 17): ("7dda55063c4424d5",
+                                                   "4d778ea4f71b89c7"),
+}
+
+
+@pytest.mark.parametrize("config, mix, seed", sorted(GOLDEN_LOOP))
+def test_the_accepted_generators_send_what_they_sent_through_the_loop(
+        config, mix, seed):
+    """policy_flows, dual_stack_flows and namespace_flows define neither
+    optional part: through warm_up and the window they hand the engine the
+    same batches, and the sample holds the same lanes, as before both."""
+    from test_seam import _digest
+
+    m = Manifest()
+    cfg = load_json(os.path.join(FIXTURES, f"{config}.json"))
+    params = load_json(os.path.join(FIXTURES, f"{mix}.json"))
+    world = harness.load_module(m.world_path(cfg)).build_world(
+        cfg["world"], cfg["world_seed"])
+    ref = harness.load_module(m.reference_path(cfg)).Reference(world)
+    traffic = harness.load_module(m.generator_path(
+        params["generator"])).Traffic(params, world, seed, ref)
+    assert not hasattr(traffic, "observe")
+    t = time.perf_counter()
+    eng = _Echo(stop_s=2.0)
+    harness.warm_up(eng, traffic, t)
+    eng.stop_at = eng.calls + 4
+    w = harness.run_window(eng, traffic, 2.0, t, None)
+    assert len(w.t_verdict) == 4
+    s = {k: v for k, v in w.sampled().items() if v.dtype != object}
+    assert (_digest(*eng.batches), _digest(s)) == GOLDEN_LOOP[
+        config, mix, seed]
