@@ -37,12 +37,28 @@ has closed.  Numbers, each with a limit of its own (the traffic file's
                       stepped once more after the close; of its fresh lanes
                       that were committed, the share not established then.
                       A step that returns its state unchanged reads 1.
+  reply_unanswered_share
+                      only where the generator labels `leg` (1 on a lane
+                      sent as the reply leg of a connection the generator
+                      heard was committed, 0 on any other): of the sampled
+                      reply lanes, the share the program answered with
+                      `reply` 0, as a packet of its own.  A reference
+                      cannot state "answered as a reply" lane by lane: a
+                      direct-mapped cache may rightly lose a reply's entry
+                      to another flow, and the reply is then a packet of its
+                      own, held to the statements of any other lane.  A
+                      window can state it as a share: a program that keeps
+                      no reply entries, or answers replies without their
+                      mark, reads 1.  A sample with `leg` but no reply lane
+                      proves nothing and raises.
 
-The three step-level numbers and the limits' handling are the same for every
-cell: a reference may state more about a lane, it cannot drop one of them.
-What a reference's statements may read: the sample's batch columns, its
-answers, `fresh`, and the lane labels its generator gave (manifest.py), each
-under its own name; `failed_statements` below reads none of the labels.
+The three step-level numbers, the reply number where `leg` is labelled, and
+the limits' handling are the same for every cell: a reference may state more
+about a lane, it cannot drop one of them.  What a reference's statements may
+read: the sample's batch columns, its answers, `fresh`, and the lane labels
+its generator gave (manifest.py), each under its own name;
+`failed_statements` below reads none of the labels, and `decide` reads `leg`
+alone.
 """
 
 from __future__ import annotations
@@ -51,6 +67,7 @@ import numpy as np
 
 PROTO_TCP = 6
 ALLOW, DROP, REJECT = 0, 1, 2
+REPLY_LEG = 1  # the lane label `leg` on a reply leg
 
 
 def _u32(a):
@@ -158,6 +175,14 @@ def decide(ref, sample: dict, steps: dict, replay, limits: dict):
                              "the replay proves nothing")
         hold("replay_unhit_share", float(np.mean(
             np.asarray(replay["est_again"])[was] == 0)))
+    legs = None
+    if "leg" in sample:
+        legs = np.asarray(sample["leg"]) == REPLY_LEG
+        if not legs.any():
+            raise ValueError("no reply lane sampled: the number proves "
+                             "nothing")
+        hold("reply_unanswered_share", float(np.mean(
+            np.asarray(sample["reply"])[legs] == 0)))
     correct = all(v["value"] <= v["limit"] for v in numbers.values())
     est = np.asarray(sample["est"])
     # What the sample covered (no limit: information for the reader).
@@ -173,4 +198,6 @@ def decide(ref, sample: dict, steps: dict, replay, limits: dict):
             ("lanes_fresh", fresh),
             ("lanes_service", np.asarray(sample["svc_idx"]) >= 0)):
         numbers[name] = {"value": int(np.sum(mask)), "limit": None}
+    if legs is not None:
+        numbers["lanes_reply"] = {"value": int(np.sum(legs)), "limit": None}
     return correct, numbers, describe_wrong(bad, sample) if wrong else []

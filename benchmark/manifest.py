@@ -31,7 +31,11 @@ optional:
                columns and the answers, for the reference's statements
                (correct.py).  A label named like a PacketBatch field, an
                answer, `fresh`, `ref_code` or `ref_rule` is refused at the
-               first step.  Labels never choose the sampled lanes.
+               first step.  Labels never choose the sampled lanes.  One
+               name is read by `correct` itself: `leg`, 1 on a lane sent as
+               the reply leg of a connection the generator heard was
+               committed and 0 on any other; a mix whose generator labels
+               it gives `reply_unanswered_share` a limit (correct.py).
   observe      `observe(columns, result)` is called once for every StepResult
                of the warm-up and of the window, in the order the results
                come back, never for a step that raised nor for the replay

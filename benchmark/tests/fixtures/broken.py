@@ -5,7 +5,10 @@ import dataclasses
 import numpy as np
 
 from antrea_tpu.datapath import make_datapath
-from antrea_tpu.packet import PacketBatch
+from antrea_tpu.oracle.interpreter import Oracle
+from antrea_tpu.packet import Packet, PacketBatch
+
+ALLOW, REJECT = 0, 2
 
 
 class _Wrapped:
@@ -85,6 +88,55 @@ class _ReplyAtEndpoint(_Wrapped):
                 np.asarray(res.dnat_port).dtype))
 
 
+class _NoReplyEntries(_Wrapped):
+    """A program that keeps no reply entries.  On every lane the real engine
+    answered from a connection's reply entry (`reply` 1) it answers what
+    such a program would: the reply packet's own policy verdict (the
+    program's scalar `Oracle` on the packet as it is, endpoint to client, no
+    Service, no un-DNAT), committed and counted as a miss of the step the
+    first time that reply is seen, established and from the cache after.
+    Every such lane meets the statements a reference holds a reply to whose
+    entry the cache lost: only `reply_unanswered_share` sees it."""
+
+    def install_bundle(self, ps, services):
+        self._oracle = Oracle(ps)
+        self._verdicts = {}  # reply tuple -> (code, ingress rule, egress rule)
+        return super().install_bundle(ps, services)
+
+    def step(self, batch, now):
+        res = self.dp.step(batch, now)
+        lanes = np.nonzero(np.asarray(res.reply) == 1)[0]
+        if not len(lanes):
+            return res
+        code, est = np.array(res.code), np.array(res.est)
+        committed, svc = np.array(res.committed), np.array(res.svc_idx)
+        dnat_ip, dnat_port = np.array(res.dnat_ip), np.array(res.dnat_port)
+        rule_in, rule_out = list(res.ingress_rule), list(res.egress_rule)
+        reject = np.array(res.reject_kind)
+        first = 0
+        for i in lanes:
+            t = tuple(int(getattr(batch, c)[i]) for c in (
+                "src_ip", "dst_ip", "proto", "src_port", "dst_port"))
+            seen = t in self._verdicts
+            if not seen:
+                first += 1
+                v = self._oracle.classify(Packet(*t))
+                self._verdicts[t] = (int(v.code), v.ingress.rule,
+                                     v.egress.rule)
+            c, r_in, r_out = self._verdicts[t]
+            code[i], svc[i] = c, -1
+            reject[i] = (1 if t[2] == 6 else 2) if c == REJECT else 0
+            est[i] = int(seen and c == ALLOW)
+            committed[i] = int(c == ALLOW and not seen)
+            dnat_ip[i], dnat_port[i] = t[1], t[4]
+            rule_in[i], rule_out[i] = r_in, r_out
+        return dataclasses.replace(
+            res, code=code, est=est, committed=committed, svc_idx=svc,
+            dnat_ip=dnat_ip, dnat_port=dnat_port, ingress_rule=rule_in,
+            egress_rule=rule_out, reject_kind=reject,
+            reply=np.zeros_like(res.reply), n_miss=res.n_miss + first)
+
+
 def flip_code(**kw):
     return _FlipCode(**kw)
 
@@ -103,3 +155,7 @@ def reply_unmarked(**kw):
 
 def reply_at_endpoint(**kw):
     return _ReplyAtEndpoint(**kw)
+
+
+def no_reply_entries(**kw):
+    return _NoReplyEntries(**kw)

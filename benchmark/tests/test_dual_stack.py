@@ -211,24 +211,57 @@ def test_a_cpu_cell_run_is_correct_on_both_families(ds_tree, capsys):
     assert err[-1].startswith("[bench] correct = True")
 
 
-def test_a_traced_run_reads_the_v6_share(ds_tree):
+def test_a_traced_run_reads_the_v6_share(ds_tree, monkeypatch):
+    import harness
+    import reduce_trace
+    import step_spans
+
+    seen = {}
+    read_layers = harness.read_layers
+
+    def keep(manifest, cell, ctx):
+        seen.update(ctx)
+        return read_layers(manifest, cell, ctx)
+
+    monkeypatch.setattr(harness, "read_layers", keep)
     r = run(ds_tree, f"{CONFIG}.churn", trace=True, seconds=6.0)
     assert r["correct"] is True
     asked = {m["name"] for m in ds_tree.metrics_of(f"{CONFIG}.churn",
                                                    "per_layer")}
     assert set(NEW_METRICS) <= asked
-    # The CPU's trace nests no loop body inside its `while` event, so the
-    # time inside the rounds reads 0 there (test_harness.py).
     assert asked - set(r["metrics"]) <= {"slowpath.device_ms"}
-    assert r["metrics"]["slowpath.width_us"]["value"] == 0.0
+    # The CPU's trace holds the `while` ops of some steps and their bodies
+    # in one run and none in the next (0, 0.02, 0.03, and 1.1 on a loaded
+    # host), so the width reads the time inside the rounds of the trace
+    # that was recorded: a CPU time, or 0 where it holds no `while`.
+    rounds = step_spans.window_records(seen)["round_lanes"]
+    inside = reduce_trace.step_device_ms(seen["reduced"], seen["config"])
+    assert rounds.mean() > 0
+    assert r["metrics"]["slowpath.width_us"]["value"] == pytest.approx(
+        1e3 * inside["while"] / rounds.mean())
     assert r["metrics"]["entry.v6_lane_share"]["value"] == pytest.approx(
         40.0, abs=4.0)  # 256 lanes a step
     # three more uploads than a narrow engine's nine, 28 -> 64 B a lane; two
-    # more copies than its three, 50 -> 82 B a lane
+    # more copies than its three: the egress record's blocks as
+    # models/forwarding lays them out, and the two wide outputs beside them
     assert r["metrics"]["entry.h2d_transfers"]["value"] == 12
     assert r["metrics"]["entry.h2d_bytes"]["value"] == 256 * (28 + 36) + 8
     assert r["metrics"]["entry.d2h_transfers"]["value"] == 5
-    assert r["metrics"]["entry.d2h_bytes"]["value"] == 256 * (50 + 32) + 16
+    assert r["metrics"]["entry.d2h_bytes"]["value"] == _d2h_bytes(256)
+
+
+def _d2h_bytes(lanes: int) -> int:
+    """What a dual-stack step fetches: every lane field of the egress record
+    at its width, its scalar counters once, and the wide outputs `dnat_w_f`
+    and `peer_w`, an IPv6 address a lane each as four 32-bit words."""
+    from antrea_tpu.models import forwarding as fw
+
+    lane_bits = sum(bits for _, block, _, bits, _ in fw.EGRESS_RECORD
+                    if block != "scalars")
+    scalar_bits = sum(bits for _, block, _, bits, _ in fw.EGRESS_RECORD
+                      if block == "scalars")
+    wide = 2 * 4 * 4
+    return lanes * (lane_bits // 8 + wide) + scalar_bits // 8
 
 
 def test_the_new_readers_return_nothing_on_a_record_without_the_field():

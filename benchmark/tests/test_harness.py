@@ -50,12 +50,14 @@ TINY_CELLS = [
     ("tiny_half_batch.steady", "tiny_half_batch", "tiny_steady", 1),
     ("tiny_state_unchanged.steady", "tiny_state_unchanged", "tiny_steady", 1),
     # reply legs (test_replies.py): a generator that listens and labels its
-    # lanes, a reference that reads the labels, and two faults of the reply
-    # path under the same mix
+    # lanes, a reference that reads the labels, and three faults of the
+    # reply path under the same mix (the third in test_replies.py)
     ("tiny_replies.churn", "tiny_replies", "tiny_churn_replies", 1),
     ("tiny_reply_unmarked.churn", "tiny_reply_unmarked", "tiny_churn_replies",
      1),
     ("tiny_reply_at_endpoint.churn", "tiny_reply_at_endpoint",
+     "tiny_churn_replies", 1),
+    ("tiny_no_reply_entries.churn", "tiny_no_reply_entries",
      "tiny_churn_replies", 1),
 ]
 # Files a tiny cell names beside its configuration and mix: (file, folder).
@@ -331,6 +333,7 @@ def test_mesh_cell_on_four_virtual_devices(tree):
     ("state_unchanged", "steady", "remiss_share"),
     # under tiny_churn_replies (TINY_CELLS)
     ("reply_unmarked", "churn", "wrong_lanes"),
+    ("reply_unmarked", "churn", "reply_unanswered_share"),
     ("reply_at_endpoint", "churn", "wrong_lanes"),
 ])
 def test_a_broken_timed_path_is_not_correct(tree, fault, mix, number):
