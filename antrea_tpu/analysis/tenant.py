@@ -34,7 +34,7 @@ QUEUE_ALLOWLIST = {
 REQUIRED_WORLD_FIELDS = {
     "datapath/tpuflow.py": {
         "_ps", "_cps", "_drs", "_meta", "_meta_step", "_state", "_gen",
-        "_stats_in", "_stats_out", "_evictions", "_state_mutations",
+        "_tally_in", "_tally_out", "_evictions", "_state_mutations",
         "_pipe_kw",
     },
     "datapath/oracle_dp.py": {

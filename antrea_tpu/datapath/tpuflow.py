@@ -63,6 +63,7 @@ from .commit import TransactionalDatapath, pad_probes
 from .interface import (Datapath, DatapathStats, DatapathType, StepResult,
                         WideStepResult)
 from .maintenance import MaintainableDatapath
+from .rule_tally import RuleTally
 from .slowpath import ADMIT_HOLD
 from .tenancy import TenantedDatapath, TenantSpec
 
@@ -97,7 +98,7 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         "_ps", "_cps", "_drs", "_meta", "_meta_step", "_state", "_gen",
         "_has_named_ports", "_n_deltas", "_delta_host", "_name_gids",
         "_gid_ident", "_group_members", "_static_blocks", "_member_meta",
-        "_stats_in", "_stats_out", "_bytes_in", "_bytes_out",
+        "_tally_in", "_tally_out",
         "_default_allow", "_default_deny", "_evictions", "_reclaims",
         "_state_mutations", "_pipe_kw", "_persist_dirty",
     )
@@ -262,12 +263,11 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         # is dropped (re-classifies, never re-verdicts differently).
         self._init_persist(persist_dir, ps, services)
         self._state = self._init_pipeline_state(flow_slots, aff_slots)
-        # Per-rule packet counters (IngressMetric/EgressMetric analog),
-        # keyed by stable rule id so they survive bundle renumbering.
-        self._stats_in: Counter = Counter()
-        self._stats_out: Counter = Counter()
-        self._bytes_in: Counter = Counter()
-        self._bytes_out: Counter = Counter()
+        # Per-rule packet and byte counters (IngressMetric/EgressMetric
+        # analog): arrays by rule index, read keyed by stable rule id so
+        # they survive bundle renumbering (datapath/rule_tally).
+        self._tally_in = RuleTally()
+        self._tally_out = RuleTally()
         self._default_allow = 0
         self._default_deny = 0
         self._evictions = 0
@@ -760,11 +760,13 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         return res
 
     def stats(self) -> DatapathStats:
+        ingress, ingress_bytes = self._tally_in.read()
+        egress, egress_bytes = self._tally_out.read()
         return DatapathStats(
-            ingress=dict(self._stats_in),
-            egress=dict(self._stats_out),
-            ingress_bytes=dict(self._bytes_in),
-            egress_bytes=dict(self._bytes_out),
+            ingress=ingress,
+            egress=egress,
+            ingress_bytes=ingress_bytes,
+            egress_bytes=egress_bytes,
             default_allow=self._default_allow,
             default_deny=self._default_deny,
         )
@@ -1579,35 +1581,17 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         punt = o.get("punt")
         if punt is not None and not_spoofed is not None:
             not_spoofed = not_spoofed & (punt == 0)
-        for key, ids, ctr, bctr in (
-            ("ingress_rule", in_ids, self._stats_in, self._bytes_in),
-            ("egress_rule", out_ids, self._stats_out, self._bytes_out),
-        ):
+        for key, ids, tally in (("ingress_rule", in_ids, self._tally_in),
+                                ("egress_rule", out_ids, self._tally_out)):
             idx = o[key]
             # Cached entries can carry attribution indices from an older
             # generation (ct_label semantics); clamp to the current table.
             ok = (idx >= 0) & (idx < len(ids))
             vals = idx[ok]
             if vals.size:
-                bc = np.bincount(vals, minlength=len(ids))
                 # Byte volumes ride the same attribution (pkg/apis/stats
-                # bytes counters): weighted bincount over packet lengths.
-                bb = (np.bincount(vals, weights=lens[ok],
-                                  minlength=len(ids))
-                      if lens is not None else None)
-                # One fold a DISTINCT rule the step named (tens of
-                # thousands where every namespace has rules of its own):
-                # plain Python ints from one tolist() a column, never a
-                # numpy scalar an iteration.
-                hit = np.nonzero(bc)[0]
-                counts = bc[hit].tolist()
-                volumes = bb[hit].tolist() if bb is not None else None
-                for k, r in enumerate(hit.tolist()):
-                    rid = ids[r]
-                    if rid:
-                        ctr[rid] += counts[k]
-                        if volumes is not None and volumes[k]:
-                            bctr[rid] += int(volumes[k])
+                # bytes counters): packet lengths weigh the same indices.
+                tally.add(ids, vals, lens[ok] if lens is not None else None)
         none_mask = (o["ingress_rule"] < 0) & (o["egress_rule"] < 0)
         if not_spoofed is not None:
             none_mask = none_mask & not_spoofed
@@ -1848,10 +1832,8 @@ class TpuflowDatapath(TenantedDatapath, MaintainableDatapath,
         self._gen = 0
         self._pipe_kw = dict(self._pipe_kw, flow_slots=spec.quota,
                              aff_slots=spec.aff_quota)
-        self._stats_in = Counter()
-        self._stats_out = Counter()
-        self._bytes_in = Counter()
-        self._bytes_out = Counter()
+        self._tally_in = RuleTally()
+        self._tally_out = RuleTally()
         self._default_allow = 0
         self._default_deny = 0
         self._evictions = 0

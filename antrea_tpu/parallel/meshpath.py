@@ -589,7 +589,7 @@ class MeshDatapath(TpuflowDatapath):
         "_ps", "_cps", "_drs", "_meta", "_meta_step", "_state", "_gen",
         "_has_named_ports", "_n_deltas", "_delta_host", "_name_gids",
         "_gid_ident", "_group_members", "_static_blocks", "_member_meta",
-        "_stats_in", "_stats_out", "_bytes_in", "_bytes_out",
+        "_tally_in", "_tally_out",
         "_default_allow", "_default_deny", "_evictions", "_reclaims",
         "_state_mutations", "_pipe_kw", "_persist_dirty",
         "_mesh", "_n_data", "_topo_gen", "_replica_audit_entries",

@@ -184,6 +184,38 @@ def test_spill_lanes_classify_but_never_cache_foreign(world, mesh):
     assert occ[0].sum() > 0
 
 
+def test_mesh_rule_stats_equal_the_step_results_with_a_spill(world, mesh):
+    """Per-rule packet and byte counters on the mesh, where half the
+    lanes spill and their retry runs, equal a fold of the StepResults'
+    rule ids and the batch's packet lengths."""
+    from antrea_tpu.packet import PacketBatch
+
+    cluster, _services = world
+    mdp = _mesh_dp(world, mesh)
+    big = gen_traffic(cluster.pod_ips, 512, n_flows=256, seed=17)
+    shard = pm.shard_of_tuples(big.src_ip, big.dst_ip, big.proto,
+                               big.src_port, big.dst_port, 2)
+    idx = np.nonzero(shard == 0)[0][:64]
+    skew = PacketBatch.from_packets([big.packet(int(i)) for i in idx])
+    skew.pkt_len = (np.arange(64, dtype=np.int32) * 7) % 50
+    want = ({}, {}), ({}, {})
+    for t in range(2):
+        res = mdp.step(skew, 300 + t)
+        for (pk, by), col in zip(want, (res.ingress_rule, res.egress_rule)):
+            for rid, ln in zip(col, skew.pkt_len.tolist()):
+                if rid is not None:
+                    pk[rid] = pk.get(rid, 0) + 1
+                    by[rid] = by.get(rid, 0) + ln
+    recs = mdp.step_trace()["records"][-2:]
+    assert all(r["retry_lanes"] > 0 for r in recs), recs
+    st = mdp.stats()
+    (pin, bin_), (pout, bout) = want
+    assert st.ingress == pin and st.egress == pout
+    assert st.ingress_bytes == {k: v for k, v in bin_.items() if v}
+    assert st.egress_bytes == {k: v for k, v in bout.items() if v}
+    assert sum(pin.values()) + sum(pout.values()) > 0
+
+
 def test_spill_hold_admission_serves_cached_verdicts(world, mesh):
     """admission="hold" under hash skew: after a drain, spilled
     ESTABLISHED flows must serve their real cached verdicts through the
